@@ -19,8 +19,6 @@ from dataclasses import dataclass
 NOFILE_TARGET = 4096
 #: workspaces allocate up to a few GiB of /dev/shm at production depths
 SHM_MIN_BYTES = 1 << 30
-CACHE_DIR = os.environ.get("JAX_COMPILATION_CACHE_DIR",
-                           os.path.expanduser("~/.cache/jax_comp"))
 
 
 @dataclass
@@ -61,27 +59,38 @@ def _stage_ulimit(fix: bool) -> StageResult:
 
 
 def _stage_cache(fix: bool) -> StageResult:
-    if os.path.isdir(CACHE_DIR):
-        n = len(os.listdir(CACHE_DIR))
-        return StageResult("cache", True, f"{CACHE_DIR} ({n} entries)")
+    # the location is hostdev's decision alone (JAX_COMPILATION_CACHE_DIR,
+    # else the checkout's fixed directory); this stage only reports it
+    from firedancer_tpu.utils.hostdev import compilation_cache_dir
+
+    cache_dir = compilation_cache_dir()
+    if os.path.isdir(cache_dir):
+        n = len(os.listdir(cache_dir))
+        return StageResult("cache", True, f"{cache_dir} ({n} entries)")
     if fix:
-        os.makedirs(CACHE_DIR, exist_ok=True)
-        return StageResult("cache", True, f"created {CACHE_DIR}")
-    return StageResult("cache", False, f"{CACHE_DIR} missing (init creates)")
+        os.makedirs(cache_dir, exist_ok=True)
+        return StageResult("cache", True, f"created {cache_dir}")
+    return StageResult("cache", False, f"{cache_dir} missing (init creates)")
 
 
 def _stage_device(fix: bool) -> StageResult:
+    """ok only for a TPU backend: a CPU backend runs the tests, not the
+    product, and reporting it ok is how a missing accelerator stays
+    unnoticed until the verify tile is found on its host fallback.
+    NOTE: this initialises the backend, so it takes the chip for the
+    life of the configure process — run it before `fdtctl run`, not
+    beside it."""
     try:
         import jax
 
         devs = jax.devices()
-        return StageResult(
-            "device", True,
-            f"{jax.default_backend()}: "
-            + ", ".join(str(d) for d in devs[:4]),
-        )
+        backend = jax.default_backend()
     except Exception as e:  # noqa: BLE001 — report, don't crash configure
         return StageResult("device", False, f"jax backend failed: {e}")
+    detail = f"{backend}: " + ", ".join(str(d) for d in devs[:4])
+    if backend != "tpu":
+        return StageResult("device", False, detail + " (no TPU backend)")
+    return StageResult("device", True, detail)
 
 
 def _stage_keys(fix: bool, keyfile: str | None = None) -> StageResult:

@@ -22,10 +22,14 @@ def cmd_run(args) -> int:
     from firedancer_tpu.app.monitor import Monitor
 
     from firedancer_tpu.utils import log
+    from firedancer_tpu.utils.hostdev import enable_compilation_cache
 
     text = open(args.config).read() if args.config else ""
     cfg = C.parse(text)
     log.init(path=args.log_path, stderr_level="NOTICE")
+    # config only, no backend: under the process runtime this parent
+    # must leave the chip to the verify tile child
+    enable_compilation_cache()
     if args.keyfile:
         identity = open(args.keyfile, "rb").read()[:32]
     else:
@@ -37,18 +41,19 @@ def cmd_run(args) -> int:
         qt = handles["net"]
         topo.build()
         topo.start()
+        quic_addr, udp_addr = _wire_addrs(topo, qt, cfg)
         log.notice(
             "workspace %r: quic %s udp %s metrics %s rpc %s",
-            cfg.name, qt.quic_addr, qt.udp_addr,
+            cfg.name, quic_addr, udp_addr,
             handles["metric"].addr, handles["rpc"].addr,
         )
     else:
         topo, qt = C.build_ingress_topology(cfg, identity)
         topo.build()
         topo.start()
+        quic_addr, udp_addr = _wire_addrs(topo, qt, cfg)
         log.notice(
-            "workspace %r: quic %s udp %s",
-            cfg.name, qt.quic_addr, qt.udp_addr,
+            "workspace %r: quic %s udp %s", cfg.name, quic_addr, udp_addr
         )
 
     stop = []
@@ -71,6 +76,16 @@ def cmd_run(args) -> int:
         topo.halt()
         topo.close()
     return 0
+
+
+def _wire_addrs(topo, tile, cfg):
+    """(quic, udp) listen addresses for the boot log.  Under the process
+    runtime the sockets are bound in the tile's CHILD — the parent's
+    copy of the tile never boots — so the configured ports are all this
+    process knows (an ephemeral 0 stays 0: give real ports there)."""
+    if topo._resolve_runtime() == "process":
+        return ("0.0.0.0", cfg.quic_port), ("0.0.0.0", cfg.udp_port)
+    return tile.quic_addr, tile.udp_addr
 
 
 def cmd_configure(args) -> int:

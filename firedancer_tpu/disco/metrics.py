@@ -32,8 +32,8 @@ HIST_BUCKETS = 16
 #: (values beyond the domain land there and percentiles interpolate
 #: inside it with the documented 2x-span bias).  Introduced for
 #: `sched_lag_us` (disco/profile.py): the 16-bucket domain ends at
-#: 2^16 µs = 65.5 ms, and the threaded-runtime baseline (PROFILE.md
-#: round 8) PINS its p99 at that ceiling — both the pre-refactor
+#: 2^16 µs = 65.5 ms, and a threaded runtime with more tiles than
+#: cores PINS its p99 at that ceiling — both the pre-refactor
 #: 100 ms-class lags and the post-refactor sub-ms lags must be
 #: representable for the process-runtime A/B to mean anything.
 WIDE_HIST_BUCKETS = 24

@@ -324,6 +324,13 @@ class Tile:
         shared account table is the motivating case."""
         return {}
 
+    def device_ordinals(self) -> tuple[int, ...]:
+        """Local accelerator ordinals this tile dispatches to from its
+        own process (empty = host-only).  The topology reads it at build
+        to refuse two tile PROCESSES on one chip (disco/topo.py
+        _check_device_owners)."""
+        return ()
+
     def on_boot(self, ctx: MuxCtx) -> None: ...
 
     def on_frags(self, ctx: MuxCtx, in_idx: int, frags: np.ndarray) -> None:
@@ -498,7 +505,8 @@ def _arm_stem_trace(stem, ctx, m, tracer) -> bool:
     hist updates and span emission all happen INSIDE the GIL-released
     burst — the measurement substrate living with the data plane
     instead of being applied at the burst boundary with one post-burst
-    clock read (the PROFILE.md round-11d skew).  Returns False when the
+    clock read (which stamped a whole burst with one time).  Returns
+    False when the
     ctx has neither link hists nor a tracer; the stem then runs
     untraced (zero overhead) and _stem_apply keeps the legacy
     burst-boundary bookkeeping for whatever hists exist."""

@@ -66,7 +66,7 @@ PROFILE_SCHEMA = MetricsSchema(
     ),
     # sched-lag is a WIDE hist (metrics.WIDE_HIST_BUCKETS): the
     # 16-bucket domain ends at 2^16 µs and the threaded baseline pins
-    # its p99 exactly there (PROFILE.md round 8 caveat) — the
+    # its p99 exactly there (a clamp, not a reading) — the
     # process-runtime A/B needs the 100 ms-class "before" AND the
     # sub-ms "after" to be representable in the same storage format,
     # with the top bucket as the explicit overflow bucket.
@@ -184,7 +184,7 @@ def aggregate(profiles: dict[str, Metrics]) -> dict:
 
 
 def render_rows(profiles: dict[str, Metrics]) -> str:
-    """Human table (PROFILE.md / monitor footer)."""
+    """Human table (monitor footer, profiling reports)."""
     lines = [
         f"{'tile':>10} {'gil_wait':>9} {'frag':>6} {'hk':>6} "
         f"{'credit':>7} {'bp':>6} {'lag p50/p99 us':>16} {'samples':>8}"

@@ -20,9 +20,10 @@ this build supports both shapes over the same /dev/shm-backed objects:
     failure classification) lives entirely in shared-memory words
     (cnc + a per-tile pstat region), so the supervisor can watchdog,
     SIGKILL, and in-place restart a child with the same rejoin
-    discipline as thread restarts.  This is what escapes the GIL:
-    PROFILE.md round 8 measured ~94% of every tile's non-sleeping wall
-    time as runnable-but-not-running in the threaded runtime.
+    discipline as thread restarts.  This is what escapes the GIL: with
+    more tile threads than the one interpreter lock can serve, a tile
+    spends most of its non-sleeping wall time runnable but not running
+    (disco/profile.py's gil_wait_frac is that share).
 
 Runtime selection: Topology(runtime=...) / start(mode=...) >
 FDT_RUNTIME env > "thread".  Observer tiles that close over parent
@@ -96,12 +97,15 @@ def device_assignments(spec, n_tiles: int) -> list[list[int]]:
     Each replica gets a DISJOINT device-ordinal list so two workers
     never contend for one accelerator (the reference pins each
     wiredancer lane to one FPGA slot for the same reason).  With fewer
-    devices than replicas the devices are shared round-robin — valid,
-    just contended.  "auto" probes the jax local-device inventory AT
-    BUILD TIME (the partition needs the count), which initializes and
-    freezes the backend — a caller that must control the platform
-    (the forced virtual CPU mesh) calls ensure_cpu_devices() first;
-    host-only topologies should pass an explicit spec, not "auto".
+    devices than replicas the devices are shared round-robin.  Sharing
+    is valid only between THREADS of one process: an accelerator
+    belongs to one process at a time, so under the process runtime
+    Topology.build refuses two tile processes on one real ordinal
+    (_check_device_owners).  "auto" needs the local inventory at build
+    time (the partition needs the count); hostdev.local_device_count
+    takes it in a child that exits, so a process-runtime parent stays
+    off the backend.  Host-only topologies should pass an explicit
+    spec, not "auto".
     """
     assert n_tiles >= 1
     if spec in (None, 1, "off"):
@@ -413,6 +417,34 @@ class Topology:
         already initialized a device runtime)."""
         return os.environ.get("FDT_SPAWN", "spawn")
 
+    def _check_device_owners(self) -> None:
+        """One process per chip: under the process runtime every
+        boot-active tile that dispatches to a real accelerator
+        (Tile.device_ordinals) must own its ordinals alone.  A second
+        process on the same chip does not contend for it — it fails or
+        hangs at boot — so the misconfiguration is refused HERE, with a
+        sentence.  On a CPU-pinned platform (tests, rehearsals) the
+        devices are virtual and processes may share them."""
+        from firedancer_tpu.utils.hostdev import cpu_pinned
+
+        if cpu_pinned():
+            return
+        owner: dict[int, str] = {}
+        for name, ts in self.tiles.items():
+            if not (ts.active and ts.tile.proc_safe):
+                continue
+            for d in ts.tile.device_ordinals():
+                if d in owner:
+                    raise ValueError(
+                        f"tiles {owner[d]!r} and {name!r} are both "
+                        f"assigned accelerator {d}: under the process "
+                        f"runtime each tile is its own process and a "
+                        f"chip belongs to one process at a time — give "
+                        f"each verify replica its own [tiles.verify] "
+                        f"devices ordinals, or run one replica"
+                    )
+                owner[d] = name
+
     def _tile_schema(self, ts: TileSpec) -> MetricsSchema:
         """The tile's own schema + base + the per-in-link latency
         attribution hists (qwait/svc/e2e per consumed link) the run
@@ -517,9 +549,11 @@ class Topology:
     def build(self, runtime: str | None = None) -> None:
         assert self.wksp is None, "already built"
         self._runtime = self._resolve_runtime(runtime)
-        if self._runtime == "process" and self.name is None:
-            # children attach by name; auto-name anonymous topologies
-            self.name = f"p{os.getpid()}_{os.urandom(3).hex()}"
+        if self._runtime == "process":
+            self._check_device_owners()
+            if self.name is None:
+                # children attach by name; auto-name anonymous topologies
+                self.name = f"p{os.getpid()}_{os.urandom(3).hex()}"
         self.wksp = R.Workspace(self._footprint(), name=self.name)
         for ls in self.links.values():
             self._mcaches[ls.name] = R.MCache.create(

@@ -45,26 +45,6 @@ from firedancer_tpu.ops import pack_select
 from firedancer_tpu.ops.ed25519 import verify as fver
 from firedancer_tpu.utils.hotpath import hot_path
 
-# jax.shard_map graduated from jax.experimental in 0.4.x (where the
-# replication-check kwarg was still named check_rep); accept both so the
-# pipeline runs on the container's pinned jax as well as newer ones
-_shard_map_raw = getattr(jax, "shard_map", None)
-if _shard_map_raw is None:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
-
-
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    try:
-        return _shard_map_raw(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    except TypeError:  # pragma: no cover - version-dependent
-        return _shard_map_raw(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
-
 #: bloom filter size in bits (power of two; must divide across mp); sized
 #: for the pre-rotation worst case of 2*AGE_CAPACITY resident tags
 BLOOM_BITS = 1 << 28
@@ -204,7 +184,7 @@ def make_step(mesh: Mesh):
         return keep, new_cur, metrics
 
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             step,
             mesh=mesh,
             in_specs=(

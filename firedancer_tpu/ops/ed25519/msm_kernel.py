@@ -229,8 +229,8 @@ def _msm_kernel(one_ref, cd_ref, zd_ref, an_ref, rn_ref, out_ref):
             )
 
     # digit rows are read by dynamic index from the full (NWIN, TILE)
-    # column block: dynamic sublane reads are free on this hardware
-    # (PROFILE.md round 4a), and a full-column block satisfies the
+    # column block: a dynamic sublane read costs the kernel nothing it
+    # would not pay for a static one, and a full-column block satisfies the
     # Mosaic (8, 128) tiling constraint where a (WPB, TILE) block cannot
     for j in range(WPB):
         d = jnp.squeeze(cd_ref[pl.ds(w0 + j, 1), :], axis=0)

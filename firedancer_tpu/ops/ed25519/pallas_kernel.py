@@ -14,8 +14,9 @@ VMEM-resident values: the math is written once and runs under XLA (tests,
 CPU interpret mode) or Mosaic (TPU) unchanged.
 
 Grid = batch tiles; Pallas pipelines each tile's HBM→VMEM input DMA behind
-the previous tile's compute.  PROFILE.md records the measured cost model
-(VPU multiply-issue bound) that drove the op-count choices in point.py.
+the previous tile's compute.  The op-count choices in point.py follow one
+cost model: the kernel is int32 VPU work and is bound by how fast the VPU
+issues multiplies, so multiplies are what every variant counts.
 """
 
 from __future__ import annotations

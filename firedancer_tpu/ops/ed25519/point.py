@@ -303,8 +303,8 @@ def _select9(table, absd):
 
     Branchless 4-level select tree keyed on the bits of absd: 8 wheres at
     the VPU cheap-op rate, replacing the masked-sum gather (9 multiplies +
-    8 adds at the multiply-issue rate) — the lookup half of the dsm-loop
-    overhead PROFILE.md flagged."""
+    8 adds at the multiply-issue rate) — the table lookup is half of the
+    dsm loop's non-curve-op overhead."""
     b0 = ((absd & 1) != 0)[None, None, :]
     b1 = ((absd & 2) != 0)[None, None, :]
     b2 = ((absd & 4) != 0)[None, None, :]

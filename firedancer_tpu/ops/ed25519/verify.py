@@ -54,7 +54,11 @@ def _is_small_order_enc(b):
 
 def _use_pallas() -> bool:
     """The fused Pallas kernel runs the dsm hot loop on TPU; elsewhere the
-    plain XLA path is used (Pallas interpret mode is for tests only)."""
+    plain XLA path is used (Pallas interpret mode is for tests only).
+    The choice is silent by design — it is what lets the CPU tests run
+    the same entry points — so whatever must prove the chip did the
+    work asserts the outcome instead: chip_smoke.py looks for the Mosaic
+    call (`tpu_custom_call`) in the compiled program's text."""
     import os
 
     env = os.environ.get("FDT_VERIFY_PALLAS")
@@ -209,8 +213,8 @@ def _verify_digest_rlc_impl(digests, sigs, pubs, zbytes, interpret=False):
 
     # prologue checks, shared with the per-sig path.  Decompress + niels
     # conversion run in a fused Pallas pass: the sqrt chain is ~250
-    # sequential field ops and dominates the batch under plain XLA
-    # (PROFILE.md round 5)
+    # sequential field ops, and one kernel keeps their intermediates in
+    # VMEM instead of leaving the fusion boundaries to XLA
     s_limbs = SC.from_bytes(sigs[:, 32:])
     ok = SC.is_canonical(s_limbs)
     ok = ok & ~_is_small_order_enc(pubs) & ~_is_small_order_enc(sigs[:, :32])
@@ -267,10 +271,11 @@ def _verify_digest_rlc_impl(digests, sigs, pubs, zbytes, interpret=False):
 
 
 def _use_rlc() -> bool:
-    """Opt-in (FDT_VERIFY_RLC=1).  Measured round 5 (PROFILE.md): the
-    bucket-MSM batch path runs at ~298K sigs/s vs the per-sig Strauss
-    kernel's ~388K on this chip — the per-update bucket overhead eats
-    the curve-op savings — so per-sig stays the default."""
+    """Opt-in (FDT_VERIFY_RLC=1).  The bucket-MSM batch path saves curve
+    operations but pays a bucket read-modify-write per update, and in
+    its one A/B against the per-sig Strauss kernel it lost — so per-sig
+    stays the default.  Not measured on this installation; ROADMAP
+    S3(b)/D2 decide whether the path stays."""
     import os
 
     env = os.environ.get("FDT_VERIFY_RLC")
@@ -320,7 +325,8 @@ def verify_batch_digest(digests, sigs, pubs):
     The host computes the digests during lane expansion so the device is
     shipped 64 bytes per lane instead of the whole message — the right
     trade whenever host→device bandwidth, not device compute, bounds the
-    pipeline (PROFILE.md).  digests: (B, 64); sigs: (B, 64);
+    pipeline (which of the two bounds it on this installation is not
+    measured; ROADMAP D4).  digests: (B, 64); sigs: (B, 64);
     pubs: (B, 32).  Returns (B,) bool."""
     digests = jnp.asarray(digests, jnp.uint8)
     sigs = jnp.asarray(sigs, jnp.uint8)
@@ -335,11 +341,13 @@ def verify_batch_digest_on(device):
     Inputs are committed to `device` with an explicit device_put and the
     jitted kernel follows their placement, so each pool domain compiles
     and runs on its own accelerator.  The explicit put is also what buys
-    the pool its transfer/compute overlap: a put onto one device
-    progresses while another device (or this one's previous batch)
-    executes — the round-3 measurement the scale-out design rests on.
-    jax.jit caches per placement, and the persistent compilation cache
-    makes devices 1..n-1 near-free after device 0."""
+    the pool its transfer/compute overlap: a put onto one device can
+    progress while another device (or this one's previous batch)
+    executes — the premise the scale-out design rests on (ROADMAP R4
+    measures it).  jax.jit caches per placement, and so does the
+    persistent cache (its key covers the device assignment): every
+    device pays its own lowering and, cold, its own compile — about
+    74 s a device on a v5e host (PERF.md, PR 22)."""
     use_pallas = _use_pallas()
 
     def fn(digests, sigs, pubs):
@@ -349,4 +357,7 @@ def verify_batch_digest_on(device):
         return _verify_digest_impl(d, s, p, use_pallas=use_pallas)
 
     fn.device = device
+    #: the jit object whose cache holds this fn's compiled programs
+    #: (tiles/verify.py counts them: VerifyTile._program_count)
+    fn.jitted = _verify_digest_impl
     return fn

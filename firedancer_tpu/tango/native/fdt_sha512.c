@@ -1,8 +1,9 @@
 /* Host-side SHA-512 for the verify tile's Ed25519 k-digest.
 
-   Why it exists: the TPU rides behind a narrow host<->device transfer
-   path, and shipping whole messages to the device costs ~2.2x the bytes
-   of shipping their 64-byte digests (PROFILE.md "pipeline" notes).  The
+   Why it exists: shipping whole messages to the device costs ~2.2x the
+   bytes of shipping their 64-byte digests (356 against 160 per lane at
+   the bench's 256-byte message width), and the host's expand pass walks
+   every message anyway.  The
    verify k = SHA512(R || A || M) is therefore computed on the host inside
    fdt_verify_expand's one GIL-released pass, and the device prologue
    starts from the digest (ops/ed25519/verify.verify_batch_digest).

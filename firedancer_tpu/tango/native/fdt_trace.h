@@ -9,8 +9,8 @@
  * drain→handle→publish burst in C but applied every latency sample and
  * span event from Python at the burst boundary with ONE post-burst
  * clock read, so on the native path all frags of a burst shared a
- * timestamp and tail percentiles were burst-quantized (PROFILE.md
- * round 11d) — exactly where "The Tail at Scale" (Dean & Barroso,
+ * timestamp and tail percentiles were burst-quantized
+ * — exactly where "The Tail at Scale" (Dean & Barroso,
  * CACM 2013) says the tail matters, and the opposite of Dapper's
  * (Sigelman et al., 2010) always-on in-path span emission.  fdt_trace
  * moves the measurement substrate into the burst:

@@ -1,13 +1,13 @@
 """Standalone pack insert/schedule benchmark at rate.
 
-VERDICT r4 #5: pack is unexercised above the landed-TPS rate; measure
+Pack is unexercised above the landed-TPS rate (round-4 review, #5): measure
 insert throughput and schedule/commit latency at 100K-1M inserts/s with
 payer contention, device prefilter on vs off, BEFORE the full pipeline
 gets there.  Reference bar: fd_pack survives ~1M inserts/s
 (src/ballet/pack/fd_pack.c:742-953 insert path).
 
 Run: python scripts/bench_pack.py [n_txns_log2=17] [n_payers=1024]
-Prints one summary line per phase + a JSON tail for PROFILE.md.
+Prints one summary line per phase + a JSON tail.
 """
 
 from __future__ import annotations

@@ -15,8 +15,8 @@ fixed execution overhead is amortized; `ok` lanes verify correctness):
               scalar-conditioned row select + shift + mask
   chunk8    — one dynamic (8,B) read per 8 iterations, inner 8 rows static
 
-Timing per PROFILE.md rules: np.asarray sync on a scalar reduction,
-distinct (lane-rolled) buffers per rep.
+Timing rules: np.asarray sync on a scalar reduction, distinct
+(lane-rolled) buffers per rep.
 """
 
 import sys

@@ -3,7 +3,7 @@
 Measures the marginal per-lane cost of each point/field op this session:
 mul_rr, sqr_rr, carry1, double(noT), double(T), add_niels, add_niels_affine,
 lookup9, and one full dsm iteration — so the dsm loop total can be
-reconciled against its parts.  Methodology per PROFILE.md.
+reconciled against its parts.  Methodology as scripts/exp_dsm_variants.py.
 """
 
 import sys

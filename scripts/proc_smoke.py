@@ -13,7 +13,7 @@ tango/interpreter work — the round-3b "host pipeline caps on pure GIL
 contention" shape) with the run-loop profiler enabled in both runtimes
 and print the contended-interpreter keys side by side — gil_wait_frac,
 sched_lag_p99_us, relay tps — the measurement contract of the ISSUE 7
-refactor (PROFILE.md round 9).
+refactor.
 
 Usage:
     scripts/proc_smoke.py [--runtime thread|process] [--txns N] [--json]

@@ -18,7 +18,70 @@ from firedancer_tpu.utils.hostdev import ensure_cpu_devices  # noqa: E402
 # minutes — cache hits make topology boots and suite re-runs fast
 ensure_cpu_devices(8)
 
+import glob  # noqa: E402
+import time  # noqa: E402
+
 import pytest  # noqa: E402
+
+
+def _foreign_mapped() -> set[str]:
+    """/dev/shm workspace files mapped by a live process that is neither
+    this one nor one of its descendants (another xdist worker's
+    topology, or one of its tile children)."""
+    me = os.getpid()
+    ppid, maps = {}, {}
+    for d in os.listdir("/proc"):
+        if not d.isdigit():
+            continue
+        try:
+            with open(f"/proc/{d}/stat") as f:
+                ppid[int(d)] = int(f.read().rsplit(")", 1)[1].split()[1])
+            with open(f"/proc/{d}/maps") as f:
+                # field 6 is the path ("(deleted)" may follow it)
+                maps[int(d)] = {
+                    ln.split()[5] for ln in f if "/dev/shm/fdt_wksp_" in ln
+                }
+        except (OSError, IndexError, ValueError):
+            continue  # the process went away while we looked
+    foreign = set()
+    for pid, paths in maps.items():
+        p = pid
+        while p not in (me, 0, 1) and p in ppid:
+            p = ppid[p]
+        if paths and p != me:
+            foreign |= paths
+    return foreign
+
+
+@pytest.fixture
+def no_shm_leak():
+    """A test must not leave a /dev/shm/fdt_wksp_* file behind (close()
+    always unlinks, even for children dead mid-boot).
+
+    Only THIS process tree's files count.  The driver runs tier-1 under
+    several xdist workers, and every worker's workspaces live in the
+    same /dev/shm: a glob alone reads another worker's LIVE topology as
+    this test's leak, which is what most of tier-1's "load-sensitive"
+    failures under six workers were.  A foreign workspace is one some
+    process outside this tree still maps; one caught between its
+    creation and its first mmap (or its last munmap and its unlink)
+    gets three short looks to resolve itself."""
+    before = set(glob.glob("/dev/shm/fdt_wksp_*"))
+    yield
+    for _ in range(3):
+        leaked = set(glob.glob("/dev/shm/fdt_wksp_*")) - before
+        if leaked:
+            foreign = _foreign_mapped()
+            # the manifest sidecar (<wksp>.dir) is never mapped: it
+            # belongs to whoever owns the workspace itself
+            leaked = {
+                p for p in leaked
+                if p.removesuffix(".dir") not in foreign
+            }
+        if not leaked:
+            return
+        time.sleep(0.3)
+    assert not leaked, f"leaked shm files: {sorted(leaked)}"
 
 
 def pytest_collection_modifyitems(config, items):

@@ -21,7 +21,6 @@ Tier-1 contract:
 
 from __future__ import annotations
 
-import glob
 import hashlib
 import os
 import signal
@@ -43,11 +42,9 @@ from firedancer_tpu.ballet import shred as SH
 
 
 @pytest.fixture(autouse=True)
-def no_shm_leak():
-    before = set(glob.glob("/dev/shm/fdt_wksp_*"))
+def _no_shm_leak(no_shm_leak):
+    """Every test here runs under conftest's /dev/shm leak check."""
     yield
-    leaked = set(glob.glob("/dev/shm/fdt_wksp_*")) - before
-    assert not leaked, f"leaked shm files: {sorted(leaked)}"
 
 
 # ---------------------------------------------------------------------------

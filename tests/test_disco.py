@@ -56,7 +56,7 @@ def test_metrics_wide_hist_domain():
     )
     mem = np.zeros(Metrics.footprint(schema), dtype=np.uint8)
     m = Metrics(mem, schema)
-    # 100 ms-class lag (PROFILE.md round 8's clamped regime) and a
+    # 100 ms-class lag (past the 16-bucket domain's clamp) and a
     # sub-ms lag must BOTH be representable in the wide hist
     m.hist_sample("wide", 100_000)
     m.hist_sample("wide", 500)

@@ -26,7 +26,6 @@ changes overlap live frags even when a spawn takes tens of seconds.
 
 from __future__ import annotations
 
-import glob
 import json
 import os
 import signal
@@ -68,11 +67,9 @@ from firedancer_tpu.ops.ed25519 import hostpath
 
 
 @pytest.fixture(autouse=True)
-def no_shm_leak():
-    before = set(glob.glob("/dev/shm/fdt_wksp_*"))
+def _no_shm_leak(no_shm_leak):
+    """Every test here runs under conftest's /dev/shm leak check."""
     yield
-    leaked = set(glob.glob("/dev/shm/fdt_wksp_*")) - before
-    assert not leaked, f"leaked shm files: {sorted(leaked)}"
 
 
 # ---------------------------------------------------------------------------

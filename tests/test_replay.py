@@ -1,5 +1,5 @@
 """pcap determinism + replay tile: write a corpus, replay it twice through
-a pipeline, assert bit-identical delivery (VERDICT round-1 item 6)."""
+a pipeline, assert bit-identical delivery (round-1 review, item 6)."""
 
 import time
 

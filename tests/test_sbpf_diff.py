@@ -7,7 +7,7 @@ environment, so the oracle here is a SECOND, independently written
 interpreter — a naive dict-driven big-int evaluator with none of the VM's
 structure — run over thousands of randomly generated programs.
 
-Round-4 corpus widening (VERDICT r3 item 5): memory ops over every
+Round-4 corpus widening (round-3 review, item 5): memory ops over every
 region (stack/heap/input, all widths, ST/STX/LDX), out-of-bounds
 accesses (fault-class agreement), BACKWARD jumps via bounded counter
 loops, lddw, and syscalls (memset/memcpy/memcmp/sha256) with the

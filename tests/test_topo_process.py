@@ -11,7 +11,6 @@ child pays a fresh-interpreter import on this host.
 
 from __future__ import annotations
 
-import glob
 import os
 import signal
 import socket
@@ -35,14 +34,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
-def no_shm_leak():
-    """Repeated runs must not leak /dev/shm/fdt_wksp_* files (ISSUE 7
-    satellite: close() always unlinks, even for children dead
-    mid-boot)."""
-    before = set(glob.glob("/dev/shm/fdt_wksp_*"))
+def _no_shm_leak(no_shm_leak):
+    """Every test here runs under conftest's /dev/shm leak check."""
     yield
-    leaked = set(glob.glob("/dev/shm/fdt_wksp_*")) - before
-    assert not leaked, f"leaked shm files: {sorted(leaked)}"
 
 
 def _relay_topo(name: str, runtime: str, pool_n: int, repeat: int,
@@ -452,3 +446,46 @@ def test_process_quic_verify_dedup_pack():
         topo.halt()
     finally:
         topo.close()
+
+
+def test_shm_leak_check_tells_foreign_workspaces_from_own():
+    """conftest's leak check under xdist: a workspace another process
+    tree still maps is that tree's business; one nobody outside this
+    tree maps — mapped here or by no one — is ours, and a leak."""
+    import mmap
+    import subprocess
+    import sys
+
+    from conftest import _foreign_mapped
+
+    own = f"/dev/shm/fdt_wksp_leakown{os.getpid()}"
+    theirs = f"/dev/shm/fdt_wksp_leakfar{os.getpid()}"
+    holder = far_pid = None
+    try:
+        for p in (own, theirs):
+            with open(p, "wb") as f:
+                f.write(b"\0" * 4096)
+        with open(own, "r+b") as f:
+            mine = mmap.mmap(f.fileno(), 4096)
+        # a process outside this tree: double-forked, so its parent is
+        # not this test process
+        holder = subprocess.Popen(
+            [sys.executable, "-c",
+             "import mmap, os, sys, time\n"
+             "if os.fork(): sys.exit(0)\n"
+             f"f = open({theirs!r}, 'r+b'); m = mmap.mmap(f.fileno(), 4096)\n"
+             "print(os.getpid(), flush=True); time.sleep(60)"],
+            stdout=subprocess.PIPE, text=True,
+        )
+        far_pid = int(holder.stdout.readline())
+        holder.wait(timeout=30)  # the intermediate is gone: reparented
+        foreign = _foreign_mapped()
+        assert theirs in foreign and own not in foreign
+        mine.close()
+    finally:
+        if far_pid is not None:
+            os.kill(far_pid, 9)
+        if holder is not None:
+            holder.wait(timeout=10)
+        for p in (own, theirs):
+            os.unlink(p)

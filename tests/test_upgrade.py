@@ -31,7 +31,6 @@ import on this host, and the new-tree test pays one probe subprocess.
 from __future__ import annotations
 
 import copy
-import glob
 import json
 import os
 import shutil
@@ -61,11 +60,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
-def no_shm_leak():
-    before = set(glob.glob("/dev/shm/fdt_wksp_*"))
+def _no_shm_leak(no_shm_leak):
+    """Every test here runs under conftest's /dev/shm leak check."""
     yield
-    leaked = set(glob.glob("/dev/shm/fdt_wksp_*")) - before
-    assert not leaked, f"leaked shm files: {sorted(leaked)}"
 
 
 # ---------------------------------------------------------------------------
