@@ -56,6 +56,9 @@ Config shape (all keys optional; defaults below):
     device_select = false            # TPU conflict prefilter (python loop)
     [links]
     depth = 1024
+    [trace]                          # fdttrace span rings (disco/trace.py);
+    sample = 64                      # absent = off; 1-in-N frags by sig
+    depth = 16384                    # span events kept per tile (pow2)
     [slo]                            # asserted SLOs (disco/slo.py)
     e2e_p99_us = 50000               # omit a key = not asserted
     verify_hop_p99_us = 20000
@@ -84,7 +87,7 @@ from __future__ import annotations
 import tomllib
 from dataclasses import dataclass, field
 
-from firedancer_tpu.disco import SloConfig, Topology
+from firedancer_tpu.disco import SloConfig, Topology, TraceConfig
 from firedancer_tpu.tiles import wire
 from firedancer_tpu.tiles.dedup import DedupTile
 from firedancer_tpu.tiles.quic import QuicIngressTile
@@ -142,6 +145,10 @@ class Config:
     shred_version: int = 1
     metrics_port: int = 0
     rpc_port: int = 0
+    #: fdttrace span rings from the `[trace]` section (the operator's
+    #: switch for what tests reach through Topology.enable_trace); None =
+    #: no section = off: no ring is allocated, no tracer installed
+    trace: TraceConfig | None = None
     #: asserted SLOs from the `[slo]` section; None = none asserted
     slo: SloConfig | None = None
     #: elastic-topology policy from the `[elastic]` section
@@ -209,12 +216,23 @@ def parse(text: str) -> Config:
         shred_version=t.get("shred", {}).get("version", 1),
         metrics_port=t.get("metric", {}).get("port", 0),
         rpc_port=t.get("rpc", {}).get("port", 0),
+        trace=_parse_trace(doc["trace"]) if "trace" in doc else None,
         slo=SloConfig.from_dict(doc["slo"]) if "slo" in doc else None,
         elastic=(
             _parse_elastic(doc["elastic"]) if "elastic" in doc else None
         ),
         raw=doc,
     )
+
+
+def _parse_trace(doc: dict) -> TraceConfig:
+    unknown = set(doc) - {"sample", "depth"}
+    if unknown:
+        raise ValueError(f"[trace]: unknown keys {sorted(unknown)}")
+    tc = TraceConfig(**doc)
+    if tc.sample > 0 and (tc.depth <= 0 or tc.depth & (tc.depth - 1)):
+        raise ValueError(f"[trace] depth = {tc.depth}: not a power of two")
+    return tc
 
 
 def _parse_elastic(doc: dict):
@@ -296,7 +314,9 @@ def build_validator_topology(cfg: Config, identity_secret: bytes,
         and nb_prov > 1
     )
     verify_devs = _verify_device_split(cfg, n, n_prov)
-    topo = Topology(name=cfg.name, runtime=cfg.runtime, stem=cfg.stem)
+    topo = Topology(
+        name=cfg.name, trace=cfg.trace, runtime=cfg.runtime, stem=cfg.stem
+    )
     # asserted SLOs ride the topology: build() allocates the shared slo
     # gauge region and the manifest carries the config to attached
     # monitors (disco/slo.py, disco/flight.py)
@@ -430,7 +450,9 @@ def build_ingress_topology(
 ) -> tuple[Topology, QuicIngressTile]:
     """The production ingress shape: quic -> N seq-sharded verify ->
     dedup -> sink (reference connection map, config.c:681-712)."""
-    topo = Topology(name=cfg.name, runtime=cfg.runtime, stem=cfg.stem)
+    topo = Topology(
+        name=cfg.name, trace=cfg.trace, runtime=cfg.runtime, stem=cfg.stem
+    )
     topo.slo = cfg.slo
     adm, stakes = _quic_policy(cfg)
     qt = QuicIngressTile(

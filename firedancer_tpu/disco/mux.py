@@ -43,6 +43,12 @@ def now_ts() -> int:
     return (time.monotonic_ns() // 1000) & 0xFFFFFFFF
 
 
+def ns_to_ts(ns: int) -> int:
+    """A time.monotonic_ns() reading in now_ts()'s domain (a caller that
+    already holds the ns reading saves the second clock read)."""
+    return (ns // 1000) & 0xFFFFFFFF
+
+
 # -- wrap-safe compressed-timestamp arithmetic ------------------------------
 #
 # now_ts() values live on a u32 ring (2^32 µs ~ 71 min); a plain Python

@@ -9,8 +9,8 @@ metrics regions (disco/metrics.py): single writer (the tile's mux
 thread), lock-free, torn-read-tolerant, readable by any process that
 maps the workspace.  The run loop (disco/mux.py) writes span events at
 its fixed points (frag ingest, publish, housekeeping, backpressure) and
-the verify device pool adds its own (enqueue, dispatch, land, fallback,
-quarantine); `scripts/fdttrace.py` drains the rings and assembles
+the verify device pool adds its own (a batch's lifecycle: stage, enqueue,
+dispatch, land, published — plus fallback and quarantine); `scripts/fdttrace.py` drains the rings and assembles
 per-frag timelines keyed by (link, seq, sig).
 
 Sampling: 1-in-N by the frag's sig field.  The sig is the dedup tag and
@@ -39,7 +39,10 @@ Python reader drains indistinguishably from Tracer's.  The layout
 constants below (_HDR_WORDS, EVENT_WORDS, header word meanings, INGEST/
 PUBLISH kinds) are therefore SHARED FORMAT: changing any of them means
 changing fdt_trace.c in the same commit, and the differential tests in
-tests/test_fdttrace_native.py pin the two byte-identical.
+tests/test_fdttrace_native.py pin the two byte-identical.  Every other
+kind — the verify pool's STAGE/ENQUEUE/DISPATCH/LAND/PUBLISHED among
+them — is Python-only: fdt_trace.c emits INGEST and PUBLISH and nothing
+else, so a new kind number needs no C change.
 """
 
 from __future__ import annotations
@@ -60,11 +63,15 @@ LAND = 7        # verify pool: batch landed (aux16 = device idx)
 FALLBACK = 8    # verify pool: batches served by the strict host path
 QUARANTINE = 9  # verify pool: a device domain degraded (aux16 = device idx)
 FAULT = 10      # faultinj / supervisor annotation (aux16 = FAULT_CODES)
+STAGE = 11      # verify pool: the batch's oldest frag was staged (ts = its
+                # mux-loop ingest time; written with ENQUEUE, at submit)
+PUBLISHED = 12  # verify pool: the batch's last verdict was published
 
 KIND_NAMES = {
     INGEST: "ingest", PUBLISH: "publish", HK: "hk", BP: "bp",
     ENQUEUE: "enqueue", DISPATCH: "dispatch", LAND: "land",
     FALLBACK: "fallback", QUARANTINE: "quarantine", FAULT: "fault",
+    STAGE: "stage", PUBLISHED: "published",
 }
 
 #: aux16 codes for FAULT events — injected faults (disco/faultinj.py)

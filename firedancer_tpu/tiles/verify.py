@@ -38,6 +38,7 @@ per-frag work is vectorized numpy; the Python loop body is O(1) per batch.
 from __future__ import annotations
 
 import collections
+import contextlib
 import queue
 import threading
 import time
@@ -46,16 +47,47 @@ import numpy as np
 
 from firedancer_tpu.disco import trace as SPAN
 from firedancer_tpu.disco.metrics import MetricsSchema, device_counters
-from firedancer_tpu.disco.mux import MuxCtx, Tile, now_ts
+from firedancer_tpu.disco.mux import MuxCtx, Tile, now_ts, ns_to_ts, ts_diff
 from firedancer_tpu.tango import rings as R
+from firedancer_tpu.tango.tempo import tickcount
 
 from . import wire
+
+#: where a device batch's time goes, sampled once per batch when its last
+#: verdict is published (wide log2 hists, compressed-us clock of now_ts):
+#: t_first -> t_submit (a pool slot / more lanes), t_submit -> t_disp (the
+#: worker's request queue), t_disp -> t_land (H2D, the batches ahead on the
+#: chip, the kernel, D2H), t_land -> t_pub (the mux thread's turn, then
+#: out-link credits)
+BATCH_HISTS = (
+    "batch_fill_us", "batch_queue_us", "batch_inflight_us", "batch_drain_us",
+)
+#: the mux thread's self-time by phase, wall ns (tango.tempo.tickcount):
+#: on_frags up to staging; _submit_front; _land_results and _publish_ready
+#: on the iterations that landed or published something; and the wall time
+#: during which the pool refused new work (_pool_open): the tile then holds
+#: its frags in the ring (in_budget) or staged, waiting for a slot
+PHASE_COUNTERS = (
+    "expand_ns", "submit_ns", "results_ns", "publish_ns", "pool_full_ns",
+)
+#: what of a batch's meta outlives its landing, until its last publish
+_LIFE_KEYS = (
+    "t_first", "t_submit", "t_disp", "t_land", "t_dev", "pool_seq", "lanes",
+)
 
 #: reference: VERIFY_TCACHE_DEPTH 16 (fd_verify.h:6) — a tiny per-tile
 #: pre-dedup catching back-to-back duplicates before they burn device time
 PRE_DEDUP_DEPTH = 16
 
 _STOP = object()
+
+_NULL_SPAN = contextlib.nullcontext()
+
+
+def _no_span(name: str, **kw):
+    """The span factory of a process that holds no JAX backend: the
+    per-batch `fdt.verify.*` spans (VerifyTile._span) cost one call."""
+    return _NULL_SPAN
 
 
 class FallbackPolicy:
@@ -308,9 +340,12 @@ class _DeviceWorker:
     """
 
     def __init__(self, policy: FallbackPolicy, depth: int = 3,
-                 name: str = "verify-dev"):
+                 name: str = "verify-dev", span=_no_span):
         self.policy = policy
         self.depth = depth
+        #: host-span factory (VerifyTile._span): `fdt.verify.dispatch` and
+        #: `fdt.verify.land` wrap this thread's two device calls
+        self.span = span
         self.reqq: queue.Queue = queue.Queue(maxsize=depth)
         self.results: collections.deque = collections.deque()
         self.pending: collections.deque = collections.deque()
@@ -434,10 +469,11 @@ class _DeviceWorker:
                     # that wedges must leave the batch recoverable
                     slot = [meta, args, mode, None]
                     pending.append(slot)
-                    # span timestamps ride the meta dict (plain writes on
-                    # this worker thread); the MUX thread turns them into
-                    # DISPATCH/LAND span events when the batch lands —
-                    # the span ring itself stays single-writer
+                    # the batch's lifecycle stamps ride the meta dict
+                    # (plain writes on this worker thread); the MUX
+                    # thread samples them into the batch_*_us hists and
+                    # turns them into DISPATCH/LAND span events — the
+                    # metrics region and the span ring stay single-writer
                     meta["t_disp"] = now_ts()
                     meta["t_dev"] = getattr(self.policy, "index", 0)
                     if mode == "host":
@@ -447,7 +483,12 @@ class _DeviceWorker:
                         # but the H2D put inside it can block on a sick
                         # device, so the watchdog window covers it too
                         self.land_t0 = time.monotonic()
-                        slot[3] = self.policy.dispatch(args)
+                        with self.span(
+                            "fdt.verify.dispatch",
+                            seq=meta.get("pool_seq", 0),
+                            lanes=meta["lanes"],
+                        ):
+                            slot[3] = self.policy.dispatch(args)
                         self.land_t0 = 0.0
                 if pending:
                     meta, args, mode, fut = pending[0]
@@ -457,7 +498,12 @@ class _DeviceWorker:
                     # when the batch has run, and it is where an async
                     # dispatch surfaces a runtime error
                     self.land_t0 = time.monotonic()
-                    ok = self.policy.land(fut, args, meta["lanes"])
+                    with self.span(
+                        "fdt.verify.land",
+                        seq=meta.get("pool_seq", 0),
+                        lanes=meta["lanes"],
+                    ):
+                        ok = self.policy.land(fut, args, meta["lanes"])
                     self.land_t0 = 0.0
                     meta["t_land"] = now_ts()
                     self.policy.stalled = False  # the call returned
@@ -492,10 +538,11 @@ class _DevicePool:
     thread only; workers touch only their own queues/results.
     """
 
-    def __init__(self, policies: list, depth: int = 3, name: str = "verify"):
+    def __init__(self, policies: list, depth: int = 3, name: str = "verify",
+                 span=_no_span):
         self.policies = policies
         self.workers = [
-            _DeviceWorker(p, depth, name=f"{name}-dev{i}")
+            _DeviceWorker(p, depth, name=f"{name}-dev{i}", span=span)
             for i, p in enumerate(policies)
         ]
         self.aborted = False
@@ -565,6 +612,9 @@ class _DevicePool:
         seq = self.next_seq
         self.next_seq += 1
         meta["pool_seq"] = seq
+        # stamped BEFORE the worker can see the batch, so t_submit <=
+        # t_disp holds; a resubmission keeps the first acceptance
+        meta["t_submit"] = now_ts()
         self.outstanding[seq] = [meta, args, mode, tgt]
         self.workers[tgt].submit(meta, args, mode)
         return True
@@ -813,8 +863,10 @@ class VerifyTile(Tile):
                 # for the length of a cold compile
                 "device_programs",
             )
+            + PHASE_COUNTERS
             + device_counters(self.n_devices),
-            hists=("lane_batch",),
+            hists=("lane_batch",) + BATCH_HISTS,
+            wide_hists=BATCH_HISTS,
         )
         self._tc: R.TCache | None = None
         self._fns: list | None = None
@@ -825,6 +877,15 @@ class VerifyTile(Tile):
         self._prev_fallback = 0  # FALLBACK span edge detector
         self._prev_degraded: dict[int, int] = {}  # QUARANTINE edges
         self._mirror_tick = 0
+        #: host-span factory for the per-batch steps: jax.profiler's
+        #: TraceAnnotation once THIS process has imported JAX for the
+        #: device path (_make_device_fns) — no other tile imports it
+        self._span = _no_span
+        self._next_clock_ns = 0  # next `fdt.clock` tie (housekeeping)
+        #: PHASE_COUNTERS accumulators: plain ints the mux thread adds to
+        #: per burst, flushed to the metrics region every 16th iteration
+        self._phase_ns = dict.fromkeys(PHASE_COUNTERS, 0)
+        self._full_t0 = 0  # tickcount of the pool's first open refusal
         #: staged host-prepared lanes not yet submitted (list of dicts)
         self._staged: collections.deque = collections.deque()
         self._staged_lanes = 0
@@ -858,6 +919,7 @@ class VerifyTile(Tile):
         if self._fns is None:
             import jax
 
+            self._span = jax.profiler.TraceAnnotation
             from firedancer_tpu.ops.ed25519 import verify as fver
             from firedancer_tpu.utils.hostdev import (
                 enable_compilation_cache,
@@ -976,7 +1038,8 @@ class VerifyTile(Tile):
             # supervisor restarts; only the worker threads are per-life
             self._policies = self._build_policies()
         self._pool = _DevicePool(
-            self._policies, self.async_depth, name=self.name
+            self._policies, self.async_depth, name=self.name,
+            span=self._span,
         )
 
     def _build_policies(self) -> list:
@@ -1050,12 +1113,41 @@ class VerifyTile(Tile):
         self._fns = None
         self._policies = self._build_policies()
         self._pool = _DevicePool(
-            self._policies, self.async_depth, name=self.name
+            self._policies, self.async_depth, name=self.name,
+            span=self._span,
         )
 
     # ---- ingress: host prep + staging -----------------------------------
 
     def on_frags(self, ctx: MuxCtx, in_idx: int, frags: np.ndarray) -> None:
+        # two clock reads a burst: the first is also the burst's ingest
+        # time — the t_first of a batch whose oldest frag it staged
+        t0 = tickcount()
+        self._stage(ctx, in_idx, frags, ns_to_ts(t0))
+        self._phase_ns["expand_ns"] += tickcount() - t0
+        # submit only while the pool has room: a full pool means every
+        # device pipe is behind, and the right response is to hold frags
+        # in the RING (in_budget -> credit backpressure), not to block
+        # this thread past its heartbeat deadline
+        while self._staged_lanes >= self.max_lanes and self._pool_open():
+            self._submit_front(self.max_lanes)
+
+    def _pool_open(self) -> bool:
+        """pool.can_accept(), with the wall time from the first refusal
+        to the next acceptance counted into pool_full_ns: a clock read
+        at each edge, none on the turns between."""
+        if self._pool.can_accept():
+            if self._full_t0:
+                self._phase_ns["pool_full_ns"] += tickcount() - self._full_t0
+                self._full_t0 = 0
+            return True
+        if not self._full_t0:
+            self._full_t0 = tickcount()
+        return False
+
+    def _stage(
+        self, ctx: MuxCtx, in_idx: int, frags: np.ndarray, t_ingest: int
+    ) -> None:
         il = ctx.ins[in_idx]
         if self.elastic is not None:
             # elastic seq sharding (disco/elastic.py): assignment is a
@@ -1089,17 +1181,9 @@ class VerifyTile(Tile):
         # ring seq per txn, carried through staging -> device -> publish
         # so ack_floor can hold the fseq at the oldest unflushed frag
         b["seqs"] = frags["seq"].copy()
+        b["t_first"] = t_ingest
         self._staged.append(b)
         self._staged_lanes += lanes
-        # submit only while the pool has room: a full pool means every
-        # device pipe is behind, and the right response is to hold frags
-        # in the RING (in_budget -> credit backpressure), not to block
-        # this thread past its heartbeat deadline
-        while (
-            self._staged_lanes >= self.max_lanes
-            and self._pool.can_accept()
-        ):
-            self._submit_front(self.max_lanes)
 
     def elastic_drained(self, ctx: MuxCtx) -> bool:
         """Retirement drain contract (disco/elastic.py): beyond the
@@ -1142,8 +1226,7 @@ class VerifyTile(Tile):
         # stop draining the ring when the device pool is full or results
         # are waiting on downstream credits — backpressure flows upstream
         # through the ring's credit model, not an unbounded host buffer
-        p = self._pool
-        if p is not None and not p.can_accept():
+        if self._pool is not None and not self._pool_open():
             return 0
         if self._staged_lanes >= 2 * self.max_lanes:
             return 0
@@ -1156,6 +1239,7 @@ class VerifyTile(Tile):
     def _submit_front(self, lanes_cap: int) -> None:
         """Concatenate staged chunks into one device batch of <= lanes_cap
         lanes (whole txns only) and push it to the pool."""
+        t0 = tickcount()
         take, lanes = [], 0
         while self._staged:
             chunk = self._staged[0]
@@ -1186,31 +1270,38 @@ class VerifyTile(Tile):
         if not take:
             return
         self._staged_lanes -= lanes
-        if len(take) == 1:
-            b = take[0]
-        else:
-            b = {
-                k: np.concatenate([c[k] for c in take])
-                for k in take[0]
-            }
-        pad = (
-            self.max_lanes
-            if self.pad_full
-            else 1 << max(lanes - 1, 0).bit_length()
-        )
-        meta = dict(
-            rows=b["rows"], szs=b["szs"], tsorigs=b["tsorigs"],
-            sig_cnt=b["sig_cnt"], tags=b["tags"], seqs=b["seqs"],
-            lanes=lanes,
-        )
-        self._submit(
-            meta,
-            (
-                _pad2(b["digests"], pad),
-                _pad2(b["sigs"], pad),
-                _pad2(b["pubs"], pad),
-            ),
-        )
+        with self._span(
+            "fdt.verify.submit", seq=self._pool.next_seq, lanes=lanes
+        ):
+            if len(take) == 1:
+                b = take[0]
+            else:
+                b = {
+                    k: np.concatenate([c[k] for c in take])
+                    for k in take[0]
+                    if k != "t_first"
+                }
+            pad = (
+                self.max_lanes
+                if self.pad_full
+                else 1 << max(lanes - 1, 0).bit_length()
+            )
+            meta = dict(
+                rows=b["rows"], szs=b["szs"], tsorigs=b["tsorigs"],
+                sig_cnt=b["sig_cnt"], tags=b["tags"], seqs=b["seqs"],
+                lanes=lanes,
+                # the deque is FIFO: the first chunk holds the oldest frag
+                t_first=take[0]["t_first"],
+            )
+            self._submit(
+                meta,
+                (
+                    _pad2(b["digests"], pad),
+                    _pad2(b["sigs"], pad),
+                    _pad2(b["pubs"], pad),
+                ),
+            )
+        self._phase_ns["submit_ns"] += tickcount() - t0
 
     def _submit(self, meta, args) -> None:
         """Interruptible submit: a full pool behind a slow host path
@@ -1228,10 +1319,15 @@ class VerifyTile(Tile):
                 raise TileInterrupted(f"{self.name}: submit abandoned")
             if pool.submit(meta, args):
                 if self._tracer is not None:
+                    seq = meta["pool_seq"]
+                    lanes16 = min(meta["lanes"], 0xFFFF)
                     self._tracer.point(
-                        SPAN.ENQUEUE,
-                        seq=meta["pool_seq"],
-                        aux16=min(meta["lanes"], 0xFFFF),
+                        SPAN.STAGE, ts=meta["t_first"], seq=seq,
+                        aux16=lanes16,
+                    )
+                    self._tracer.point(
+                        SPAN.ENQUEUE, ts=meta["t_submit"], seq=seq,
+                        aux16=lanes16,
                     )
                 return
             # no capacity anywhere: poll (stall watchdog + retry pump
@@ -1242,58 +1338,94 @@ class VerifyTile(Tile):
     # ---- egress: results -> publish --------------------------------------
 
     def _land_results(self, ctx: MuxCtx) -> None:
+        t0 = tickcount()
         pool = self._pool
         pool.check_fatal()
         pool.poll()
+        if not pool.ready:
+            return
         while pool.ready:
             meta, ok = pool.ready.popleft()
-            lanes = meta["lanes"]
-            ok = ok[:lanes]
-            if self._tracer is not None:
-                # dispatch/land timestamps were stamped into the meta by
-                # the worker thread; emitted here so the span ring keeps
-                # its single writer (this mux thread)
-                dev = int(meta.get("t_dev", 0)) & 0xFF
-                seq = meta.get("pool_seq", 0)
-                if "t_disp" in meta:
-                    self._tracer.point(
-                        SPAN.DISPATCH, ts=meta["t_disp"], seq=seq,
-                        aux16=dev,
-                    )
-                self._tracer.point(
-                    SPAN.LAND, ts=meta.get("t_land"), seq=seq, aux16=dev,
-                    aux64=lanes,
-                )
-            ctx.metrics.inc("verified_sigs", lanes)
-            ctx.metrics.inc("device_batches")
-            ctx.metrics.hist_sample("lane_batch", lanes)
-            cnt = meta["sig_cnt"]
-            starts = np.concatenate(([0], np.cumsum(cnt)[:-1]))
-            txn_ok = (
-                np.logical_and.reduceat(ok, starts)
-                if lanes
-                else np.zeros(0, bool)
+            with self._span(
+                "fdt.verify.results", seq=meta["pool_seq"],
+                lanes=meta["lanes"],
+            ):
+                self._land_batch(ctx, meta, ok)
+        self._phase_ns["results_ns"] += tickcount() - t0
+
+    def _land_batch(self, ctx: MuxCtx, meta: dict, ok: np.ndarray) -> None:
+        """One landed batch: lane verdicts -> per-txn verdicts -> the
+        credit-gated publish queue."""
+        lanes = meta["lanes"]
+        ok = ok[:lanes]
+        if self._tracer is not None:
+            # dispatch/land timestamps were stamped into the meta by
+            # the worker thread; emitted here so the span ring keeps
+            # its single writer (this mux thread)
+            dev = int(meta["t_dev"]) & 0xFF
+            seq = meta["pool_seq"]
+            self._tracer.point(
+                SPAN.DISPATCH, ts=meta["t_disp"], seq=seq, aux16=dev,
             )
-            n_fail = int((~txn_ok).sum())
-            if n_fail:
-                ctx.metrics.inc("verify_fail_txns", n_fail)
-            if not txn_ok.any():
-                continue
-            # dedup tag: first 8 bytes of the first signature, LE u64
-            # (reference: fd_dedup keys the tango sig field, fd_dedup.c:125)
-            # — computed by fdt_verify_expand at staging time
-            self._outq.append(
-                dict(
-                    tags=meta["tags"][txn_ok],
-                    rows=meta["rows"][txn_ok],
-                    szs=meta["szs"][txn_ok].astype(np.uint16),
-                    tsorigs=meta["tsorigs"][txn_ok],
-                    seqs=meta["seqs"][txn_ok],
-                )
+            self._tracer.point(
+                SPAN.LAND, ts=meta["t_land"], seq=seq, aux16=dev,
+                aux64=lanes,
             )
-            self._outq_txns += int(txn_ok.sum())
+        ctx.metrics.inc("verified_sigs", lanes)
+        ctx.metrics.inc("device_batches")
+        ctx.metrics.hist_sample("lane_batch", lanes)
+        cnt = meta["sig_cnt"]
+        starts = np.concatenate(([0], np.cumsum(cnt)[:-1]))
+        txn_ok = (
+            np.logical_and.reduceat(ok, starts)
+            if lanes
+            else np.zeros(0, bool)
+        )
+        n_fail = int((~txn_ok).sum())
+        if n_fail:
+            ctx.metrics.inc("verify_fail_txns", n_fail)
+        if not txn_ok.any():
+            self._batch_published(ctx, meta)  # nothing of it to publish
+            return
+        # dedup tag: first 8 bytes of the first signature, LE u64
+        # (reference: fd_dedup keys the tango sig field, fd_dedup.c:125)
+        # — computed by fdt_verify_expand at staging time
+        self._outq.append(
+            dict(
+                tags=meta["tags"][txn_ok],
+                rows=meta["rows"][txn_ok],
+                szs=meta["szs"][txn_ok].astype(np.uint16),
+                tsorigs=meta["tsorigs"][txn_ok],
+                seqs=meta["seqs"][txn_ok],
+                # the stamps alone: the batch's arrays are not kept alive
+                # for as long as its verdicts wait for credits
+                life={k: meta[k] for k in _LIFE_KEYS},
+            )
+        )
+        self._outq_txns += int(txn_ok.sum())
+
+    def _batch_published(self, ctx: MuxCtx, meta: dict) -> None:
+        """The batch's last verdict has left: take t_pub and sample its
+        lifecycle (BATCH_HISTS) from the five stamps (`meta` holds at
+        least _LIFE_KEYS)."""
+        t_pub = now_ts()
+        m = ctx.metrics
+        stamps = (
+            meta["t_first"], meta["t_submit"], meta["t_disp"],
+            meta["t_land"], t_pub,
+        )
+        for name, a, b in zip(BATCH_HISTS, stamps, stamps[1:]):
+            m.hist_sample(name, max(ts_diff(b, a), 0))
+        if self._tracer is not None:
+            self._tracer.point(
+                SPAN.PUBLISHED, ts=t_pub, seq=meta["pool_seq"],
+                aux16=int(meta["t_dev"]) & 0xFF, aux64=meta["lanes"],
+            )
 
     def _publish_ready(self, ctx: MuxCtx) -> None:
+        if not self._outq:
+            return
+        t0 = tickcount()
         while self._outq and ctx.credits > 0:
             b = self._outq[0]
             n = len(b["tags"])
@@ -1302,6 +1434,7 @@ class VerifyTile(Tile):
                 ctx.publish(b["tags"], b["rows"], b["szs"], tsorigs=b["tsorigs"])
                 ctx.credits -= n
                 self._outq_txns -= n
+                self._batch_published(ctx, b["life"])
             else:
                 m = ctx.credits
                 ctx.publish(
@@ -1312,6 +1445,7 @@ class VerifyTile(Tile):
                     b[k] = b[k][m:]
                 ctx.credits = 0
                 self._outq_txns -= m
+        self._phase_ns["publish_ns"] += tickcount() - t0
 
     def after_credit(self, ctx: MuxCtx) -> None:
         self._land_results(ctx)
@@ -1320,9 +1454,23 @@ class VerifyTile(Tile):
             self._maybe_repartition()
         # keep the devices fed: push a partial batch when the pool has
         # room and nothing fuller is coming (trickle traffic)
-        if self._staged_lanes and self._pool.can_accept():
+        if self._staged_lanes and self._pool_open():
             self._submit_front(self.max_lanes)
         self._mirror_policy_metrics(ctx)
+
+    def during_housekeeping(self, ctx: MuxCtx) -> None:
+        """Once a second, in the process that holds the chip: a
+        zero-length `fdt.clock` host span whose `mono_ns` argument is
+        time.monotonic_ns() at its start — a reader of the profiler's
+        trace gets that clock's offset to the span ring's and the
+        metrics' (monotonic) clock from any one of them."""
+        if self._span is _no_span:
+            return
+        now = tickcount()
+        if now >= self._next_clock_ns:
+            self._next_clock_ns = now + 1_000_000_000
+            with self._span("fdt.clock", mono_ns=now):
+                pass
 
     def _mirror_policy_metrics(self, ctx: MuxCtx) -> None:
         """Expose the pool's degradation state in the shared metrics
@@ -1346,6 +1494,14 @@ class VerifyTile(Tile):
         self._mirror_tick += 1
         if (self._mirror_tick & 0xF) != 1:
             return
+        if self._full_t0:  # a refusal still open: count it up to now
+            now = tickcount()
+            self._phase_ns["pool_full_ns"] += now - self._full_t0
+            self._full_t0 = now
+        for name, ns in self._phase_ns.items():
+            if ns:
+                m.inc(name, ns)  # inc, not set: monotone across restarts
+                self._phase_ns[name] = 0
         m.set("device_programs", self._program_count())
         now = time.monotonic()
         for i, w in enumerate(pool.workers):
@@ -1395,6 +1551,7 @@ class VerifyTile(Tile):
         self._staged_lanes = 0
         self._outq.clear()
         self._outq_txns = 0
+        self._full_t0 = 0
 
     def on_halt(self, ctx: MuxCtx) -> None:
         # drain everything: staged -> devices -> results -> downstream.
@@ -1491,6 +1648,8 @@ def _split_chunk(chunk: dict, k_txns: int, k_lanes: int) -> tuple[dict, dict]:
         head[key], tail[key] = chunk[key][:k_txns], chunk[key][k_txns:]
     for key in ("digests", "sigs", "pubs"):
         head[key], tail[key] = chunk[key][:k_lanes], chunk[key][k_lanes:]
+    # both halves were ingested by the same burst
+    head["t_first"] = tail["t_first"] = chunk["t_first"]
     return head, tail
 
 

@@ -7,6 +7,8 @@ Usage:
     scripts/fdttrace.py WKSP --out trace.json    # Chrome trace-event JSON
     scripts/fdttrace.py WKSP --follow [-i 2.0]   # live summary loop
     scripts/fdttrace.py WKSP --seconds 2 --out t.json   # longer capture
+    scripts/fdttrace.py WKSP --out t.json --xplane FILE.xplane.pb
+                                  # + the profiler's trace, on the same clock
 
 WKSP is the topology's workspace name (Topology(name=...) with
 enable_trace(); the manifest published at start() carries the span-ring
@@ -25,12 +27,23 @@ faults (disco/faultinj.py) and supervisor restarts appear on each
 tile's fault track, so a kill -> restart gap is visible in the trace
 and assertable from `classify()` (a timeline is whole, or it is lost
 with its furthest-reached hop named).
+
+The verify tile's device-pool track draws each device batch's lifecycle
+from its five span events (STAGE, ENQUEUE, DISPATCH, LAND, PUBLISHED,
+matched by pool seq) as four back-to-back spans: fill, queue, the
+device's batch, drain.  `--xplane` lays a jax.profiler trace of the
+process that holds the chip under the same time axis: the tile writes a
+`fdt.clock` host span once a second whose `mono_ns` argument is
+time.monotonic_ns() at its start, which ties the profiler's clock to
+the span rings' (`profiler_events`).
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import re
+import statistics
 import sys
 import time
 from pathlib import Path
@@ -43,7 +56,11 @@ from firedancer_tpu.disco.metrics import (  # noqa: E402
     MetricsSchema,
     hist_percentile,
 )
-from firedancer_tpu.disco.mux import LINK_HIST_KINDS, ts_diff  # noqa: E402
+from firedancer_tpu.disco.mux import (  # noqa: E402
+    LINK_HIST_KINDS,
+    ns_to_ts,
+    ts_diff,
+)
 from firedancer_tpu.tango import rings as R  # noqa: E402
 
 #: per-tile sub-tracks in the Chrome trace (tid = tile_index * 4 + facet)
@@ -207,9 +224,58 @@ def _anchor(session: TraceSession) -> int:
     return 0
 
 
-def chrome_trace(session: TraceSession) -> list[dict]:
+def profiler_events(xplane_path: str) -> tuple[int | None, list[dict]]:
+    """A jax.profiler trace (`*.xplane.pb`) -> (offset_ns, events), the
+    events being the program's own host spans (`fdt.*`) and every device
+    op, each {"track", "name", "start_ns", "dur_ns"} on the PROFILER's
+    clock.  offset_ns = time.monotonic_ns() - profiler ns, taken from
+    the `fdt.clock` spans the verify tile writes once a second (their
+    `mono_ns` argument is the monotonic clock at their start; the median
+    over the trace's ties); None when the trace holds no tie.  With it,
+    `start_ns + offset_ns` is a time on the clock of the span rings, the
+    metrics' stamps and any harness that reads time.monotonic_ns()."""
+    from jax.profiler import ProfileData
+
+    pd = ProfileData.from_file(xplane_path)
+    ties, events = [], []
+    for plane in pd.planes:
+        device = plane.name.startswith("/device:")
+        for line in plane.lines:
+            if device and not line.name.startswith("XLA Ops"):
+                continue
+            for e in line.events:
+                name = e.name
+                if not device and not name.startswith("fdt."):
+                    continue
+                if name.startswith("fdt.clock"):
+                    # the profiler keeps an annotation's arguments as the
+                    # event's stats, or (other versions) inside its name
+                    # as `fdt.clock#mono_ns=...#`
+                    mono = dict(e.stats).get("mono_ns")
+                    if mono is None:
+                        m = re.search(r"mono_ns=(\d+)", name)
+                        mono = m and m.group(1)
+                    if mono is not None:
+                        ties.append(int(mono) - int(e.start_ns))
+                events.append(
+                    {
+                        "track": f"{plane.name} {line.name}",
+                        "name": name.split("#", 1)[0],
+                        "start_ns": int(e.start_ns),
+                        "dur_ns": int(e.duration_ns),
+                    }
+                )
+    offset = int(statistics.median(ties)) if ties else None
+    return offset, events
+
+
+def chrome_trace(
+    session: TraceSession, profiler: tuple[int, list[dict]] | None = None
+) -> list[dict]:
     """Span events -> Chrome trace-event JSON (list of "X" events,
-    strictly sorted per (pid, tid) track)."""
+    strictly sorted per (pid, tid) track).  `profiler` = what
+    profiler_events returned (with a clock tie): its events go under
+    pid 2, one track per profiler line, on the rings' time axis."""
     anchor = _anchor(session)
     rel0 = min(
         (
@@ -283,32 +349,46 @@ def chrome_trace(session: TraceSession) -> list[dict]:
                         },
                     }
                 )
-        # device-pool track: ENQUEUE -> DISPATCH wait + DISPATCH -> LAND
-        # service, matched by pool seq
-        enq = {e["seq"]: e for e in evs if e["kind"] == T.ENQUEUE}
-        disp = {e["seq"]: e for e in evs if e["kind"] == T.DISPATCH}
+        # device-pool track: one batch's lifecycle, matched by pool seq —
+        # STAGE -> ENQUEUE (fill: waiting for a pool slot / more lanes),
+        # ENQUEUE -> DISPATCH (the worker's request queue), DISPATCH ->
+        # LAND (the device's batch), LAND -> PUBLISHED (drain).  A ring
+        # lap can lose any of the five; a span needs both its ends.
+        by_kind = {
+            k: {e["seq"]: e for e in evs if e["kind"] == k}
+            for k in (T.STAGE, T.ENQUEUE, T.DISPATCH, T.PUBLISHED)
+        }
         for e in evs:
             if e["kind"] != T.LAND:
                 continue
             seq = e["seq"]
-            d, q = disp.get(seq), enq.get(seq)
-            t_end = us(e["ts"])
-            if d is not None:
+            st, q, d, pub = (
+                by_kind[k].get(seq)
+                for k in (T.STAGE, T.ENQUEUE, T.DISPATCH, T.PUBLISHED)
+            )
+            args = {
+                "pool_seq": int(seq),
+                "lanes": int(e["aux64"]),
+                "queue_us": 0 if q is None or d is None
+                else max(us(d["ts"]) - us(q["ts"]), 0),
+            }
+            for name, a, b in (
+                (f"{tile} fill", st, q),
+                (f"{tile} queue", q, d),
+                (f"{tile} dev{e['aux16']} batch", d, e),
+                (f"{tile} drain", e, pub),
+            ):
+                if a is None or b is None:
+                    continue
                 out.append(
                     {
-                        "name": f"{tile} dev{e['aux16']} batch",
+                        "name": name,
                         "ph": "X",
                         "pid": 1,
                         "tid": tid + _FACET_DEVICE,
-                        "ts": us(d["ts"]),
-                        "dur": max(t_end - us(d["ts"]), 1),
-                        "args": {
-                            "pool_seq": int(seq),
-                            "lanes": int(e["aux64"]),
-                            "queue_us": 0
-                            if q is None
-                            else max(us(d["ts"]) - us(q["ts"]), 0),
-                        },
+                        "ts": us(a["ts"]),
+                        "dur": max(us(b["ts"]) - us(a["ts"]), 1),
+                        "args": args,
                     }
                 )
         # loop track (housekeeping + backpressure streak markers) and
@@ -354,6 +434,22 @@ def chrome_trace(session: TraceSession) -> list[dict]:
                         "args": {"detail": int(e["aux64"])},
                     }
                 )
+    if profiler is not None:
+        offset_ns, spans = profiler
+        tracks = {t: i for i, t in enumerate(
+            sorted({s["track"] for s in spans}))}
+        for s in spans:
+            out.append(
+                {
+                    "name": s["name"],
+                    "ph": "X",
+                    "pid": 2,
+                    "tid": tracks[s["track"]],
+                    "ts": us(ns_to_ts(s["start_ns"] + offset_ns)),
+                    "dur": max(s["dur_ns"] // 1000, 1),
+                    "args": {"track": s["track"]},
+                }
+            )
     # strict per-track time order (Perfetto requires monotone begins)
     out.sort(key=lambda e: (e["pid"], e["tid"], e["ts"], -e["dur"]))
     return out
@@ -437,6 +533,10 @@ def main(argv: list[str] | None = None) -> int:
                     help="span capture window for the trace export")
     ap.add_argument("--out", default=None, metavar="FILE",
                     help="write Chrome trace-event JSON here (default stdout)")
+    ap.add_argument("--xplane", default=None, metavar="FILE",
+                    help="a jax.profiler *.xplane.pb of the verify tile's "
+                    "process: its fdt.* host spans and device ops are added "
+                    "to the export, tied to the rings' clock by fdt.clock")
     args = ap.parse_args(argv)
 
     try:
@@ -474,7 +574,18 @@ def main(argv: list[str] | None = None) -> int:
     while time.monotonic() < end:
         time.sleep(min(0.05, args.seconds))
         session.drain()
-    events = chrome_trace(session)
+    profiler = None
+    if args.xplane:
+        profiler = profiler_events(args.xplane)
+        if profiler[0] is None:
+            print(
+                f"fdttrace: {args.xplane} holds no fdt.clock span: its "
+                "clock cannot be tied to the rings' (was the verify tile "
+                "serving, with JAX, while it was traced?)",
+                file=sys.stderr,
+            )
+            return 2
+    events = chrome_trace(session, profiler)
     doc = json.dumps(events)
     if args.out:
         Path(args.out).write_text(doc)

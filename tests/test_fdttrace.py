@@ -508,3 +508,174 @@ def test_trace_off_installs_no_tracer():
     # tracing (attribution is always-on; spans are the opt-in layer)
     assert "qwait_us_a_sink" in topo.metrics("sink").schema.hists
     topo.close()
+
+
+# ---------------------------------------------------------------------------
+# the verify tile's batch lifecycle in the span rings, `[trace]` in a
+# config file, and the profiler-clock tie (ISSUE 25)
+
+
+def _admit_all(digests, sigs, pubs):
+    return np.ones(len(digests), bool)
+
+
+def test_batch_lifecycle_is_in_the_ring_once_per_pool_seq():
+    """synth -> verify -> sink under the real run loop, tracing at
+    sample 1: the verify ring holds STAGE, ENQUEUE, DISPATCH, LAND and
+    PUBLISHED exactly once per pool_seq, in time order, and the Chrome
+    export draws them as fill / queue / batch / drain on one track."""
+    from firedancer_tpu.tiles.synth import SynthTile, make_txn_pool
+
+    n = 40
+    rows, szs, _ = make_txn_pool(n, seed=31)
+    verify = VerifyTile(
+        msg_width=256, max_lanes=8, pre_dedup=False, device_fn=_admit_all,
+        async_depth=2,
+    )
+    topo = Topology()
+    topo.enable_trace(sample=1, depth=1 << 12)
+    topo.link("synth_verify", depth=256, mtu=wire.LINK_MTU)
+    topo.link("verify_sink", depth=256, mtu=wire.LINK_MTU)
+    topo.tile(SynthTile(rows, szs, total=n), outs=["synth_verify"])
+    topo.tile(verify, ins=[("synth_verify", True)], outs=["verify_sink"])
+    topo.tile(SinkTile(), ins=[("verify_sink", True)])
+    topo.start(batch_max=8)
+    try:
+        deadline = time.monotonic() + 60.0
+        while topo.metrics("sink").counter("sunk_frags") < n:
+            assert time.monotonic() < deadline, "pipeline did not drain"
+            topo.poll_failure()
+            time.sleep(0.01)
+    finally:
+        topo.halt()
+    try:
+        m = topo.metrics("verify").read()
+        batches = m["device_batches"]
+        assert batches >= n // 8
+        for h in ("batch_fill_us", "batch_queue_us", "batch_inflight_us",
+                  "batch_drain_us"):
+            assert m[h]["count"] == batches, h
+        session = fdttrace.TraceSession.from_topology(topo)
+        session.drain()
+        assert sum(session.dropped.values()) == 0
+        life = (T.STAGE, T.ENQUEUE, T.DISPATCH, T.LAND, T.PUBLISHED)
+        per_seq: dict = {}
+        for e in session.events["verify"]:
+            if e["kind"] in life:
+                per_seq.setdefault(e["seq"], []).append(e)
+        assert sorted(per_seq) == list(range(batches))
+        for seq, evs in per_seq.items():
+            by_kind = {e["kind"]: e["ts"] for e in evs}
+            assert len(evs) == 5 and set(by_kind) == set(life), (seq, evs)
+            ts = [by_kind[k] for k in life]
+            assert all(ts_diff(b, a) >= 0 for a, b in zip(ts, ts[1:])), ts
+        assert [T.KIND_NAMES[k] for k in life] == [
+            "stage", "enqueue", "dispatch", "land", "published"]
+        doc = fdttrace.chrome_trace(session)
+        track = [e for e in doc if e["args"].get("pool_seq") is not None]
+        assert len({e["tid"] for e in track}) == 1
+        for part in ("fill", "queue", "dev0 batch", "drain"):
+            spans = [e for e in track if e["name"] == f"verify {part}"]
+            assert sorted(e["args"]["pool_seq"] for e in spans) == list(
+                range(batches)), part
+        # a batch's four spans abut: each starts where the last one ended
+        # (to the microsecond the 1 us floor on a span's length allows)
+        first = sorted((e for e in track if e["args"]["pool_seq"] == 0),
+                       key=lambda e: e["ts"])
+        assert [e["name"].split(" ", 1)[1] for e in first] == [
+            "fill", "queue", "dev0 batch", "drain"]
+        for a, b in zip(first, first[1:]):
+            assert 0 <= a["ts"] + a["dur"] - b["ts"] <= 1
+    finally:
+        topo.close()
+
+
+@pytest.mark.parametrize("section,want", [
+    ("", None),
+    ("[trace]\n", T.TraceConfig(sample=64, depth=1 << 14)),
+    ("[trace]\nsample = 1\ndepth = 256\n", T.TraceConfig(1, 256)),
+    ("[trace]\nsample = 0\n", "off"),
+])
+def test_trace_section_of_a_config_file_installs_the_tracers(section, want):
+    """`[trace]` is the operator's switch: a topology booted from a file
+    (fdtctl run, every benchmark cell) gets span rings from it, and none
+    without it."""
+    from firedancer_tpu.app import config as C
+
+    cfg = C.parse(
+        "[tiles.dedup]\nsignature_cache_size = 4096\n"
+        "[links]\ndepth = 64\n" + section
+    )
+    assert cfg.trace == (T.TraceConfig(sample=0) if want == "off" else want)
+    topo, _ = C.build_ingress_topology(cfg, bytes(32))
+    topo.build()
+    try:
+        if want in (None, "off"):
+            assert topo._tracers == {}
+            assert all(ts.ctx.tracer is None for ts in topo.tiles.values())
+            assert all(not k.startswith("trace_") for k in topo.wksp._allocs)
+        else:
+            assert set(topo._tracers) == set(topo.tiles)
+            tr = topo.tiles["verify0"].ctx.tracer
+            assert (tr.sample, tr.ring.depth) == (want.sample, want.depth)
+    finally:
+        topo.close()
+
+
+@pytest.mark.parametrize("text,match", [
+    ("[trace]\ndepth = 1000\n", "power of two"),
+    ("[trace]\nrate = 3\n", "unknown keys"),
+])
+def test_trace_section_rejects_what_it_cannot_run(text, match):
+    from firedancer_tpu.app import config as C
+
+    with pytest.raises(ValueError, match=match):
+        C.parse(text)
+
+
+def test_profiler_clock_is_tied_to_the_rings_by_fdt_clock(tmp_path):
+    """The CPU profiler records TraceAnnotations too: a trace that holds
+    `fdt.clock` spans gives the offset from the profiler's clock to
+    time.monotonic_ns(), and the program's `fdt.*` spans land on the
+    rings' time axis within the tie's own jitter."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        marks = []
+        for seq in range(3):
+            now = time.monotonic_ns()
+            with jax.profiler.TraceAnnotation("fdt.clock", mono_ns=now):
+                pass
+            t0 = time.monotonic_ns()
+            with jax.profiler.TraceAnnotation(
+                    "fdt.verify.dispatch", seq=seq, lanes=8):
+                time.sleep(0.002)
+            marks.append(t0)
+        with jax.profiler.TraceAnnotation("not.ours"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    offset, events = fdttrace.profiler_events(str(path))
+    assert offset is not None
+    names = [e["name"] for e in events]
+    assert names.count("fdt.clock") == 3 and "not.ours" not in names
+    spans = sorted((e for e in events if e["name"] == "fdt.verify.dispatch"),
+                   key=lambda e: e["start_ns"])
+    assert len(spans) == 3
+    for e, t0 in zip(spans, marks):
+        assert abs(e["start_ns"] + offset - t0) < 1_000_000  # < 1 ms
+        assert e["dur_ns"] >= 2_000_000
+    # on the Chrome export they sit under pid 2, on the rings' axis
+    ring = T.SpanRing(np.zeros(T.SpanRing.footprint(64), np.uint8), 64, 1)
+    T.Tracer(ring, 1).point(T.HK, ts=(marks[0] // 1000) & 0xFFFFFFFF)
+    session = fdttrace.TraceSession({"verify": ring}, [])
+    session.drain()
+    doc = fdttrace.chrome_trace(session, (offset, events))
+    ours = [e for e in doc if e["pid"] == 2
+            and e["name"] == "fdt.verify.dispatch"]
+    hk = next(e for e in doc if e["name"] == "verify hk")
+    assert len(ours) == 3 and abs(min(e["ts"] for e in ours) - hk["ts"]) < 1000
