@@ -31,6 +31,12 @@ from firedancer_tpu.ballet import shred as SH
 from firedancer_tpu.ops import reedsol as RS
 
 NORMAL_FEC_SET_PAYLOAD_SZ = 31200
+#: the most ONE 32:32 set carries: 32 data shreds of 995 payload bytes
+#: (tree depth 6).  The reference gives a batch's tail of up to twice the
+#: normal size one odd-sized set of up to 64:64; this build's sets (and the
+#: shred tile's pending store, PD_MAX) stop at 32:32, so a tail above this
+#: goes out as a normal set and a last, smaller one
+MAX_FEC_SET_PAYLOAD_SZ = 32 * 995
 
 DATA_TO_PARITY = [
     0, 17, 18, 19, 19, 20, 21, 21,
@@ -101,7 +107,7 @@ class Shredder:
             remaining = total - offset
             chunk = (
                 NORMAL_FEC_SET_PAYLOAD_SZ
-                if remaining >= 2 * NORMAL_FEC_SET_PAYLOAD_SZ
+                if remaining > MAX_FEC_SET_PAYLOAD_SZ
                 else remaining
             )
             fec, consumed = self._build_fec_set(

@@ -11,9 +11,14 @@ to a device worker thread; the worker keeps several batches in flight
 when its device-to-host copy returns) and lands results on a lock-free
 deque; the mux loop publishes landed results downstream as
 credits allow.  Upstream backpressure propagates through `in_budget`:
-when the request queue is full the tile stops draining its in-ring and
-the ring's credit model takes over — exactly the reference's flow-control
-discipline, with the device behind the same tile/link boundary.
+when no device can take another full batch the tile stops draining its
+in-ring and the ring's credit model takes over — exactly the reference's
+flow-control discipline, with the device behind the same tile/link
+boundary.
+
+`VerifyTile._submit_staged` is the one place a staged batch is handed
+to a device, and holds the rule for it: a full batch may queue behind
+others, a partial one waits staged for the land of the batch in flight.
 
 Round-6 scale-out: the single worker became a DEVICE POOL (`_DevicePool`)
 — one worker thread (with its own in-flight pipeline, i.e. the double
@@ -55,21 +60,35 @@ from . import wire
 
 #: where a device batch's time goes, sampled once per batch when its last
 #: verdict is published (wide log2 hists, compressed-us clock of now_ts):
-#: t_first -> t_submit (a pool slot / more lanes), t_submit -> t_disp (the
-#: worker's request queue), t_disp -> t_land (H2D, the batches ahead on the
-#: chip, the kernel, D2H), t_land -> t_pub (the mux thread's turn, then
-#: out-link credits)
+#: t_first -> t_submit (staged: the submit rule's hold for a land, or for a
+#: device below its depth), t_submit -> t_disp (the worker's request
+#: queue), t_disp -> t_land (H2D, the batches ahead on the chip, the
+#: kernel, D2H), t_land -> t_pub (the mux thread's turn, then out-link
+#: credits)
 BATCH_HISTS = (
     "batch_fill_us", "batch_queue_us", "batch_inflight_us", "batch_drain_us",
 )
 #: the mux thread's self-time by phase, wall ns (tango.tempo.tickcount):
 #: on_frags up to staging; _submit_front; _land_results and _publish_ready
 #: on the iterations that landed or published something; and the wall time
-#: during which the pool refused new work (_pool_open): the tile then holds
-#: its frags in the ring (in_budget) or staged, waiting for a slot
+#: during which no device could take a full batch (_pool_open): the tile
+#: then leaves its frags in the ring (in_budget)
 PHASE_COUNTERS = (
     "expand_ns", "submit_ns", "results_ns", "publish_ns", "pool_full_ns",
 )
+#: how a device batch came to be submitted (_submit_staged), counted where
+#: `device_batches` is, when it lands: `full_batches` went out full (the
+#: throughput regime: queueing behind other batches is real work);
+#: `held_batches` are partial batches whose submit waited for a batch in
+#: flight to land.  The rest of `device_batches` are partial batches that
+#: found their device idle and went at once (trickle), and halt's flush.
+SUBMIT_COUNTERS = ("held_batches", "full_batches")
+#: batches a device may have in flight for a PARTIAL batch still to be
+#: submitted to it.  1: a partial batch never queues behind another; it
+#: waits staged (and grows) for the land instead.  2 would hide the ~2 ms
+#: of dispatch + H2D under the running batch and cost every txn one more
+#: batch time; PERF.md (PR 26) has both readings on the chip.
+PARTIAL_AHEAD = 1
 #: what of a batch's meta outlives its landing, until its last publish
 _LIFE_KEYS = (
     "t_first", "t_submit", "t_disp", "t_land", "t_dev", "pool_seq", "lanes",
@@ -323,13 +342,14 @@ class DevicePolicy(FallbackPolicy):
 class _DeviceWorker:
     """Push-request/push-result engine (the wd_f1.c interface shape).
 
-    One dedicated thread owns all interaction with ONE device.  `depth`
-    batches ride in flight: the thread dispatches every queued request
-    before it blocks on the oldest result's D2H copy, so transfer and
-    compute of batch N+1 overlap the sync of batch N (the double
-    buffer).  All dispatch/land calls go through the policy, so a device
-    failure degrades (classic) or surfaces to the pool (DevicePolicy)
-    instead of killing this thread.
+    One dedicated thread owns all interaction with ONE device.  Up to
+    `depth` batches ride in flight (the pool's cap on `inflight()`): the
+    thread dispatches every queued request before it blocks on the
+    oldest result's D2H copy, so transfer and compute of batch N+1
+    overlap the sync of batch N (the double buffer).  All dispatch/land
+    calls go through the policy, so a device failure degrades (classic)
+    or surfaces to the pool (DevicePolicy) instead of killing this
+    thread.
 
     Accounting contract: every submitted batch is exactly one of
     landed (a results entry), still queued/in flight (visible in
@@ -374,8 +394,9 @@ class _DeviceWorker:
         return self.error is None and self.thread.is_alive()
 
     def submit(self, meta, args, mode: str = "auto") -> None:
-        """Single-submitter (mux thread); the caller checks reqq.full()
-        first, so this never blocks."""
+        """Single-submitter (mux thread); the pool submits only while
+        inflight() is below the depth, which keeps `reqq` (as deep)
+        from ever being full, so this never blocks."""
         self.reqq.put_nowait((meta, args, mode))
         self.submitted_n += 1
 
@@ -518,9 +539,12 @@ class _DevicePool:
     """N per-device workers behind one submit/land facade.
 
     Scheduler: least-in-flight across healthy domains, ties broken
-    round-robin; per-device in-flight cap = the worker queue depth.
-    When no device is healthy, batches go out in `mode="host"` — the
-    strict host path as last resort — on any responsive worker.
+    round-robin.  A device is open while its `inflight()` (submitted and
+    not yet landed: queued, dispatched or running) is below a cap: the
+    pool's `depth` by default, or the caller's lower `ahead` (the tile's
+    submit rule gives a partial batch PARTIAL_AHEAD).  When no device is
+    healthy, batches go out in `mode="host"` — the strict host path as
+    last resort — on any responsive worker, under the same caps.
 
     Landing is IN ORDER: every batch gets a monotonically increasing
     `pool_seq` at first submit; completed batches park in a reorder
@@ -541,6 +565,7 @@ class _DevicePool:
     def __init__(self, policies: list, depth: int = 3, name: str = "verify",
                  span=_no_span):
         self.policies = policies
+        self.depth = depth
         self.workers = [
             _DeviceWorker(p, depth, name=f"{name}-dev{i}", span=span)
             for i, p in enumerate(policies)
@@ -567,7 +592,12 @@ class _DevicePool:
         w = self.workers[i]
         return w.alive() and not self.policies[i].stalled
 
-    def _pick(self, peek: bool = False) -> tuple[int | None, str]:
+    def _pick(
+        self, peek: bool = False, ahead: int | None = None
+    ) -> tuple[int | None, str]:
+        """The least-loaded schedulable domain with fewer than `ahead`
+        batches in flight (None: the pool's depth), or None."""
+        cap = self.depth if ahead is None else min(ahead, self.depth)
         now = time.monotonic()
         n = len(self.workers)
         cand = [
@@ -580,7 +610,7 @@ class _DevicePool:
             # any still-responsive worker is the last resort
             mode = "host"
             cand = [i for i in range(n) if self._domain_ok(i)]
-        open_ = [i for i in cand if not self.workers[i].reqq.full()]
+        open_ = [i for i in cand if self.workers[i].inflight() < cap]
         if not open_:
             return None, mode
         best, best_load = None, None
@@ -593,16 +623,20 @@ class _DevicePool:
             self.rr = (self.rr + 1) % max(n, 1)
         return best, mode
 
-    def can_accept(self) -> bool:
-        """Room for NEW work: evicted batches retry first (publishing is
-        seq-ordered, so head-of-line seqs must not starve)."""
+    def can_accept(self, ahead: int | None = None) -> bool:
+        """Room for NEW work behind fewer than `ahead` batches (None:
+        for a full batch, anywhere below the depth): evicted batches
+        retry first (publishing is seq-ordered, so head-of-line seqs
+        must not starve)."""
         if self.retryq:
             return False
-        return self._pick(peek=True)[0] is not None
+        return self._pick(peek=True, ahead=ahead)[0] is not None
 
     def submit(self, meta, args) -> bool:
-        """Schedule one new batch; False = no capacity (caller holds it
-        staged and retries — ring backpressure does the rest)."""
+        """Schedule one new batch on the least-loaded open device (the
+        one can_accept(ahead) found, if the caller asked it first);
+        False = no capacity (caller holds it staged and retries — ring
+        backpressure does the rest)."""
         self.pump()
         if self.retryq:
             return False
@@ -773,17 +807,23 @@ class VerifyTile(Tile):
         name: str = "verify",
     ):
         """pad_full: always pad sub-batches to max_lanes (one compiled
-        shape; right for steady full-rate ingress).  False pads to
-        power-of-two buckets (log2(max_lanes) compiled shapes; cheaper on
-        trickle traffic).
+        shape, so one boot-time compile; what both config-built
+        topologies use).  A padded batch costs a full batch time
+        whatever it carries, which is why a partial batch is held while
+        its device has one in flight (_submit_staged).  False pads to
+        power-of-two buckets (log2(max_lanes) compiled shapes, each a
+        cold compile on first use; cheaper per batch on trickle traffic).
 
         shard=(idx, cnt): horizontal scaling — this replica only processes
         frags with seq % cnt == idx (reference: round-robin seq sharding
         across verify tiles, fd_verify.c:46); the others are skipped
         without gathering payloads.
 
-        async_depth: device batches in flight PER DEVICE (the wiredancer
-        request pipe depth); 1 degenerates to synchronous dispatch.
+        async_depth: FULL batches in flight PER DEVICE — submitted and
+        not yet landed, counted once (`_DeviceWorker.inflight()`), the
+        wiredancer request pipe depth; 1 degenerates to synchronous
+        dispatch.  A partial batch is allowed PARTIAL_AHEAD (a module
+        constant) instead.
 
         device: "auto" jits the batched kernel; "off" never touches JAX
         and verifies every batch on the strict host path (CPU-only tests,
@@ -864,6 +904,7 @@ class VerifyTile(Tile):
                 "device_programs",
             )
             + PHASE_COUNTERS
+            + SUBMIT_COUNTERS
             + device_counters(self.n_devices),
             hists=("lane_batch",) + BATCH_HISTS,
             wide_hists=BATCH_HISTS,
@@ -886,6 +927,9 @@ class VerifyTile(Tile):
         #: per burst, flushed to the metrics region every 16th iteration
         self._phase_ns = dict.fromkeys(PHASE_COUNTERS, 0)
         self._full_t0 = 0  # tickcount of the pool's first open refusal
+        #: the front of staging has been refused by the submit rule since
+        #: the last submit: its batch waited for a land (SUBMIT_COUNTERS)
+        self._held = False
         #: staged host-prepared lanes not yet submitted (list of dicts)
         self._staged: collections.deque = collections.deque()
         self._staged_lanes = 0
@@ -1125,17 +1169,14 @@ class VerifyTile(Tile):
         t0 = tickcount()
         self._stage(ctx, in_idx, frags, ns_to_ts(t0))
         self._phase_ns["expand_ns"] += tickcount() - t0
-        # submit only while the pool has room: a full pool means every
-        # device pipe is behind, and the right response is to hold frags
-        # in the RING (in_budget -> credit backpressure), not to block
-        # this thread past its heartbeat deadline
-        while self._staged_lanes >= self.max_lanes and self._pool_open():
-            self._submit_front(self.max_lanes)
+        # staged only: after_credit, which the run loop calls next in
+        # the same turn, is the one place staged work is submitted
 
     def _pool_open(self) -> bool:
-        """pool.can_accept(), with the wall time from the first refusal
-        to the next acceptance counted into pool_full_ns: a clock read
-        at each edge, none on the turns between."""
+        """pool.can_accept() — some device can take a FULL batch — with
+        the wall time from the first refusal to the next acceptance
+        counted into pool_full_ns: a clock read at each edge, none on
+        the turns between."""
         if self._pool.can_accept():
             if self._full_t0:
                 self._phase_ns["pool_full_ns"] += tickcount() - self._full_t0
@@ -1223,9 +1264,12 @@ class VerifyTile(Tile):
         return floor
 
     def in_budget(self, ctx: MuxCtx) -> int | None:
-        # stop draining the ring when the device pool is full or results
-        # are waiting on downstream credits — backpressure flows upstream
-        # through the ring's credit model, not an unbounded host buffer
+        # stop draining the ring when no device can take a full batch
+        # or results are waiting on downstream credits — backpressure
+        # flows upstream through the ring's credit model, not an
+        # unbounded host buffer.  A partial batch HELD by the submit rule
+        # does not close the ring: the tile keeps draining into staging
+        # (up to 2 x max_lanes), so expand runs during the hold
         if self._pool is not None and not self._pool_open():
             return 0
         if self._staged_lanes >= 2 * self.max_lanes:
@@ -1236,9 +1280,45 @@ class VerifyTile(Tile):
 
     # ---- device submit ---------------------------------------------------
 
-    def _submit_front(self, lanes_cap: int) -> None:
+    def _submit_staged(self) -> None:
+        """THE submit rule — when a staged batch may go to a device.  It
+        reads only what the tile observes: the lanes staged and each
+        device's batches in flight.
+
+        1. A full batch goes to the least-loaded healthy device with
+           fewer than `async_depth` in flight (_pool_open): queueing
+           there is real work.
+        2. A partial batch goes only to one with fewer than
+           PARTIAL_AHEAD in flight; otherwise it stays staged, and grows
+           with every burst, until a batch lands or it is full (rule 1).
+           With nothing in flight it goes at once: trickle traffic pays
+           no linger.  The hold waits on nothing but the land of a batch
+           that IS in flight, so it cannot deadlock; halt, crash
+           teardown and repartition flush or drop staging as before.
+
+        Under `pad_full` a batch costs one full batch time whatever it
+        carries: a partial batch queued behind k others delays its txns
+        k batch times and takes a slot from the next, fuller, one."""
+        pool = self._pool
+        while self._staged_lanes:
+            full = self._staged_lanes >= self.max_lanes
+            if not (
+                self._pool_open() if full else pool.can_accept(PARTIAL_AHEAD)
+            ):
+                self._held = True
+                return
+            rule = (
+                "full_batches" if full
+                else "held_batches" if self._held
+                else None
+            )
+            self._held = False
+            self._submit_front(self.max_lanes, rule)
+
+    def _submit_front(self, lanes_cap: int, rule: str | None = None) -> None:
         """Concatenate staged chunks into one device batch of <= lanes_cap
-        lanes (whole txns only) and push it to the pool."""
+        lanes (whole txns only) and push it to the pool; `rule` is the
+        SUBMIT_COUNTERS name it lands under, if any."""
         t0 = tickcount()
         take, lanes = [], 0
         while self._staged:
@@ -1289,7 +1369,7 @@ class VerifyTile(Tile):
             meta = dict(
                 rows=b["rows"], szs=b["szs"], tsorigs=b["tsorigs"],
                 sig_cnt=b["sig_cnt"], tags=b["tags"], seqs=b["seqs"],
-                lanes=lanes,
+                lanes=lanes, rule=rule,
                 # the deque is FIFO: the first chunk holds the oldest frag
                 t_first=take[0]["t_first"],
             )
@@ -1373,6 +1453,8 @@ class VerifyTile(Tile):
             )
         ctx.metrics.inc("verified_sigs", lanes)
         ctx.metrics.inc("device_batches")
+        if meta["rule"]:
+            ctx.metrics.inc(meta["rule"])
         ctx.metrics.hist_sample("lane_batch", lanes)
         cnt = meta["sig_cnt"]
         starts = np.concatenate(([0], np.cumsum(cnt)[:-1]))
@@ -1452,10 +1534,9 @@ class VerifyTile(Tile):
         self._publish_ready(ctx)
         if self._pending_devices is not None:
             self._maybe_repartition()
-        # keep the devices fed: push a partial batch when the pool has
-        # room and nothing fuller is coming (trickle traffic)
-        if self._staged_lanes and self._pool_open():
-            self._submit_front(self.max_lanes)
+        # after the landings, so a batch held for a land goes in the
+        # same turn that sees it
+        self._submit_staged()
         self._mirror_policy_metrics(ctx)
 
     def during_housekeeping(self, ctx: MuxCtx) -> None:
@@ -1552,6 +1633,7 @@ class VerifyTile(Tile):
         self._outq.clear()
         self._outq_txns = 0
         self._full_t0 = 0
+        self._held = False
 
     def on_halt(self, ctx: MuxCtx) -> None:
         # drain everything: staged -> devices -> results -> downstream.

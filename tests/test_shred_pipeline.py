@@ -136,3 +136,34 @@ def test_blockstore_roundtrip(tmp_path):
     assert bs.block(5) is None
     assert bs.slots() == [3, 5]
     bs.close()
+
+
+@pytest.mark.parametrize("sz", [
+    31_840,          # the most one 32:32 set carries
+    31_841,          # one byte more: a normal set and a 1-shred tail
+    36_192,          # a slot that caught up after a stall: 348 entries
+    62_399, 62_400,  # either side of twice the normal payload
+    93_041,
+])
+def test_entry_batch_of_any_size_shreds_and_reassembles(sz):
+    """A slot's entry batch whose tail is more than one set carries used
+    to index past the 32-shred parity table (the shred tile died on the
+    first slot with more than 306 entries — a burst after a host stall,
+    once verify stopped spacing it out: PERF.md, PR 26)."""
+    from firedancer_tpu.disco import shredder as SD
+
+    batch = np.random.default_rng(sz).integers(0, 256, sz, np.uint8).tobytes()
+    sd = SD.Shredder(1)
+    sd.start_slot(9)
+    sets = sd.shred_batch(batch, SD.EntryBatchMeta(block_complete=True))
+    res, payload = FecResolver(), b""
+    for fs in sets:
+        assert 1 <= len(fs.data_shreds) <= 32 >= len(fs.parity_shreds) >= 1
+        out = res.add_shred(fs.parity_shreds[0])  # sizes the set
+        for raw in fs.data_shreds:
+            out = res.add_shred(raw) or out
+        payload += out.payload
+    assert payload == batch
+    assert all(len(fs.data_shreds) == 32 for fs in sets[:-1])
+    last = SH.parse(sets[-1].data_shreds[-1])
+    assert last.flags & SH.FLAG_SLOT_COMPLETE

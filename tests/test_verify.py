@@ -10,7 +10,10 @@ golden.verify.
 Tile (tier-1, JAX-free: a host `device_fn` stub, the tile's hooks driven
 by hand on one thread so every burst and batch is countable): the five
 lifecycle stamps and the four batch_*_us hists, the mux thread's phase
-counters, and that host spans are built per BATCH, never per burst.
+counters, that host spans are built per BATCH, never per burst, and the
+submit rule (a gated `device_fn`: batches land only as the test lets
+them): a partial batch is held while its device has PARTIAL_AHEAD in
+flight, a full one goes up to `async_depth`.
 """
 
 import threading
@@ -254,6 +257,65 @@ def _ordered(b: dict) -> bool:
     return all(ts_diff(y, x) >= 0 for x, y in zip(ts, ts[1:]))
 
 
+def _until(cond, what: str = "condition") -> None:
+    deadline = time.monotonic() + 30.0
+    while not cond():
+        assert time.monotonic() < deadline, what
+        time.sleep(1e-3)
+
+
+class _Gate:
+    """A `device_fn` shaped like JAX's: the call (the dispatch) returns
+    at once with a future, and reading the future (the land: np.asarray)
+    blocks until the test gives it a permit with `land()` — so batches
+    land one a permit, in dispatch order on each device."""
+
+    def __init__(self):
+        self.sem = threading.Semaphore(0)
+        self.lock = threading.Lock()
+        self.landed = 0
+
+    def __call__(self, digests, sigs, pubs):
+        return _GatedResult(self, len(digests))
+
+    def land(self, n: int = 1) -> None:
+        """Let n of the batches in flight land, and wait until each has
+        taken its permit (so none is banked for a later batch)."""
+        want = self.landed + n
+        for _ in range(n):
+            self.sem.release()
+        _until(lambda: self.landed >= want, "no batch in flight took it")
+
+    def open(self) -> None:
+        for _ in range(10_000):
+            self.sem.release()
+
+
+class _GatedResult:
+    def __init__(self, gate, n):
+        self.gate, self.n = gate, n
+
+    def __array__(self, dtype=None, copy=None):
+        assert self.gate.sem.acquire(timeout=30.0)
+        with self.gate.lock:
+            self.gate.landed += 1
+        return np.ones(self.n, bool)
+
+
+def _inflight(rig) -> list[int]:
+    return [w.inflight() for w in rig.tile._pool.workers]
+
+
+def _fill_ahead(rig, lanes: int = 3) -> None:
+    """Put PARTIAL_AHEAD partial batches in flight on every device of
+    the pool: each finds a device below the mark, so each goes at once."""
+    for _ in range(VT.PARTIAL_AHEAD * rig.tile.n_devices):
+        rig.burst(lanes)
+        rig.credit()
+        assert rig.tile._staged_lanes == 0
+    assert _inflight(rig) == [VT.PARTIAL_AHEAD] * rig.tile.n_devices
+
+
 @pytest.mark.parametrize("traced", [False, True])
 def test_batch_lifecycle_stamps_and_hists(rig_factory, traced):
     """Every device batch carries the five stamps in order, and each of
@@ -286,10 +348,10 @@ def test_batch_split_on_a_txn_boundary_keeps_each_bursts_ingest_time(
     time.sleep(0.005)
     t_between = VT.now_ts()
     time.sleep(0.005)
-    rig.burst(6)           # 12 staged >= 8: on_frags submits 8 at once
+    rig.burst(6)           # 12 staged >= 8
     time.sleep(0.005)
-    rig.credit()           # the 4-lane tail goes as a partial batch
-    rig.settle(2)
+    rig.credit()           # 8 go as a full batch; the 4-lane tail goes
+    rig.settle(2)          # as a partial one, now or after that lands
     first, second = rig.published
     assert (first["lanes"], second["lanes"]) == (8, 4)
     assert ts_diff(t_between, first["t_first"]) >= 4000   # burst 1's
@@ -355,36 +417,35 @@ def test_drain_wait_is_charged_to_the_batch_that_waited_for_credits(
 def test_phase_counters_advance_and_pool_full_only_while_refused(
         rig_factory):
     """expand/submit/results/publish all grow over a run; pool_full_ns
-    grows only while the pool refuses new work (the tile then leaves
-    its frags in the ring: in_budget is 0)."""
-    gate, entered = threading.Event(), threading.Event()
-
-    def gated(digests, sigs, pubs):
-        entered.set()
-        assert gate.wait(30.0)
-        return np.ones(len(digests), bool)
-
-    rig = rig_factory(device_fn=gated, async_depth=1).boot()
+    grows only while no device can take a FULL batch (the tile then
+    leaves its frags in the ring: in_budget is 0) — not while a partial
+    batch is held for a land, when the ring stays open."""
+    gate = _Gate()
+    rig = rig_factory(device_fn=gate, async_depth=VT.PARTIAL_AHEAD + 1).boot()
     tile = rig.tile
     try:
         assert tile.in_budget(rig.ctx) is None
-        rig.burst(3)
-        rig.credit()                 # batch 0: the worker takes it, blocks
-        assert entered.wait(10.0)
+        _fill_ahead(rig)       # these go at once; the worker blocks
         assert tile.in_budget(rig.ctx) is None
         rig.burst(3)
-        rig.credit()                 # batch 1: sits in the request queue
+        rig.credit()                 # held: it would queue behind them
+        assert tile._staged_lanes == 3
         c = rig.counters()
         assert c["pool_full_ns"] == 0 and c["expand_ns"] > 0
         assert c["submit_ns"] > 0 and c["results_ns"] == 0
+        assert tile.in_budget(rig.ctx) is None    # a hold is no refusal
+        rig.burst(5)
+        rig.credit()                 # 8 staged: a full batch goes at once
+        assert tile._staged_lanes == 0
+        assert rig.counters()["pool_full_ns"] == 0
         assert tile.in_budget(rig.ctx) == 0   # refused: from here it counts
         time.sleep(0.02)
         assert tile.in_budget(rig.ctx) == 0
         refused = rig.counters()["pool_full_ns"]  # an open refusal counts
         assert refused >= 20_000_000
     finally:
-        gate.set()
-    rig.settle(2)
+        gate.open()
+    rig.settle(VT.PARTIAL_AHEAD + 1)
     assert tile.in_budget(rig.ctx) is None    # accepted again: it stops
     done = rig.counters()
     assert done["pool_full_ns"] >= refused
@@ -444,3 +505,260 @@ def test_host_spans_are_built_per_batch_never_per_burst(rig_factory):
     assert plain.tile._span is VT._no_span
     assert VT._no_span("a", seq=1) is VT._no_span("b")
     plain.tile.during_housekeeping(plain.ctx)
+
+
+# ---------------------------------------------------------------------------
+# the submit rule: when a staged batch may go to a device (tier-1)
+
+
+def test_partial_batch_is_held_while_one_is_in_flight_and_goes_on_the_land(
+        rig_factory):
+    """With PARTIAL_AHEAD batches in flight a partial batch stays staged
+    — the ring stays open and staging grows with every burst — and it
+    goes, as ONE batch, on the first after_credit after a land."""
+    gate = _Gate()
+    rig = rig_factory(device_fn=gate, async_depth=3).boot()
+    tile = rig.tile
+    try:
+        _fill_ahead(rig)
+        for k in (1, 2):
+            rig.burst(2)
+            rig.credit()
+            assert tile._staged_lanes == 2 * k and tile._held
+            assert tile.in_budget(rig.ctx) is None
+            assert _inflight(rig) == [VT.PARTIAL_AHEAD]
+        for _ in range(20):          # turns without a land change nothing
+            rig.credit()
+        assert tile._staged_lanes == 4
+        assert rig.counters()["pool_full_ns"] == 0
+        gate.land()
+        _until(lambda: _inflight(rig) == [VT.PARTIAL_AHEAD - 1], "no land")
+        rig.credit()                 # the turn that sees the land
+        assert tile._staged_lanes == 0 and not tile._held
+        assert _inflight(rig) == [VT.PARTIAL_AHEAD]
+    finally:
+        gate.open()
+    rig.settle(VT.PARTIAL_AHEAD + 1)
+    assert [b["lanes"] for b in rig.published] == (
+        [3] * VT.PARTIAL_AHEAD + [4])
+    held = rig.published[-1]
+    assert _ordered(held)
+    c = rig.counters()
+    assert (c["held_batches"], c["full_batches"]) == (1, 0)
+    assert c["device_batches"] == VT.PARTIAL_AHEAD + 1
+    assert c["out_frags"] == 3 * VT.PARTIAL_AHEAD + 4
+
+
+def test_partial_batch_goes_at_once_with_nothing_in_flight(rig_factory):
+    """Trickle traffic pays no linger: an idle device takes whatever is
+    staged in the same turn, and such a batch is neither held nor full."""
+    rig = rig_factory(async_depth=3).boot()
+    for i in range(4):
+        rig.burst(1 + i)
+        assert rig.tile._staged_lanes == 1 + i   # on_frags only stages
+        rig.credit()
+        assert rig.tile._staged_lanes == 0 and not rig.tile._held
+        rig.settle(i + 1)
+    c = rig.counters()
+    assert c["device_batches"] == 4
+    assert (c["held_batches"], c["full_batches"]) == (0, 0)
+    assert c["batch_fill_us"]["sum"] < 4 * 5000  # no hold in the fill time
+
+
+def test_full_batches_go_up_to_async_depth_in_flight_and_no_further(
+        rig_factory):
+    """Rule 1: a full batch does not wait for a land — it queues behind
+    others up to `async_depth` in flight, counted once (not a request
+    queue of that depth plus as many dispatched) — and beyond that the
+    tile refuses the ring."""
+    gate = _Gate()
+    rig = rig_factory(device_fn=gate, async_depth=3).boot()
+    tile = rig.tile
+    try:
+        for k in (1, 2, 3):
+            assert tile.in_budget(rig.ctx) is None
+            rig.burst(8)
+            rig.credit()
+            assert tile._staged_lanes == 0 and _inflight(rig) == [k]
+        assert tile.in_budget(rig.ctx) == 0
+        rig.burst(8)                 # (the run loop would not drain it)
+        rig.credit()
+        assert tile._staged_lanes == 8 and _inflight(rig) == [3]
+        assert tile.in_budget(rig.ctx) == 0
+        gate.land()
+        _until(lambda: _inflight(rig) == [2], "no land")
+        rig.credit()
+        assert tile._staged_lanes == 0 and _inflight(rig) == [3]
+    finally:
+        gate.open()
+    rig.settle(4)
+    c = rig.counters()
+    assert (c["device_batches"], c["full_batches"], c["held_batches"]) == (
+        4, 4, 0)
+    assert c["pool_full_ns"] > 0
+    assert [b["lanes"] for b in rig.published] == [8] * 4
+
+
+def test_two_devices_carry_two_partial_batches_one_each(rig_factory):
+    """The rule is per device: with `devices=2` each device takes
+    partial batches up to the mark, the next one is held, and a land on
+    EITHER device lets it go — to that device."""
+    gate = _Gate()
+    rig = rig_factory(device_fn=gate, devices=2, async_depth=3).boot()
+    tile = rig.tile
+    try:
+        _fill_ahead(rig)
+        assert _inflight(rig) == [VT.PARTIAL_AHEAD] * 2
+        rig.burst(2)
+        rig.credit()
+        assert tile._staged_lanes == 2 and tile._held
+        assert tile.in_budget(rig.ctx) is None
+        gate.land()                  # whichever device it was
+        _until(lambda: sum(_inflight(rig)) == 2 * VT.PARTIAL_AHEAD - 1,
+               "no land")
+        rig.credit()
+        assert tile._staged_lanes == 0
+        assert _inflight(rig) == [VT.PARTIAL_AHEAD] * 2
+    finally:
+        gate.open()
+    rig.settle(2 * VT.PARTIAL_AHEAD + 1)
+    c = rig.counters()
+    assert c["held_batches"] == 1 and c["full_batches"] == 0
+    assert [b["pool_seq"] for b in rig.published] == list(
+        range(2 * VT.PARTIAL_AHEAD + 1))
+    assert c["dev0_landed"] + c["dev1_landed"] == c["device_batches"]
+    assert min(c["dev0_landed"], c["dev1_landed"]) >= VT.PARTIAL_AHEAD
+
+
+def test_halt_flushes_a_held_stage(rig_factory):
+    """on_halt does not wait for the rule: what is staged goes behind
+    the batch in flight, and everything lands and is published."""
+    gate = _Gate()
+    rig = rig_factory(device_fn=gate, async_depth=3).boot()
+    _fill_ahead(rig)
+    rig.burst(5)
+    rig.credit()
+    assert rig.tile._staged_lanes == 5 and rig.tile._held
+    threading.Timer(0.05, gate.open).start()
+    rig.tile.on_halt(rig.ctx)
+    assert rig.tile._staged_lanes == 0 and not rig.tile._outq
+    assert rig.tile._pool.idle()
+    assert [b["lanes"] for b in rig.published] == (
+        [3] * VT.PARTIAL_AHEAD + [5])
+    c = rig.ctx.metrics.read()
+    assert c["out_frags"] == 3 * VT.PARTIAL_AHEAD + 5
+    # the flush is neither of the rule's two classes
+    assert (c["held_batches"], c["full_batches"]) == (0, 0)
+
+
+def test_elastic_drain_completes_with_a_held_stage(rig_factory):
+    """A retiring member with a held partial batch is not drained until
+    that batch, too, has gone, landed and been published — and it does
+    go: the hold ends with the land of the batch in flight."""
+    gate = _Gate()
+    rig = rig_factory(device_fn=gate, async_depth=3).boot()
+    tile = rig.tile
+    try:
+        _fill_ahead(rig)
+        rig.burst(4)
+        rig.credit()
+        assert tile._held and not tile.elastic_drained(rig.ctx)
+        gate.land(VT.PARTIAL_AHEAD)
+        rig.settle(VT.PARTIAL_AHEAD)   # the credit turns submit the held one
+        assert tile._staged_lanes == 0
+        assert not tile.elastic_drained(rig.ctx)   # it is in flight now
+        gate.land()
+        rig.settle(VT.PARTIAL_AHEAD + 1)
+        assert tile.elastic_drained(rig.ctx)
+    finally:
+        gate.open()
+    assert rig.counters()["held_batches"] == 1
+
+
+def test_crash_teardown_drops_a_held_stage_and_the_next_life_starts_clean(
+        rig_factory):
+    """on_crash discards staging (the ring replay re-delivers): the held
+    flag must not survive into the next incarnation's first batch."""
+    gate = _Gate()
+    rig = rig_factory(device_fn=gate, async_depth=3).boot()
+    _fill_ahead(rig)
+    rig.burst(4)
+    rig.credit()
+    assert rig.tile._held
+    gate.open()
+    rig.tile.on_crash(rig.ctx)
+    assert not rig.tile._held and rig.tile._staged_lanes == 0
+    assert rig.tile.ack_floor(rig.ctx, 0) is None
+    rig.boot()
+    n0 = len(rig.published)
+    rig.burst(2)
+    rig.credit()
+    rig.settle(n0 + 1)
+    assert rig.counters()["held_batches"] == 0
+
+
+@pytest.mark.parametrize("devices,seed", [
+    (1, 1), (1, 2), (1, 3), (2, 4), (2, 5), (3, 6)])
+def test_random_bursts_publish_every_txn_once_in_ring_order(
+        rig_factory, devices, seed):
+    """Random bursts, credits and lands against the rule: every submit
+    obeys it, ack_floor is exactly the oldest unpublished frag at every
+    step, the three counters add up, and the published stream is the
+    ring's, txn for txn."""
+    rng = np.random.default_rng(seed)
+    gate = _Gate()
+    depth = 3
+    rig = rig_factory(device_fn=gate, devices=devices,
+                      async_depth=depth).boot()
+    tile, ctx = rig.tile, rig.ctx
+    tags = rig.rows[:, 1:9].copy().view("<u8").ravel()
+    out: list[int] = []
+    publish = ctx.publish
+
+    def recording(t, *a, **kw):
+        out.extend(int(x) for x in t)
+        return publish(t, *a, **kw)
+
+    ctx.publish = recording
+    subs: list[tuple[int, int]] = []   # (lanes, in flight ahead of it)
+    for w in tile._pool.workers:
+        def submit(meta, args, mode="auto", w=w, inner=w.submit):
+            subs.append((meta["lanes"], w.inflight()))
+            inner(meta, args, mode)
+        w.submit = submit
+    seq0 = int(ctx.ins[0].seq)
+
+    def check():
+        floor = tile.ack_floor(ctx, 0)
+        if len(out) == rig.sent:
+            assert floor is None
+        else:
+            assert floor == seq0 + len(out)
+        assert tile._staged_lanes == sum(
+            len(b["sigs"]) for b in tile._staged)
+        assert all(0 <= n <= depth for n in _inflight(rig))
+
+    try:
+        for _ in range(300):
+            act = rng.integers(0, 6)
+            if act <= 1 and tile.in_budget(ctx) is None:
+                rig.burst(int(rng.integers(1, 13)))
+            elif act == 2:
+                rig.credit(int(rng.integers(0, 17)))
+            elif act == 3 and sum(_inflight(rig)):
+                gate.land()
+            else:
+                rig.credit()
+            check()
+    finally:
+        gate.open()
+    _until(lambda: (rig.credit(), check(), len(out) == rig.sent)[-1],
+           "the tail did not drain")
+    assert out == [int(t) for t in tags[np.arange(rig.sent) % len(tags)]]
+    for lanes, ahead in subs:
+        assert ahead < (depth if lanes == 8 else VT.PARTIAL_AHEAD), subs
+    c = rig.counters()
+    assert c["device_batches"] == len(subs) == len(rig.published)
+    assert c["full_batches"] == sum(1 for n, _ in subs if n == 8)
+    assert 0 < c["held_batches"] <= len(subs) - c["full_batches"]
+    assert c["verified_sigs"] == c["out_frags"] == rig.sent
