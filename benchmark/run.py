@@ -4,9 +4,10 @@
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 It finds the cell (`workloads/<cell>.json`), its configuration
-(`configs/<config>.json` + `.toml`) and, in a traced run, every per-layer
-metric (`metrics/*.json` -> `readers/<reader>.py`) BY NAME: a later PR
-adds a deployment, a traffic mix or a metric by adding files only.
+(`configs/<config>.json` + `.toml`) and every metric the cell reports
+(`metrics/*.json` -> `readers/<reader>.py`) BY NAME: a later PR adds a
+deployment, a traffic mix, a metric, or a cell under the metrics that
+are there (the cell's file names them) by adding files only.
 
 A run: traffic from --seed -> boot the deployment through the program's
 normal entry points -> warm-up (set-up ends at the window's first edge)
@@ -87,14 +88,18 @@ def load_cell(root: str, name: str, rehearse: bool):
 
 def load_metrics(root: str, cell_name: str, end_to_end: bool) -> dict:
     """Every metric file of the kind asked for (`"end_to_end": true`, or
-    per-layer) that lists this cell (or lists none)."""
-    out = {}
-    for path in sorted(glob.glob(os.path.join(root, "metrics", "*.json"))):
-        m = load_json(path)
-        if (bool(m.get("end_to_end")) == end_to_end
-                and cell_name in m.get("workloads", [cell_name])):
-            out[os.path.basename(path)[:-5]] = m
-    return out
+    per-layer) that lists this cell, or lists none, or that the cell's
+    own file names under `"metrics"`.  A name with no file is Malformed."""
+    files = {os.path.basename(p)[:-5]: load_json(p) for p in
+             sorted(glob.glob(os.path.join(root, "metrics", "*.json")))}
+    path = os.path.join(root, "workloads", f"{cell_name}.json")
+    named = load_json(path).get("metrics", []) if os.path.exists(path) else []
+    if missing := [n for n in named if n not in files]:
+        raise Malformed(f"workload {cell_name!r} names metrics that have "
+                        f"no file under metrics/: {missing}")
+    return {n: m for n, m in files.items()
+            if bool(m.get("end_to_end")) == end_to_end
+            and (cell_name in m.get("workloads", [cell_name]) or n in named)}
 
 
 def load_reader(root: str, name: str):
@@ -471,6 +476,11 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
         v = load_reader(root, m["reader"])(ctx, **m.get("args", {}))
         if v is not None:
             metrics[mname] = {"value": v, "unit": m["unit"]}
+        elif not trace and mname in cell.get("metrics", []):
+            # a cell's file does not promise what its run cannot give
+            raise Malformed(
+                f"workload {name!r} names the end-to-end metric {mname!r}, "
+                f"and its reader found nothing to read in this run")
     result["metrics"] = metrics
     result["device"] = device_out
     result["checks"] = {n: [v, lim] for n, v, lim in checks}

@@ -6,6 +6,7 @@ device stubbed — sound, and broken underneath, where `correct` has to
 come out false.
 """
 
+import glob
 import json
 import os
 import re
@@ -184,22 +185,35 @@ def test_manifest_agrees_with_its_files():
         assert w["traffic"] == w["name"].split(".", 1)[1]
         assert len(reports[w["name"]]) >= 2 and cell["who"]
     files = {os.path.basename(p)[:-5]: json.load(open(p)) for p in
-             __import__("glob").glob(os.path.join(ROOT, "metrics", "*.json"))}
+             glob.glob(os.path.join(ROOT, "metrics", "*.json"))}
+    # a cell may name the metrics it reports in its own file: the manifest
+    # then lists it behind the cells the metric's file lists (a PR that
+    # adds a cell appends its name there)
+    named = {w: RUN.load_cell(ROOT, w, False).get("metrics", [])
+             for w in cells}
+
+    def listed(name):
+        f = files[name]
+        return None if "workloads" not in f else f["workloads"] + [
+            w for w in cells if name in named[w] and w not in f["workloads"]]
+
+    assert all(n in files for ns in named.values() for n in ns)
     # every metric of either kind is a file, and the file says the same
     assert {m["name"] for m in b["per_layer"]} == {
         n for n, f in files.items() if not f.get("end_to_end")}
     assert set(e2e) == {n for n, f in files.items() if f.get("end_to_end")}
     for m in b["end_to_end"]:
         f = files[m["name"]]
-        assert [m.get(k) for k in ("unit", "better", "source", "workloads")
-                ] == [f.get(k) for k in ("unit", "better", "source",
-                                         "workloads")], m["name"]
+        assert [m.get(k) for k in ("unit", "better", "source")] == [
+            f.get(k) for k in ("unit", "better", "source")], m["name"]
+        assert m.get("workloads") == listed(m["name"]), m["name"]
         assert os.path.exists(os.path.join(ROOT, "readers",
                                            f["reader"] + ".py"))
     for m in b["per_layer"]:
         f = files[m["name"]]
-        for k in ("unit", "better", "source", "layer", "moves", "workloads"):
+        for k in ("unit", "better", "source", "layer", "moves"):
             assert m[k] == f[k], (m["name"], k)
+        assert m["workloads"] == listed(m["name"]), m["name"]
         assert os.path.exists(os.path.join(ROOT, "readers",
                                            f["reader"] + ".py"))
         for w in m["workloads"]:
@@ -216,6 +230,8 @@ def test_a_cell_and_a_metric_added_as_files_are_found_with_no_edit(tmp_path):
     shutil.copytree(ROOT, root, ignore=shutil.ignore_patterns("__pycache__"))
     cell = json.load(open(root / "workloads" / "ingress.flood.json"))
     cell["window_txns"] = 4096
+    # the cell names what it reports of the metrics that are there
+    cell["metrics"] = ["verified_tps", "verify.batch_fill.ingress"]
     (root / "workloads" / "ingress.trickle.json").write_text(json.dumps(cell))
     (root / "readers" / "always_seven.py").write_text(
         "def read(ctx, plus):\n    return 7 + plus\n")
@@ -234,12 +250,13 @@ def test_a_cell_and_a_metric_added_as_files_are_found_with_no_edit(tmp_path):
     root = str(root)
     assert RUN.load_cell(root, "ingress.trickle", False)["window_txns"] == 4096
     found = RUN.load_metrics(root, "ingress.trickle", end_to_end=False)
-    assert "quic.sevens" in found and "verify.batch_fill.leader" not in found
+    assert set(found) == {"quic.sevens", "verify.batch_fill.ingress"}
     m = found["quic.sevens"]
     assert RUN.load_reader(root, m["reader"])({}, **m["args"]) == 8
     assert "quic.sevens" not in RUN.load_metrics(root, "ingress.flood", False)
     e2e = RUN.load_metrics(root, "ingress.trickle", end_to_end=True)
-    assert set(e2e) == {"lag_p99_ms", "setup_s"}
+    assert set(e2e) == {"lag_p99_ms", "setup_s", "verified_tps"}
+    assert "verified_tps" not in RUN.load_metrics(root, "no.such", True)
     lag = RUN.load_reader(root, "lag_percentile")
     assert lag({"lag_ns": np.arange(101) * 1e6}, q=99) == 99.0
     assert lag({"lag_ns": None}, q=99) is None  # a closed loop has no lag
