@@ -345,9 +345,9 @@ def verify_batch_digest_on(device):
     progress while another device (or this one's previous batch)
     executes — the premise the scale-out design rests on (ROADMAP R4
     measures it).  jax.jit caches per placement, and so does the
-    persistent cache (its key covers the device assignment): every
-    device pays its own lowering and, cold, its own compile — about
-    74 s a device on a v5e host (PERF.md, PR 22)."""
+    persistent cache (its key covers the device assignment): every device
+    pays its own lowering and, cold, its own compile: four v5e devices warmed
+    in 346.7 s cold, one in 56.2 s (PERF.md section 6, my chip runs, PR 28)."""
     use_pallas = _use_pallas()
 
     def fn(digests, sigs, pubs):

@@ -68,6 +68,15 @@ from . import wire
 BATCH_HISTS = (
     "batch_fill_us", "batch_queue_us", "batch_inflight_us", "batch_drain_us",
 )
+#: beside that chain, the part of t_land -> t_pub a batch spent parked in
+#: the pool's reorder buffer: t_taken (the mux thread took it from its
+#: worker) -> t_rel (the pool released it in pool_seq order).  One clock
+#: read a poll that takes anything, so it is 0 for a batch released by the
+#: poll that took it: every batch of a one-device pool.  Sampled as the
+#: batch lands (_land_batch), where `device_batches` and
+#: `reordered_batches` (batches some poll left parked behind an earlier
+#: pool_seq) are counted
+REORDER_HIST = "batch_reorder_us"
 #: the mux thread's self-time by phase, wall ns (tango.tempo.tickcount):
 #: on_frags up to staging; _submit_front; _land_results and _publish_ready
 #: on the iterations that landed or published something; and the wall time
@@ -83,6 +92,7 @@ PHASE_COUNTERS = (
 #: flight to land.  The rest of `device_batches` are partial batches that
 #: found their device idle and went at once (trickle), and halt's flush.
 SUBMIT_COUNTERS = ("held_batches", "full_batches")
+REORDER_COUNTER = "reordered_batches"
 #: batches a device may have in flight for a PARTIAL batch still to be
 #: submitted to it.  1: a partial batch never queues behind another; it
 #: waits staged (and grows) for the land instead.  2 would hide the ~2 ms
@@ -508,6 +518,7 @@ class _DeviceWorker:
                             "fdt.verify.dispatch",
                             seq=meta.get("pool_seq", 0),
                             lanes=meta["lanes"],
+                            dev=meta["t_dev"],
                         ):
                             slot[3] = self.policy.dispatch(args)
                         self.land_t0 = 0.0
@@ -523,6 +534,7 @@ class _DeviceWorker:
                         "fdt.verify.land",
                         seq=meta.get("pool_seq", 0),
                         lanes=meta["lanes"],
+                        dev=meta["t_dev"],
                     ):
                         ok = self.policy.land(fut, args, meta["lanes"])
                     self.land_t0 = 0.0
@@ -550,7 +562,12 @@ class _DevicePool:
     `pool_seq` at first submit; completed batches park in a reorder
     buffer and `ready` hands them out strictly by seq, so downstream
     publish order is identical to a single serialized stream no matter
-    how devices interleave.
+    how devices interleave.  The buffer keeps its own account in the
+    batch's meta: `t_taken` / `t_rel` (taken from its worker, released
+    in order; one clock read a poll, so equal unless it waited),
+    `parked` (a poll left it behind an earlier seq) and `t_dev` (the
+    domain whose result was accepted), which the tile samples as the
+    batch lands (REORDER_HIST, REORDER_COUNTER, dev{i}_landed).
 
     Fault handling: a failed batch (device error) or a quarantined/
     stalled/dead domain's in-flight work is resubmitted — same seq —
@@ -687,7 +704,7 @@ class _DevicePool:
 
     # ---- landing --------------------------------------------------------
 
-    def _drain_results(self, i: int, w: _DeviceWorker) -> None:
+    def _drain_results(self, i: int, w: _DeviceWorker, t_taken: int) -> None:
         while w.results:
             meta, ok = w.results.popleft()
             seq = meta["pool_seq"]
@@ -702,19 +719,29 @@ class _DevicePool:
                 continue
             del self.outstanding[seq]
             w.landed_n += 1
+            # the domain whose result the pool ACCEPTED (a batch moved
+            # here from a stalled domain keeps no trace of that one)
+            meta["t_dev"] = i
+            meta["t_taken"] = t_taken
+            meta["parked"] = False
             self.reorder[seq] = (meta, ok)
 
     def poll(self) -> None:
         """Drain worker results into the in-order ready queue; watchdog
         stalled/dead domains; resubmit failed batches.  Mux-thread only."""
         now = time.monotonic()
+        # one stamp for all this poll takes and releases (REORDER_HIST)
+        ts = None
         for i, w in enumerate(self.workers):
             p = self.policies[i]
             # drain completed results BEFORE any eviction below: a
             # worker that landed S1..Sk and then wedged/died on S(k+1)
             # must not have its finished batches reassigned and re-run
             # (eviction-first turned them into dropped late results)
-            self._drain_results(i, w)
+            if w.results:
+                if ts is None:
+                    ts = now_ts()
+                self._drain_results(i, w, ts)
             patience = getattr(p, "stall_patience_s", 0.0)
             t0 = w.land_t0
             if (
@@ -748,9 +775,14 @@ class _DevicePool:
                 self._evicted.add(i)
                 self._evict(i)
         self.pump()
+        # a release needs the take of `landed_seq`, so `ts` is set here
         while self.landed_seq in self.reorder:
-            self.ready.append(self.reorder.pop(self.landed_seq))
+            meta, ok = self.reorder.pop(self.landed_seq)
+            meta["t_rel"] = ts
+            self.ready.append((meta, ok))
             self.landed_seq += 1
+        for meta, _ok in self.reorder.values():
+            meta["parked"] = True  # behind an earlier seq on another device
 
     def idle(self) -> bool:
         return not self.outstanding and not self.ready
@@ -905,9 +937,10 @@ class VerifyTile(Tile):
             )
             + PHASE_COUNTERS
             + SUBMIT_COUNTERS
+            + (REORDER_COUNTER,)
             + device_counters(self.n_devices),
-            hists=("lane_batch",) + BATCH_HISTS,
-            wide_hists=BATCH_HISTS,
+            hists=("lane_batch",) + BATCH_HISTS + (REORDER_HIST,),
+            wide_hists=BATCH_HISTS + (REORDER_HIST,),
         )
         self._tc: R.TCache | None = None
         self._fns: list | None = None
@@ -1005,7 +1038,10 @@ class VerifyTile(Tile):
             # compile in the middle of serving — `device_programs`
             # shows it.  Each device pays its own lowering and, cold,
             # its own compile: the persistent cache's key covers the
-            # device assignment (PERF.md, PR 22).
+            # device assignment.  Four v5e devices warmed cold in 346.7 s
+            # (one: 56.2 s); with the programs cached the whole boot of
+            # `leader4` took 112.4 s (PERF.md section 6, my chip runs,
+            # PR 28): ROADMAP S6.
             for f in self._fns:
                 np.asarray(
                     f(
@@ -1453,8 +1489,17 @@ class VerifyTile(Tile):
             )
         ctx.metrics.inc("verified_sigs", lanes)
         ctx.metrics.inc("device_batches")
+        # counted here, beside device_batches, under the domain the pool
+        # accepted the result from: a window's deltas of the dev{i}_landed
+        # sum to device_batches' delta
+        ctx.metrics.inc(f"dev{meta['t_dev']}_landed")
         if meta["rule"]:
             ctx.metrics.inc(meta["rule"])
+        if meta["parked"]:
+            ctx.metrics.inc(REORDER_COUNTER)
+        ctx.metrics.hist_sample(
+            REORDER_HIST, max(ts_diff(meta["t_rel"], meta["t_taken"]), 0)
+        )
         ctx.metrics.hist_sample("lane_batch", lanes)
         cnt = meta["sig_cnt"]
         starts = np.concatenate(([0], np.cumsum(cnt)[:-1]))
@@ -1589,7 +1634,6 @@ class VerifyTile(Tile):
             p = ps[i]
             m.set(f"dev{i}_depth", w.reqq.qsize())
             m.set(f"dev{i}_inflight", max(w.inflight(), 0))
-            m.set(f"dev{i}_landed", w.landed_n)
             m.set(f"dev{i}_failed", p.device_errors + getattr(
                 p, "device_stalls", 0))
             degraded = (

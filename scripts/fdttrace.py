@@ -63,6 +63,11 @@ from firedancer_tpu.disco.mux import (  # noqa: E402
 )
 from firedancer_tpu.tango import rings as R  # noqa: E402
 
+#: the arguments the verify tile gives its `fdt.*` host spans: the batch's
+#: pool seq and lanes, on the worker's dispatch / land spans the pool domain
+#: (`dev=`) that worker serves, and `fdt.clock`'s tie
+_SPAN_ARGS = ("seq", "lanes", "dev", "mono_ns")
+
 #: per-tile sub-tracks in the Chrome trace (tid = tile_index * 4 + facet)
 _FACET_FRAGS, _FACET_DEVICE, _FACET_LOOP, _FACET_FAULTS = 0, 1, 2, 3
 
@@ -227,8 +232,9 @@ def _anchor(session: TraceSession) -> int:
 def profiler_events(xplane_path: str) -> tuple[int | None, list[dict]]:
     """A jax.profiler trace (`*.xplane.pb`) -> (offset_ns, events), the
     events being the program's own host spans (`fdt.*`) and every device
-    op, each {"track", "name", "start_ns", "dur_ns"} on the PROFILER's
-    clock.  offset_ns = time.monotonic_ns() - profiler ns, taken from
+    op, each {"track", "name", "start_ns", "dur_ns", "args"} on the
+    PROFILER's clock (`args`: a host span's `seq`, `lanes` and, for a pool
+    worker's dispatch / land, `dev`).  offset_ns = time.monotonic_ns() - profiler ns, taken from
     the `fdt.clock` spans the verify tile writes once a second (their
     `mono_ns` argument is the monotonic clock at their start; the median
     over the trace's ties); None when the trace holds no tie.  With it,
@@ -247,22 +253,25 @@ def profiler_events(xplane_path: str) -> tuple[int | None, list[dict]]:
                 name = e.name
                 if not device and not name.startswith("fdt."):
                     continue
-                if name.startswith("fdt.clock"):
-                    # the profiler keeps an annotation's arguments as the
-                    # event's stats, or (other versions) inside its name
-                    # as `fdt.clock#mono_ns=...#`
-                    mono = dict(e.stats).get("mono_ns")
-                    if mono is None:
-                        m = re.search(r"mono_ns=(\d+)", name)
-                        mono = m and m.group(1)
-                    if mono is not None:
-                        ties.append(int(mono) - int(e.start_ns))
+                # the profiler keeps an annotation's arguments as the
+                # event's stats, or (other versions) inside its name as
+                # `fdt.verify.dispatch#seq=7,lanes=85,dev=2#`
+                args = {} if device else {
+                    k: int(v) for k, v in dict(e.stats).items()
+                    if k in _SPAN_ARGS
+                } or {
+                    k: int(v) for k, v in
+                    re.findall(r"(\w+)=(\d+)", name.partition("#")[2])
+                }
+                if name.startswith("fdt.clock") and "mono_ns" in args:
+                    ties.append(args["mono_ns"] - int(e.start_ns))
                 events.append(
                     {
                         "track": f"{plane.name} {line.name}",
                         "name": name.split("#", 1)[0],
                         "start_ns": int(e.start_ns),
                         "dur_ns": int(e.duration_ns),
+                        "args": args,
                     }
                 )
     offset = int(statistics.median(ties)) if ties else None
@@ -447,7 +456,7 @@ def chrome_trace(
                     "tid": tracks[s["track"]],
                     "ts": us(ns_to_ts(s["start_ns"] + offset_ns)),
                     "dur": max(s["dur_ns"] // 1000, 1),
-                    "args": {"track": s["track"]},
+                    "args": {"track": s["track"], **s["args"]},
                 }
             )
     # strict per-track time order (Perfetto requires monotone begins)
@@ -535,8 +544,10 @@ def main(argv: list[str] | None = None) -> int:
                     help="write Chrome trace-event JSON here (default stdout)")
     ap.add_argument("--xplane", default=None, metavar="FILE",
                     help="a jax.profiler *.xplane.pb of the verify tile's "
-                    "process: its fdt.* host spans and device ops are added "
-                    "to the export, tied to the rings' clock by fdt.clock")
+                    "process: its fdt.* host spans (with their seq=, lanes= "
+                    "and, on a worker's dispatch / land, dev= arguments) and "
+                    "device ops are added to the export, tied to the rings' "
+                    "clock by fdt.clock")
     args = ap.parse_args(argv)
 
     try:

@@ -651,7 +651,7 @@ def test_profiler_clock_is_tied_to_the_rings_by_fdt_clock(tmp_path):
                 pass
             t0 = time.monotonic_ns()
             with jax.profiler.TraceAnnotation(
-                    "fdt.verify.dispatch", seq=seq, lanes=8):
+                    "fdt.verify.dispatch", seq=seq, lanes=8, dev=seq % 2):
                 time.sleep(0.002)
             marks.append(t0)
         with jax.profiler.TraceAnnotation("not.ours"):
@@ -666,6 +666,9 @@ def test_profiler_clock_is_tied_to_the_rings_by_fdt_clock(tmp_path):
     spans = sorted((e for e in events if e["name"] == "fdt.verify.dispatch"),
                    key=lambda e: e["start_ns"])
     assert len(spans) == 3
+    # the worker's pool domain rides along (`dev=`), beside seq and lanes
+    assert [e["args"] for e in spans] == [
+        dict(seq=i, lanes=8, dev=i % 2) for i in range(3)]
     for e, t0 in zip(spans, marks):
         assert abs(e["start_ns"] + offset - t0) < 1_000_000  # < 1 ms
         assert e["dur_ns"] >= 2_000_000
@@ -678,4 +681,5 @@ def test_profiler_clock_is_tied_to_the_rings_by_fdt_clock(tmp_path):
     ours = [e for e in doc if e["pid"] == 2
             and e["name"] == "fdt.verify.dispatch"]
     hk = next(e for e in doc if e["name"] == "verify hk")
+    assert [e["args"]["dev"] for e in ours] == [0, 1, 0]
     assert len(ours) == 3 and abs(min(e["ts"] for e in ours) - hk["ts"]) < 1000
