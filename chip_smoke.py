@@ -446,7 +446,17 @@ def phase_kernel(sz: Sizes, seed: int, rehearse: bool, workdir: str) -> dict:
     t0 = time.perf_counter()
     fn = tile._make_device_fns()[0]
     tile_warm_s = time.perf_counter() - t0
-    got_digest = np.asarray(fn(digests, sigs, pubs))
+    # with the count as the tile sends it (an int32 array): the program
+    # the tile warmed, and no other
+    count = np.asarray(sz.max_lanes, np.int32)
+    got_digest = np.asarray(fn(digests, sigs, pubs, count))
+    # and a count inside the second kernel tile: the same program stops
+    # after it, and every lane from the count on reads False
+    part = min(257, sz.max_lanes - 1)
+    got_part = np.asarray(fn(digests, sigs, pubs, np.asarray(part, np.int32)))
+    check((got_part[:part] == got_digest[:part]).all()
+          and not got_part[part:].any(),
+          f"n_lanes={part}: verdicts before it differ or one past it is set")
     check(got_digest.shape == (sz.max_lanes,) and got_digest.dtype == bool,
           f"digest entry returned {got_digest.shape} {got_digest.dtype}")
     for t in side:
@@ -476,7 +486,7 @@ def phase_kernel(sz: Sizes, seed: int, rehearse: bool, workdir: str) -> dict:
     # _use_pallas(): that flag picks plain XLA silently off-TPU)
     t0 = time.perf_counter()
     texts = {
-        "verify_batch_digest": fn.lower(digests, sigs, pubs)
+        "verify_batch_digest": fn.lower(digests, sigs, pubs, count)
         .compile().as_text(),
         "verify_batch": fver._verify_impl.lower(
             msgs, lens, sigs, pubs, msgs.shape[1],
@@ -489,7 +499,7 @@ def phase_kernel(sz: Sizes, seed: int, rehearse: bool, workdir: str) -> dict:
         for k, cnt in mosaic.items():
             check(cnt >= 1, f"{k}: no Mosaic call (tpu_custom_call) in "
                             f"the compiled program")
-    probes = _device_probes(fn, (digests, sigs, pubs))
+    probes = _device_probes(fn, (digests, sigs, pubs, count))
     res = dict(
         device=dev, lanes=sz.max_lanes, msg_width=msg_width,
         valid=int(want.sum()), rejected=int((~want).sum()),
@@ -1099,8 +1109,8 @@ def _pool_run(sz: Sizes, pcap_path: str, total: int, devices) -> dict:
     placed = [set() for _ in fns]
 
     def spy(i, f):
-        def g(d, s, p):
-            out = f(d, s, p)
+        def g(d, s, p, n):
+            out = f(d, s, p, n)
             placed[i].update(dev.id for dev in out.devices())
             return out
 

@@ -347,7 +347,9 @@ def build_validator_topology(cfg: Config, identity_secret: bytes,
                 # one compiled shape: every sub-batch pads to max_lanes,
                 # so the boot-time warm covers steady state AND trickle
                 # (bucket shapes would each pay a cold compile on first
-                # use)
+                # use).  The kernel is told the real lane count and skips
+                # the tiles of padding, so the one shape costs a small
+                # batch a small batch's kernel time
                 pad_full=True,
                 devices=verify_devs[i],
                 stall_patience_s=cfg.verify_stall_patience_s,

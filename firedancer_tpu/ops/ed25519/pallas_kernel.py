@@ -14,7 +14,12 @@ VMEM-resident values: the math is written once and runs under XLA (tests,
 CPU interpret mode) or Mosaic (TPU) unchanged.
 
 Grid = batch tiles; Pallas pipelines each tile's HBM→VMEM input DMA behind
-the previous tile's compute.  The op-count choices in point.py follow one
+the previous tile's compute.  The tiles run one after the other on the
+chip's one core, so a batch costs its tile count; the number of real lanes
+rides along as a traced scalar (`n_lanes`) and bounds the grid, which ends
+with the last tile that holds a real lane: one compiled program whatever
+the count, and a batch padded to the compiled shape costs only the tiles
+that hold a real lane.  The op-count choices in point.py follow one
 cost model: the kernel is int32 VPU work and is bound by how fast the VPU
 issues multiplies, so multiplies are what every variant counts.
 """
@@ -22,7 +27,6 @@ issues multiplies, so multiplies are what every variant counts.
 from __future__ import annotations
 
 import functools
-import os as _os
 
 import jax
 import jax.numpy as jnp
@@ -31,12 +35,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from firedancer_tpu.utils.hotpath import hot_path
 
+from . import TILE  # lanes per grid step
 from . import field as F
 from . import point as PT
 
 NL = F.NLIMB
-#: lanes per grid step; tunable via env for experiments
-TILE = int(_os.environ.get("FDT_PALLAS_TILE", "256"))
 
 # array constants the kernel math needs, packed into one (rows, TILE) input
 # (Pallas kernels cannot capture array constants; batch-dim-1 elements would
@@ -115,9 +118,17 @@ def _verify_core_kernel(c_ref, k_ref, s_ref, ay_ref, ry_ref, ok_ref):
         ok_ref[0, :] = ok.astype(jnp.int32)
 
 
+def _lane_count(n_lanes, b):
+    """The count as an int32 scalar operand; absent, `b`."""
+    return jnp.asarray(b if n_lanes is None else n_lanes, jnp.int32)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 @hot_path(static=("interpret",))
-def verify_core(k_digits, s_digits, a_y, a_sign, r_y, r_sign, *, interpret=False):
+def verify_core(
+    k_digits, s_digits, a_y, a_sign, r_y, r_sign, n_lanes=None, *,
+    interpret=False,
+):
     """Fused decompress + ([k](-A) + [s]B == R).
 
     k_digits, s_digits: (64, B) int32 signed radix-16 digits in [-8, 7]
@@ -125,9 +136,17 @@ def verify_core(k_digits, s_digits, a_y, a_sign, r_y, r_sign, *, interpret=False
     (1, B) sign bits (from point.decompress_bytes).  B is padded to a TILE
     multiple internally.  Small-order rejection happens in the caller's
     prologue (byte blocklist).  Returns (B,) bool.
+
+    n_lanes: int32 scalar, the lanes whose verdict the caller reads (the
+    rest is padding); absent, B.  It is an operand, never a static
+    argument, so its value picks no program.  A lane before it gets the
+    verdict it gets without the count, bit for bit; a lane at or past it
+    reads False, and a whole tile of such lanes is never started (at
+    least one tile runs).
     """
     B = k_digits.shape[-1]
     Bp = ((B + TILE - 1) // TILE) * TILE
+    n = _lane_count(n_lanes, B)
 
     def pad(x):
         return jnp.pad(x, ((0, 0), (0, Bp - B))) if Bp != B else x
@@ -138,7 +157,11 @@ def verify_core(k_digits, s_digits, a_y, a_sign, r_y, r_sign, *, interpret=False
     s_n = pad(s_digits)
 
     consts = jnp.asarray(_pack_consts())
-    grid = (Bp // TILE,)
+    # the grid ends with the last tile that holds a real lane.  Its bound
+    # is a traced scalar: one program, whatever the count.  (The
+    # interpreter takes no traced bound and walks every tile.)
+    tiles = Bp // TILE
+    grid = tiles if interpret else jnp.clip((n + TILE - 1) // TILE, 1, tiles)
     spec = lambda rows: pl.BlockSpec(  # noqa: E731
         (rows, TILE), lambda i: (0, i), memory_space=pltpu.VMEM
     )
@@ -148,9 +171,12 @@ def verify_core(k_digits, s_digits, a_y, a_sign, r_y, r_sign, *, interpret=False
     ok = pl.pallas_call(
         _verify_core_kernel,
         out_shape=jax.ShapeDtypeStruct((1, Bp), jnp.int32),
-        grid=grid,
+        grid=(grid,),
         in_specs=[const_spec, spec(64), spec(64), spec(NL + 1), spec(NL + 1)],
         out_specs=spec(1),
         interpret=interpret,
     )(consts, k_n, s_n, a_cat, r_cat)
-    return ok[0, :B] != 0
+    # a tile past the grid's end was never written, and padding lanes that
+    # share the last tile with real ones were computed: the count, not the
+    # memory or the arithmetic, is what makes a lane at or past it False
+    return (ok[0, :B] != 0) & (jnp.arange(B) < n)

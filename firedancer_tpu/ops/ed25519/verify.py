@@ -68,10 +68,16 @@ def _use_pallas() -> bool:
 
 
 @hot_path(static=("use_pallas",))
-def _verify_from_digest(digest, sigs, pubs, use_pallas):
+def _verify_from_digest(digest, sigs, pubs, use_pallas, n_lanes):
     """Steps 1-3 and 5 shared by the message and digest entry points;
     `digest` is SHA512(R || A || M) per lane (step 4, from either the
-    device SHA or the host's fdt_sha512_rpm)."""
+    device SHA or the host's fdt_sha512_rpm).
+
+    n_lanes: int32 scalar, the lanes whose verdict the caller reads (the
+    batch size where it reads all).  A lane at or past it reads False on
+    either path; the Pallas kernel also skips the tiles that hold none
+    before it (pallas_kernel.verify_core).  The prologue here runs on
+    every lane."""
     # 1. canonical s
     s_limbs = SC.from_bytes(sigs[:, 32:])
     ok = SC.is_canonical(s_limbs)
@@ -90,7 +96,7 @@ def _verify_from_digest(digest, sigs, pubs, use_pallas):
         a_y, a_sign = PT.decompress_bytes(pubs)
         r_y, r_sign = PT.decompress_bytes(sigs[:, :32])
         return ok & pallas_kernel.verify_core(
-            k_digits, s_digits, a_y, a_sign, r_y, r_sign
+            k_digits, s_digits, a_y, a_sign, r_y, r_sign, n_lanes
         )
 
     # 2. decompress
@@ -101,7 +107,7 @@ def _verify_from_digest(digest, sigs, pubs, use_pallas):
     # 5. [k](-A) + [s]B == R
     neg_a_table = PT.build_neg_table9(a_pt)
     acc = PT.double_scalar_mul(k_digits, neg_a_table, s_digits)
-    return ok & PT.eq_external(acc, r_pt)
+    return ok & PT.eq_external(acc, r_pt) & (jnp.arange(ok.shape[0]) < n_lanes)
 
 
 @functools.partial(jax.jit, static_argnames=("msg_len", "use_pallas"))
@@ -111,7 +117,7 @@ def _verify_impl(msgs, lens, sigs, pubs, msg_len, use_pallas=False):
     # 4. k = SHA512(R || A || M) mod L, on device
     cat = jnp.concatenate([sigs[:, :32], pubs, msgs], axis=1)
     digest = _sha.sha512(cat, lens.astype(jnp.int32) + 64)
-    return _verify_from_digest(digest, sigs, pubs, use_pallas)
+    return _verify_from_digest(digest, sigs, pubs, use_pallas, sigs.shape[0])
 
 
 def verify_batch(msgs, lens, sigs, pubs):
@@ -313,13 +319,13 @@ def verify_batch_digest_rlc(digests, sigs, pubs, zbytes=None):
 
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
 @hot_path(static=("use_pallas",))
-def _verify_digest_impl(digests, sigs, pubs, use_pallas=False):
+def _verify_digest_impl(digests, sigs, pubs, n_lanes, use_pallas=False):
     # step 4's SHA512 was done on the host (fdt_sha512_rpm inside
     # fdt_verify_expand); everything else is shared
-    return _verify_from_digest(digests, sigs, pubs, use_pallas)
+    return _verify_from_digest(digests, sigs, pubs, use_pallas, n_lanes)
 
 
-def verify_batch_digest(digests, sigs, pubs):
+def verify_batch_digest(digests, sigs, pubs, n_lanes=None):
     """Verify from precomputed k-digests = SHA512(R || A || M).
 
     The host computes the digests during lane expansion so the device is
@@ -327,11 +333,23 @@ def verify_batch_digest(digests, sigs, pubs):
     trade whenever host→device bandwidth, not device compute, bounds the
     pipeline (which of the two bounds it on this installation is not
     measured; ROADMAP D4).  digests: (B, 64); sigs: (B, 64);
-    pubs: (B, 32).  Returns (B,) bool."""
+    pubs: (B, 32).  Returns (B,) bool.
+
+    n_lanes: the rows before it are the batch, the rest padding: those
+    read False, and on the chip the kernel skips the tiles they fill.  It
+    is an operand of the one program, absent or not.  A caller that jits
+    this function passes it as an int32 ARRAY every time
+    (`np.asarray(n, np.int32)`): a Python int is weakly typed and would
+    trace a second program."""
     digests = jnp.asarray(digests, jnp.uint8)
     sigs = jnp.asarray(sigs, jnp.uint8)
     pubs = jnp.asarray(pubs, jnp.uint8)
-    return _verify_digest_impl(digests, sigs, pubs, use_pallas=_use_pallas())
+    if n_lanes is None:
+        n_lanes = sigs.shape[0]
+    return _verify_digest_impl(
+        digests, sigs, pubs, jnp.asarray(n_lanes, jnp.int32),
+        use_pallas=_use_pallas(),
+    )
 
 
 def verify_batch_digest_on(device):
@@ -350,11 +368,14 @@ def verify_batch_digest_on(device):
     in 346.7 s cold, one in 56.2 s (PERF.md section 6, my chip runs, PR 28)."""
     use_pallas = _use_pallas()
 
-    def fn(digests, sigs, pubs):
+    def fn(digests, sigs, pubs, n_lanes=None):
         d = jax.device_put(jnp.asarray(digests, jnp.uint8), device)
         s = jax.device_put(jnp.asarray(sigs, jnp.uint8), device)
         p = jax.device_put(jnp.asarray(pubs, jnp.uint8), device)
-        return _verify_digest_impl(d, s, p, use_pallas=use_pallas)
+        if n_lanes is None:
+            n_lanes = s.shape[0]
+        n = jax.device_put(np.asarray(n_lanes, np.int32), device)
+        return _verify_digest_impl(d, s, p, n, use_pallas=use_pallas)
 
     fn.device = device
     #: the jit object whose cache holds this fn's compiled programs

@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import inspect
 import queue
 import threading
 import time
@@ -53,6 +54,7 @@ import numpy as np
 from firedancer_tpu.disco import trace as SPAN
 from firedancer_tpu.disco.metrics import MetricsSchema, device_counters
 from firedancer_tpu.disco.mux import MuxCtx, Tile, now_ts, ns_to_ts, ts_diff
+from firedancer_tpu.ops.ed25519 import TILE as KERNEL_TILE
 from firedancer_tpu.tango import rings as R
 from firedancer_tpu.tango.tempo import tickcount
 
@@ -119,6 +121,21 @@ def _no_span(name: str, **kw):
     return _NULL_SPAN
 
 
+def _takes_count(fn) -> bool:
+    """Whether a device fn can be handed a batch's lane count after its
+    three arrays.  The tile's own fns can (verify_batch_digest's `n_lanes`),
+    and so can the host verifier standing in for a device (`lanes`); a stub
+    written for the three arrays is called with them alone, and computes
+    every padded lane as it always did."""
+    try:
+        params = inspect.signature(fn).parameters.values()
+    except (TypeError, ValueError):  # no signature to read (or no fn)
+        return True
+    return any(p.kind is p.VAR_POSITIONAL for p in params) or 4 <= sum(
+        p.kind in (p.POSITIONAL_ONLY, p.POSITIONAL_OR_KEYWORD) for p in params
+    )
+
+
 class FallbackPolicy:
     """Graceful degradation for the batched device-verify path.
 
@@ -154,6 +171,9 @@ class FallbackPolicy:
         fault_hook=None,
     ):
         self.device_fn = device_fn
+        #: a batch's args are its three padded arrays and its real lane
+        #: count (VerifyTile._submit_front); how many of them device_fn takes
+        self._dev_argc = 4 if _takes_count(device_fn) else 3
         self.host_fn = host_fn
         self.trip_after = max(trip_after, 1)
         self.reprobe_every = max(reprobe_every, 1)
@@ -202,7 +222,7 @@ class FallbackPolicy:
             try:
                 if self.fault_hook is not None:
                     self.fault_hook()
-                return ("dev", self.device_fn(*args))
+                return ("dev", self.device_fn(*args[: self._dev_argc]))
             except Exception:
                 self._device_failed()
         return ("host", None)
@@ -227,7 +247,7 @@ class FallbackPolicy:
             # counting it would leave monitors alarming forever on
             # CPU-only deployments.
             self.fallback_batches += 1
-        return self.host_fn(*args, lanes=lanes)
+        return self.host_fn(*args[:3], lanes=lanes)
 
 
 class DevicePolicy(FallbackPolicy):
@@ -324,7 +344,7 @@ class DevicePolicy(FallbackPolicy):
             try:
                 if self.fault_hook is not None:
                     self.fault_hook(self.index)
-                return ("dev", self.device_fn(*args))
+                return ("dev", self.device_fn(*args[: self._dev_argc]))
             except Exception:
                 self._device_failed()
                 return ("fail", None)
@@ -345,7 +365,7 @@ class DevicePolicy(FallbackPolicy):
         if kind == "host":
             if self.device_fn is not None:
                 self.fallback_batches += 1
-            return self.host_fn(*args, lanes=lanes)
+            return self.host_fn(*args[:3], lanes=lanes)
         return None  # "fail": never dispatched (quarantine raced)
 
 
@@ -840,11 +860,12 @@ class VerifyTile(Tile):
     ):
         """pad_full: always pad sub-batches to max_lanes (one compiled
         shape, so one boot-time compile; what both config-built
-        topologies use).  A padded batch costs a full batch time
-        whatever it carries, which is why a partial batch is held while
-        its device has one in flight (_submit_staged).  False pads to
-        power-of-two buckets (log2(max_lanes) compiled shapes, each a
-        cold compile on first use; cheaper per batch on trickle traffic).
+        topologies use).  The batch's real lane count goes to the device
+        with it and the kernel runs only the tiles (256 lanes) that hold
+        a real lane, so a padded batch costs its own tiles' time, not
+        the full shape's.  False pads to power-of-two buckets
+        (log2(max_lanes) compiled shapes, each a cold compile on first
+        use; the same kernel time, less to pad and to copy).
 
         shard=(idx, cnt): horizontal scaling — this replica only processes
         frags with seq % cnt == idx (reference: round-robin seq sharding
@@ -920,6 +941,9 @@ class VerifyTile(Tile):
                 "dedup_drop_txns",
                 "verified_sigs",
                 "device_batches",
+                # lanes the kernel computed for the landed batches: each
+                # batch's real lanes rounded up to whole kernel tiles
+                "kernel_lanes",
                 # FallbackPolicy state, mirrored each loop so monitors
                 # see degradation live (sums across the pool's domains)
                 "fallback_batches",
@@ -1042,12 +1066,16 @@ class VerifyTile(Tile):
             # (one: 56.2 s); with the programs cached the whole boot of
             # `leader4` took 112.4 s (PERF.md section 6, my chip runs,
             # PR 28): ROADMAP S6.
+            # The lane count goes in exactly as _submit_front sends it
+            # (an int32 array; a Python int is weakly typed and would
+            # trace a program of its own): its VALUE picks no program.
             for f in self._fns:
                 np.asarray(
                     f(
                         np.zeros((self.max_lanes, 64), dtype=np.uint8),
                         np.zeros((self.max_lanes, 64), np.uint8),
                         np.zeros((self.max_lanes, 32), np.uint8),
+                        np.asarray(self.max_lanes, np.int32),
                     )
                 )
         return self._fns
@@ -1332,9 +1360,13 @@ class VerifyTile(Tile):
            that IS in flight, so it cannot deadlock; halt, crash
            teardown and repartition flush or drop staging as before.
 
-        Under `pad_full` a batch costs one full batch time whatever it
-        carries: a partial batch queued behind k others delays its txns
-        k batch times and takes a slot from the next, fuller, one."""
+        The rule was set when a padded batch cost one full batch time
+        whatever it carried (9.3 ms): a partial batch queued behind k
+        others delayed its txns k batch times and took a slot from the
+        next, fuller, one.  A batch now costs the kernel its own tiles
+        (0.6 ms for up to 256 lanes; PERF.md section 6, PR 31), so the
+        hold is mostly the host's turnaround; the rule stands as it was
+        until a PR retunes it with those numbers."""
         pool = self._pool
         while self._staged_lanes:
             full = self._staged_lanes >= self.max_lanes
@@ -1415,6 +1447,9 @@ class VerifyTile(Tile):
                     _pad2(b["digests"], pad),
                     _pad2(b["sigs"], pad),
                     _pad2(b["pubs"], pad),
+                    # the kernel skips the tiles past it; same dtype and
+                    # shape as the boot-time warm (_make_device_fns)
+                    np.asarray(lanes, np.int32),
                 ),
             )
         self._phase_ns["submit_ns"] += tickcount() - t0
@@ -1489,6 +1524,10 @@ class VerifyTile(Tile):
             )
         ctx.metrics.inc("verified_sigs", lanes)
         ctx.metrics.inc("device_batches")
+        # what the batch cost the kernel, which runs whole tiles up to the
+        # last real lane (pallas_kernel.verify_core).  A batch the host
+        # path served is counted alike: `fallback_batches` counts those
+        ctx.metrics.inc("kernel_lanes", -(-lanes // KERNEL_TILE) * KERNEL_TILE)
         # counted here, beside device_batches, under the domain the pool
         # accepted the result from: a window's deltas of the dev{i}_landed
         # sum to device_batches' delta

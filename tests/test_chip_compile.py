@@ -72,7 +72,8 @@ def _assert_fits_and_has_mosaic(compiled):
 
 def test_verify_tile_program_compiles_for_v5e(one_chip):
     """`_verify_digest_impl(use_pallas=True)` — the verify tile's program
-    — at the config's default max_lanes."""
+    — at the config's default max_lanes, the batch's lane count its fourth
+    operand (it bounds the kernel's grid: a traced bound Mosaic must take)."""
     from firedancer_tpu.app import config as C
     from firedancer_tpu.ops.ed25519 import verify as fver
 
@@ -80,9 +81,12 @@ def test_verify_tile_program_compiles_for_v5e(one_chip):
     compiled = _compile(
         fver._verify_digest_impl, one_chip,
         ((lanes, 64), np.uint8), ((lanes, 64), np.uint8),
-        ((lanes, 32), np.uint8), use_pallas=True,
+        ((lanes, 32), np.uint8), ((), np.int32), use_pallas=True,
     )
     _assert_fits_and_has_mosaic(compiled)
+    # the lane count is an operand of the ONE kernel: no second Mosaic
+    # call for the batches that stop short of the last tile
+    assert compiled.as_text().count("tpu_custom_call") == 1
 
 
 def test_verify_message_entry_compiles_for_v5e(one_chip):
