@@ -181,6 +181,12 @@ class Deployment:
         return [n for n, cnc in self.topo._cncs.items()
                 if cnc.signal_query() == R.CNC_FAIL]
 
+    def tile_pids(self) -> dict:
+        """Each tile's child pid (process runtime; a tile that runs as a
+        thread of this process has none and is left out)."""
+        pids = {n: self.topo.tile_pid(n) for n in self.topo.tiles}
+        return {n: p for n, p in pids.items() if p}
+
     def runtime(self) -> tuple[str, str]:
         return self.topo._runtime, self.topo._loop_kw["stem"]
 

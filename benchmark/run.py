@@ -49,7 +49,8 @@ sys.path.insert(0, os.path.dirname(HERE))
 import numpy as np  # noqa: E402
 
 from benchmark.lib import corpus as C  # noqa: E402
-from benchmark.lib import ledger, reference, sampler, sender, tracered  # noqa: E402
+from benchmark.lib import (hostacct, ledger, reference, sampler,  # noqa: E402
+                           sender, tracered)
 
 #: exit codes: 0 a chip run that printed its result; 1 no TPU, or a
 #: malformed run; 2 a crash; 3 a rehearsal that ran through (not a chip run)
@@ -188,7 +189,7 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
     keys = C.make_keys(n_accounts, seed)
     pubs = keys[3]
     workdir = tempfile.mkdtemp(prefix="fdt_benchmark_")
-    dep = shm = proc = hook = None
+    dep = shm = proc = hook = watch = None
     sock = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
     n_rows = n_unique + n_unique // cell["dup_every"] + (
         n_unique // cell["bad_every"])
@@ -246,8 +247,15 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
         # once a second: how steady the window was, and where it queued
         by_second: list = []
 
+        # the `host` line's readings (lib/hostacct.py) are taken at the
+        # window's edges by a thread of their own, `watch`: this thread,
+        # which samples or sends, only stamps its own CPU clock
+        tile_pids = dep.tile_pids()
+        host_reads = hostacct.Host()  # set-up: what this host gives
+
         def at_edge(which: str, now: int, sent: int) -> None:
-            edge[which] = dict(t=now, count=read_terminal(), sent=sent)
+            edge[which] = dict(t=now, count=read_terminal(), sent=sent,
+                               cpu_ns=time.thread_time_ns())
             if trace:
                 edge[which]["snap"] = dep.snapshot()
                 edge[which]["kdrops"] = udp_kernel_drops(dep.port)
@@ -274,7 +282,7 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
                 state["next_sec"] = now + 1_000_000_000
 
         slice_ns = int(min(TRACE_SLICE_S, seconds / 2) * 1e9)
-        sent_at = None
+        sent_at = sender_said = held = None
         if open_loop:
             dgram_rate = cell["rate_tps"] * copies
             interval_ns = round(cell["burst"] / dgram_rate * 1e9)
@@ -293,6 +301,8 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
             tx.close()
             T0 = t_start + int(warmup_s * 1e9)
             T1 = T0 + int(seconds * 1e9)
+            watch = hostacct.Watch(host_reads, (T0, T1),
+                                   {**tile_pids, "sender": proc.pid})
             t_trace = T0 + (T1 - T0 - slice_ns) // 2
             t_sched_end = t_start + int(due_rel[-1])
             due = t_start + due_rel
@@ -306,15 +316,16 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
                         dep.settled() - base >= n_rows or now > t_limit):
                     state["drained"] = True
 
-            ts, cs, gap = sampler.sample_until(
+            ts, cs, gap, held = sampler.sample_until(
                 read_terminal, lambda now, c: state.get("drained", False),
                 every=every, every_ns=10_000_000)
             t_end = time.monotonic_ns()
-            sent_at = rx.recv() if rx.poll(30.0) else None
+            sender_said = rx.recv() if rx.poll(30.0) else None
             proc.join(30.0)
-            if sent_at is None or proc.exitcode != 0:
+            if sender_said is None or proc.exitcode != 0:
                 raise Malformed(f"the sender process failed (exit "
                                 f"{proc.exitcode})")
+            sent_at = sender_said["sent_at"]
             n_sent = n_rows
             say("sampler", change_points=len(ts),
                 longest_gap_ms=round(gap / 1e6, 3))
@@ -325,6 +336,7 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
             T0 = t_start + int(warmup_s * 1e9)
             T1 = T0 + int(seconds * 1e9)
             t_trace = T0 + (T1 - T0 - slice_ns) // 2
+            watch = hostacct.Watch(host_reads, (T0, T1), tile_pids)
             n_sent = sender.closed_loop(
                 sock, addr, send,
                 in_flight=lambda sent: sent - (dep.settled() - base),
@@ -380,6 +392,7 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
     finally:
         signer.shutdown(wait=True)
         sock.close()
+        host_read = watch.done() if watch is not None else []
         if proc is not None and proc.is_alive():
             proc.kill()
             proc.join()
@@ -405,7 +418,8 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
     # closed loop: the counter's growth between the two edge reads, over
     # the time between those reads (each a turn of the sender's loop late)
     rate = (edge["T1"]["count"] - edge["T0"]["count"]) / window_s
-    lag_in_win = late = None
+    lag_in_win = lag_sent = late = sent_in_win = due_in_win = None
+    host = {}
     if open_loop:
         uniq = np.flatnonzero(kind == C.KIND_UNIQUE)
         t_due = due[uniq]
@@ -418,13 +432,26 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
         lag_in_win = lag[in_win]
         row_in_win = (due >= T0) & (due < T1)
         late = (sent_at - due)[row_in_win]
+        sent_in_win, due_in_win = sent_at[uniq][in_win], t_due[in_win]
+        # the generator's share beside the program's: the lag a txn would
+        # have seen from a sender that was never late
+        lag_sent = sampler.after_send(lag_in_win, sent_in_win, due_in_win)
         # a backlog that grows shows as a lag that rises through the window
         half = t_due < (T0 + T1) // 2
         say("lag", first_half_p50_ms=round(sampler.percentile(
             lag[in_win & half], 50) / 1e6, 3),
             second_half_p50_ms=round(sampler.percentile(
                 lag[in_win & ~half], 50) / 1e6, 3),
-            sender_late_p99_us=round(float(np.percentile(late, 99)) / 1e3, 1))
+            **{f"after_send_p{q}_ms": round(
+                sampler.percentile(lag_sent, q) / 1e6, 4) for q in (50, 95)},
+            **{f"sender_late_p{q}_us": round(
+                float(np.percentile(late, q)) / 1e3, 1) for q in (50, 95, 99)})
+        host.update(sender.account(sender_said, due, cell["burst"], T0, T1))
+        for what, rows in held.items():
+            n, total, longest = sampler.held_in(rows, T0, T1)
+            host.update({f"sampler_{what}_over_1ms": n,
+                         f"sampler_{what}_sum_ms": hostacct.ms(total),
+                         f"sampler_{what}_max_ms": hostacct.ms(longest)})
     else:
         attempted = edge["T1"]["sent"] - edge["T0"]["sent"]
         failed = max(n_sent - (observed["landed"] + observed["rejected"]
@@ -433,6 +460,12 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
         round((c1 - c0) / ((t1 - t0) / 1e9)) for (t0, c0, _), (t1, c1, _)
         in zip(by_second, by_second[1:])],
         in_flight=[f for _, _, f in by_second])
+    # what the host did to the run between the edges (no metric: a far
+    # run is laid at the generator's, the sampler's or the program's door)
+    host = {**hostacct.window(host_read, list(tile_pids),
+                              edge["T1"]["cpu_ns"] - edge["T0"]["cpu_ns"]),
+            **host}
+    say("host", **{k: hostacct.say(v) for k, v in host.items()})
     say("window", edge_to_edge_s=round(window_s, 4), attempted=attempted,
         failed=failed, setup_s=round(setup_s, 4), rate_tps=round(rate, 4))
 
@@ -440,7 +473,9 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
     # (--trace 0: the cell's end-to-end metrics; --trace 1: its per-layer)
     ctx = dict(rate_tps=rate, lag_ns=lag_in_win, setup_s=setup_s,
                by_second=by_second, t0_ns=T0, t1_ns=T1, seconds=seconds,
-               sender_late_ns=late, config=conf, cell=cell)
+               sender_late_ns=late, sent_at_ns=sent_in_win,
+               due_ns=due_in_win, lag_after_send_ns=lag_sent, config=conf,
+               cell=cell)
     device_out = dict(device, memory_peak_bytes=mem_peak)
     result = dict(correct=ledger.correct(checks), attempted=attempted,
                   failed=failed)

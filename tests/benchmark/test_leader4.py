@@ -15,6 +15,9 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
 
+from append_only import (appended_only, in_order,  # noqa: E402
+                         rows_at_least)  # (this directory)
+
 from benchmark import run as RUN  # noqa: E402
 from benchmark.lib.deploy import load_config  # noqa: E402
 
@@ -23,12 +26,13 @@ CELL = "leader4.paced"
 #: what the cell's own file may say differently from leader.paced's
 OWN = {"config", "chips", "why", "who", "rate_why", "metrics"}
 E2E = {"landed_tps", "lag_p50_ms", "lag_p95_ms", "setup_s"}
-NEW = {
+NEW_IN_ORDER = [
     "verify.reorder_ms_per_batch.leader4",
     "verify.reordered_batch_share.leader4",
     "verify.device_share_min.leader4",
     "verify.dispatch_overlap_share.leader4",
-}
+]
+NEW = set(NEW_IN_ORDER)
 #: rows that need the profiler's trace of the process that holds the chip
 TRACE_ROWS = {"device.idle_share.leader", "verify_core.ms_per_batch",
               "verify.dispatch_ms_per_batch.leader",
@@ -82,7 +86,8 @@ def test_the_cell_reports_what_it_names_and_the_four_new_rows(e2e):
     if e2e:
         assert found == E2E
     else:
-        assert found == (named - E2E) | NEW and len(found) == 18
+        # at least: a later PR's row whose own file lists the cell is found too
+        assert rows_at_least(found, (named - E2E) | NEW)
         assert "bank.e2e_p50_us.leader" not in found
     # and the accepted cells none of the new rows
     for cell in ("leader.paced", "ingress.flood"):
@@ -92,23 +97,27 @@ def test_the_cell_reports_what_it_names_and_the_four_new_rows(e2e):
 def test_the_manifest_holds_the_cell_on_four_chips_and_appends_only():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         b = json.load(f)
-    assert [c["name"] for c in b["configs"]][-1] == "leader4"
-    cell = b["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["chips"]) == (CELL, "leader4", 4)
+    # behind what was there at PR 29, wherever later PRs' entries stand
+    assert in_order([c["name"] for c in b["configs"]],
+                    ["leader", "ingress", "leader4"])
+    assert in_order([w["name"] for w in b["workloads"]],
+                    ["leader.paced", "ingress.flood", CELL])
+    cell = {w["name"]: w for w in b["workloads"]}[CELL]
+    assert (cell["config"], cell["chips"]) == ("leader4", 4)
+    # ONE four-chip cell (PERF.md section 4): a second needs its argument
     assert sum(w["chips"] == 4 for w in b["workloads"]) == 1
-    assert [m["name"] for m in b["per_layer"]][-4:] == [
-        "verify.reorder_ms_per_batch.leader4",
-        "verify.reordered_batch_share.leader4",
-        "verify.device_share_min.leader4",
-        "verify.dispatch_overlap_share.leader4"]
+    # the four rows in order among themselves, not the manifest's last four
+    assert in_order([m["name"] for m in b["per_layer"]], NEW_IN_ORDER)
     named = set(_load("workloads", f"{CELL}.json")["metrics"])
     for m in b["end_to_end"] + b["per_layer"]:
-        lists = CELL in m.get("workloads", [])
-        assert lists == (m["name"] in named | NEW), m["name"]
-        if lists:
-            assert m["workloads"][-1] == CELL
+        own = _load("metrics", m["name"] + ".json").get("workloads", [])
+        # the manifest lists the cell where the cell names the metric or
+        # the metric's own file lists the cell, appended behind the file's
+        assert (CELL in m.get("workloads", [])) == (
+            m["name"] in named or CELL in own), m["name"]
+        assert appended_only(m.get("workloads", []), own), m["name"]
         if m["name"] in NEW:
-            assert m["workloads"] == [CELL]
+            assert own == [CELL]
             assert m["layer"] == "verify tile (host)"
 
 
