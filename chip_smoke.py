@@ -440,9 +440,7 @@ def phase_kernel(sz: Sizes, seed: int, rehearse: bool, workdir: str) -> dict:
     # the tile's own program, built and warmed by the tile's own code at
     # the shape every later phase boots with (same program, same bytes
     # -> the later phases' compile is a persistent-cache hit)
-    tile = VerifyTile(
-        msg_width=msg_width, max_lanes=cfg.verify_max_lanes, pad_full=True
-    )
+    tile = VerifyTile(msg_width=msg_width, max_lanes=cfg.verify_max_lanes)
     t0 = time.perf_counter()
     fn = tile._make_device_fns()[0]
     tile_warm_s = time.perf_counter() - t0
@@ -765,7 +763,7 @@ def _contention_probe(sz: Sizes, workdir: str) -> dict:
         outs=["s_v"],
     )
     topo.tile(
-        VerifyTile(max_lanes=sz.max_lanes, pad_full=True, name="verify"),
+        VerifyTile(max_lanes=sz.max_lanes, name="verify"),
         ins=[("s_v", True)], outs=["v_k"],
     )
     topo.tile(SinkTile(), ins=[("v_k", True)])
@@ -999,8 +997,8 @@ def phase_leader(sz: Sizes, seed: int, rehearse: bool, workdir: str,
 
 def phase_ingress(sz: Sizes, seed: int, rehearse: bool, workdir: str) -> dict:
     """What `fdtctl run` without --full builds, under sub-batch bursts:
-    every burst is its own partial batch, so a tile that padded to
-    power-of-two buckets would compile a new shape per burst here."""
+    every burst is its own partial batch, padded to the one shape the
+    boot warmed: `device_programs` must not grow here."""
     import numpy as np
 
     from firedancer_tpu.app import config as C
@@ -1082,8 +1080,8 @@ def phase_ingress(sz: Sizes, seed: int, rehearse: bool, workdir: str) -> dict:
 
 
 def _pool_run(sz: Sizes, pcap_path: str, total: int, devices) -> dict:
-    """bench.py's verify-path topology (replay -> verify -> dedup ->
-    sink) in this one process with `[tiles.verify] devices = devices`;
+    """The verify-path topology (replay -> verify -> dedup -> sink) in
+    this one process with `[tiles.verify] devices = devices`;
     returns the publish-order tag stream, the counters, and where each
     domain's arrays really lived."""
     import jax
@@ -1097,7 +1095,7 @@ def _pool_run(sz: Sizes, pcap_path: str, total: int, devices) -> dict:
     from firedancer_tpu.tiles.verify import VerifyTile
 
     verify = VerifyTile(
-        max_lanes=sz.max_lanes, pad_full=True, pre_dedup=False,
+        max_lanes=sz.max_lanes, pre_dedup=False,
         devices=devices,
     )
     # placement is read off the result arrays, not off the ordinal

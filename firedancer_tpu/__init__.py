@@ -5,11 +5,10 @@ Layer map (mirrors the reference's bottom-up layering, re-designed TPU-first):
   utils/     environment layer: config, logging, histograms, rng
   tango/     IPC messaging: mcache/dcache rings, flow control, tcache (C + py)
   ops/       protocol algorithms as batched JAX/Pallas kernels: ed25519,
-             sha512/256, txn parsing, pack conflict engine, dedup filters
+             sha512/256, pack conflict prefilter
   tiles/     tile framework: run loop, topology, the pipeline stages
-  parallel/  device mesh, shardings, multi-chip collectives
-  models/    assembled pipelines ("flagship": the ingress hot path
-             quic -> verify_tpu -> dedup -> pack)
+  parallel/  the multi-chip dry run (the verify tile's device pool on
+             virtual CPU devices)
 """
 
 __version__ = "0.1.0"

@@ -27,7 +27,6 @@ from .findings import Finding
 #: so adding a binding module without extending it fails loudly.
 BINDING_MODULES = [
     "firedancer_tpu/tango/rings.py",
-    "firedancer_tpu/models/pipeline.py",
     "firedancer_tpu/ops/ed25519/verify.py",
     "firedancer_tpu/ops/ed25519/sign.py",
     "firedancer_tpu/tiles/wire.py",

@@ -344,13 +344,6 @@ def build_validator_topology(cfg: Config, identity_secret: bytes,
                 # elastic groups shard via the runtime map; static
                 # topologies keep the boot-frozen seq filter
                 shard=((i, n) if n > 1 and not verify_elastic else None),
-                # one compiled shape: every sub-batch pads to max_lanes,
-                # so the boot-time warm covers steady state AND trickle
-                # (bucket shapes would each pay a cold compile on first
-                # use).  The kernel is told the real lane count and skips
-                # the tiles of padding, so the one shape costs a small
-                # batch a small batch's kernel time
-                pad_full=True,
                 devices=verify_devs[i],
                 stall_patience_s=cfg.verify_stall_patience_s,
                 name=f"verify{i}",
@@ -484,11 +477,6 @@ def build_ingress_topology(
             msg_width=cfg.verify_msg_width,
             max_lanes=cfg.verify_max_lanes,
             shard=((i, n) if n > 1 and not verify_elastic else None),
-            # one compiled shape, warmed at boot — as in the validator
-            # topology.  Power-of-two buckets would each pay a cold
-            # compile on first use, in the middle of serving, and long
-            # enough to look like a stalled device
-            pad_full=True,
             devices=verify_devs[i],
             stall_patience_s=cfg.verify_stall_patience_s,
             name=f"verify{i}",

@@ -89,7 +89,7 @@ def _verify_core_kernel(c_ref, k_ref, s_ref, ay_ref, ry_ref, ok_ref):
 
         # the double_scalar_mul loop, 8-way unrolled: one aligned (8, B)
         # digit-chunk read per outer step, then 8 statically-sliced body
-        # copies.  Measured round 4 (scripts/exp_dsm_variants.py): the
+        # copies.  Measured in round 4 (script in git history): the
         # per-iteration loop boundary costs ~5.5 ns/iter/lane (spill +
         # scheduling barrier); unrolling 8x removes 7/8 of it (1.12x),
         # and 16x/32x measure the same — 8x keeps Mosaic compile ~74 s.

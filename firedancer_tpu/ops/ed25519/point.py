@@ -112,11 +112,6 @@ def to_niels_affine(p):
     return (F.carry(y + x), F.carry(y - x), F.mul_rr(t, F.c("D2")))
 
 
-def identity_niels_affine(batch: int):
-    one = jnp.broadcast_to(F.c("ONE"), (F.NLIMB, batch))
-    return (one, one, jnp.zeros_like(one))
-
-
 def add_niels(p, e, with_t: bool = True):
     """p + e where e = (Y+X, Y-X, 2dT, 2Z) niels form (projective)."""
     x1, y1, z1, t1 = p
@@ -223,17 +218,6 @@ def is_small_order(p):
     q = double(double(double(p, with_t=False), with_t=False), with_t=False)
     x8, y8, z8, _ = q
     return F.is_zero(x8) & F.eq(y8, z8)
-
-
-def eq_points(p, q):
-    """General projective equality: X1 Z2 == X2 Z1 and Y1 Z2 == Y2 Z1."""
-    x1, y1, z1, _ = p
-    x2, y2, z2, _ = q
-    z1c = F.carry1(z1)
-    z2c = F.carry1(z2)
-    return F.eq(F.mul_rr(F.carry1(x1), z2c), F.mul_rr(F.carry1(x2), z1c)) & (
-        F.eq(F.mul_rr(F.carry1(y1), z2c), F.mul_rr(F.carry1(y2), z1c))
-    )
 
 
 def eq_external(acc, r):
@@ -355,8 +339,8 @@ def lookup9_affine(table, digit):
 
 def scalar_mul_base(s_digits):
     """[s]B from (64, B) signed digits — fixed-base Strauss over the
-    shared affine B-table.  Used for the [u]B term of batch (RLC)
-    verification; B here is tiny (typically 1)."""
+    shared affine B-table.  sign.py's [r]B (the load generator's corpus
+    signer) runs through it."""
     batch = s_digits.shape[-1]
     b_table = F.c("B_TABLE9")
 

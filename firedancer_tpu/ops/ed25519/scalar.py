@@ -60,18 +60,11 @@ def _fold_once(lo, hi):
 
 
 def _reduce_wide(x):
-    """(n, B) 13-bit limbs, n <= 2*NLIMB+1 -> canonical scalar (NLIMB, B).
-
-    Shared mod-L reduction tail: fold high limbs through _R_POW, ripple
-    the folded carries out, then split at bit 252 (L = 2^252 + c).
+    """(n, B) 13-bit limbs, NLIMB < n <= 2*NLIMB+1 -> canonical scalar
+    (NLIMB, B): fold high limbs through _R_POW, ripple the folded carries
+    out, then split at bit 252 (L = 2^252 + c).
     """
-    if x.shape[0] > NLIMB:
-        v = _fold_once(x[:NLIMB], x[NLIMB:])
-    else:
-        v = jnp.concatenate(
-            [x, jnp.zeros((NLIMB - x.shape[0],) + x.shape[1:], x.dtype)],
-            axis=0,
-        ) if x.shape[0] < NLIMB else x
+    v = _fold_once(x[:NLIMB], x[NLIMB:])
     for _ in range(5):
         v, co = _ripple(v)  # co: (1, B)
         v = _fold_once(v, co)
@@ -110,58 +103,6 @@ def reduce512(digest):
         limbs.append((window >> shift) & MASK)
     x = jnp.stack(limbs, axis=0)  # (40, B)
     return _reduce_wide(x)
-
-
-def mulmod(a, b):
-    """(na, B) x (nb, B) 13-bit limb scalars -> a*b mod L, canonical.
-
-    Exactness: schoolbook columns accumulate min(na, nb) products of
-    13-bit limbs, so min(na, nb) <= 20 keeps every column < 20 * 2^26
-    < 2^31 (int32 exact); na + nb <= 40 keeps the rippled product inside
-    _reduce_wide's 41-limb fold table.  Used by the batch-verification
-    prologue for z*k and z*s (z is a 128-bit = 10-limb random scalar).
-    """
-    na, nb = a.shape[0], b.shape[0]
-    total = na + nb - 1
-    batch = jnp.broadcast_shapes(a.shape[1:], b.shape[1:])
-    cols = F._placed_sum(
-        [
-            (i, jnp.broadcast_to(a[i : i + 1] * b, (nb,) + batch))
-            for i in range(na)
-        ],
-        total,
-        batch,
-    )
-    v, co = _ripple(cols)  # co < 2^13 (product < 2^(13*(na+nb)))
-    return _reduce_wide(jnp.concatenate([v, co], axis=0))
-
-
-def summod(x):
-    """(NLIMB, B) 13-bit limb scalars -> sum mod L as (NLIMB, 1).
-
-    Pairwise tree: each level adds halves and ripples; carries past limb
-    19 (values >= 2^260) fold back through _R_POW so limbs stay 13-bit
-    and the running value stays < 2^254 at every level.
-    """
-    n = x.shape[-1]
-    p2 = 1 << max(0, (n - 1).bit_length())
-    if p2 != n:
-        x = jnp.concatenate(
-            [x, jnp.zeros(x.shape[:-1] + (p2 - n,), x.dtype)], axis=-1
-        )
-    while x.shape[-1] > 1:
-        half = x.shape[-1] // 2
-        v = x[..., :half] + x[..., half:]
-        v, co = _ripple(v)
-        x = _fold_once(v, co)
-        x, co = _ripple(x)  # _fold_once leaves limbs up to ~2^26: renorm
-        x = _add_at0_scalar(x, co)
-    return _reduce_wide(x)
-
-
-def _add_at0_scalar(x, co):
-    """Fold a post-ripple carry (value co * 2^260) back mod L."""
-    return _fold_once(x, co)
 
 
 def to_nibbles(s):

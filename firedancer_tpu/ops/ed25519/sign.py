@@ -23,7 +23,6 @@ import numpy as np
 
 from firedancer_tpu.utils.hotpath import hot_path
 
-from . import field as F
 from . import golden
 from . import point as PT
 from . import scalar as SC
@@ -33,25 +32,11 @@ from . import scalar as SC
 @hot_path
 def _base_mul_compress(r_bytes):
     """(B, 32) uint8 little-endian scalars (< L) -> (B, 32) compressed
-    [r]B encodings.  Strauss loop over the shared affine niels B-table
-    (64 iterations x (4 doubles + 1 add); plain XLA — corpus prep is a
+    [r]B encodings (point.scalar_mul_base; plain XLA — corpus prep is a
     one-time cost, not the verify hot path)."""
-    digits = SC.to_signed_digits(SC.from_bytes(r_bytes))  # (64, B)
-    batch = digits.shape[-1]
-    b_table = F.c("B_TABLE9")
-
-    def body(j, acc):
-        idx = 63 - j
-        d = jax.lax.dynamic_slice_in_dim(digits, idx, 1, axis=0)[0]
-        acc = PT.double(acc, with_t=False)
-        acc = PT.double(acc, with_t=False)
-        acc = PT.double(acc, with_t=False)
-        acc = PT.double(acc, with_t=True)
-        return PT.add_niels_affine(acc, PT.lookup9_affine(b_table, d),
-                                   with_t=False)
-
-    acc = jax.lax.fori_loop(0, 64, body, PT.identity(batch))
-    return PT.compress(acc)
+    return PT.compress(
+        PT.scalar_mul_base(SC.to_signed_digits(SC.from_bytes(r_bytes)))
+    )
 
 
 def public_keys(secrets: list[bytes]) -> list[bytes]:

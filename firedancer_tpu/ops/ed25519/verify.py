@@ -34,7 +34,6 @@ import numpy as np
 from firedancer_tpu.utils.hotpath import hot_path
 
 from .. import sha512 as _sha
-from . import field as F
 from . import golden
 from . import point as PT
 from . import scalar as SC
@@ -133,188 +132,6 @@ def verify_batch(msgs, lens, sigs, pubs):
     return _verify_impl(
         msgs, lens, sigs, pubs, msgs.shape[1], use_pallas=_use_pallas()
     )
-
-
-def _z_limbs(zbytes):
-    """(B, 16) uint8 random z -> (10, B) 13-bit limbs (128 -> 130 bits)."""
-    padded = jnp.concatenate(
-        [zbytes, jnp.zeros(zbytes.shape[:-1] + (16,), zbytes.dtype)], axis=-1
-    )
-    return F.from_bytes(padded)[:10]
-
-
-def _signed_digits_of_int(n: int) -> np.ndarray:
-    """Host-side signed radix-16 recode (the plain-int analog of
-    scalar.to_signed_digits) for compile-time scalar constants."""
-    digs = []
-    for _ in range(64):
-        d = n & 15
-        n >>= 4
-        if d >= 8:
-            d -= 16
-            n += 1
-        digs.append(d)
-    assert n == 0, "scalar exceeds 64 signed radix-16 digits"
-    return np.array(digs, np.int32).reshape(64, 1)
-
-
-_L_DIGITS = _signed_digits_of_int(golden.L)
-#: 1/2 mod p: recovers x = (n0-n1)/2, y = (n0+n1)/2 from an affine niels
-#: triple (y+x, y-x, 2dxy) without re-running the decompress sqrt chain
-_INV2_LIMBS = F.int_to_limbs((golden.P + 1) // 2).reshape(F.NLIMB, 1)
-
-
-def _torsion_free(pts):
-    """(N,) bool: each point lies in the prime-order subgroup
-    ([L]P == identity), batched as one [L](-P) + [0]B dsm over
-    already-decompressed extended coords.
-
-    Why the RLC path needs this (ADVICE.md round 5, msm_kernel.py): the
-    batch equation weights each R_i directly by its odd z_i, and odd
-    weights can NEVER separate order-2 torsion components — two
-    signatures built on R' = R + T2 have residual T2 each, and
-    z1*T2 + z2*T2 = (odd+odd)*T2 = identity for EVERY z pair, so the
-    bare equation deterministically accepts both (A-side torsion is
-    weighted by (z*k mod L) mod 2 instead: randomized by the mod-L
-    reduction, still a coin-flip accept).  Mixed-order points are the
-    only source of torsion residuals; restricting the accept path to
-    subgroup points removes the component entirely, after which
-    random-z soundness is the standard prime-order argument.
-    """
-    n = pts[0].shape[-1]
-    ldig = jnp.broadcast_to(jnp.asarray(_L_DIGITS), (64, n))
-    acc = PT.double_scalar_mul(
-        ldig, PT.build_neg_table9(pts), jnp.zeros((64, n), jnp.int32)
-    )
-    return PT.eq_points(acc, PT.identity(n))
-
-
-def _torsion_free_pair(a_pt, r_pt):
-    """(B,) bool: BOTH A_i and R_i subgroup-checked in one dsm over the
-    2B stacked points.  See _torsion_free."""
-    both = tuple(
-        jnp.concatenate([a, r], axis=-1) for a, r in zip(a_pt, r_pt)
-    )
-    tf = _torsion_free(both)
-    b = a_pt[0].shape[-1]
-    return tf[:b] & tf[b:]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-@hot_path(static=("interpret",))
-def _verify_digest_rlc_impl(digests, sigs, pubs, zbytes, interpret=False):
-    """Batch (RLC) verification: returns (lane_ok (B,), batch_ok ()).
-
-    lane_ok is the per-lane prologue verdict (canonical s, small-order
-    blocklist, decompress); batch_ok is the one RLC group equation over
-    the lanes that passed the prologue AND a per-lane prime-order
-    subgroup check on every included A/R ([L]P == identity,
-    _torsion_free_pair).  Accept lane i iff batch_ok & lane_ok[i]; on
-    !batch_ok the caller falls back to the strict per-sig kernel, so a
-    mixed-order point anywhere in the batch routes the WHOLE batch to
-    the strict path and the RLC accept can never diverge from it.  See
-    msm_kernel.py for semantics.
-    """
-    from . import msm_kernel as MSM
-
-    # prologue checks, shared with the per-sig path.  Decompress + niels
-    # conversion run in a fused Pallas pass: the sqrt chain is ~250
-    # sequential field ops, and one kernel keeps their intermediates in
-    # VMEM instead of leaving the fusion boundaries to XLA
-    s_limbs = SC.from_bytes(sigs[:, 32:])
-    ok = SC.is_canonical(s_limbs)
-    ok = ok & ~_is_small_order_enc(pubs) & ~_is_small_order_enc(sigs[:, :32])
-    a_y, a_sign = PT.decompress_bytes(pubs)
-    r_y, r_sign = PT.decompress_bytes(sigs[:, :32])
-    an3_raw, rn3_raw, dc_ok = MSM.decompress_niels(
-        a_y, a_sign, r_y, r_sign, interpret=interpret
-    )
-    ok = ok & dc_ok
-    okm = ok[None, :]
-
-    k_limbs = SC.reduce512(digests)
-    z10 = _z_limbs(zbytes)
-    c_limbs = SC.mulmod(z10, k_limbs)  # z*k mod L
-    z20 = jnp.concatenate([z10, jnp.zeros_like(z10)], axis=0)
-    cdig = jnp.where(okm, SC.to_signed_digits(c_limbs), 0)
-    zdig = jnp.where(okm, SC.to_signed_digits(z20)[:33], 0)
-
-    su = jnp.where(okm, SC.mulmod(z10, s_limbs), 0)
-    u = SC.summod(su)  # sum z_i s_i mod L over included lanes
-    udig = SC.to_signed_digits(u)  # (64, 1)
-
-    def mask_niels(n3):
-        ident = jnp.concatenate(
-            PT.identity_niels_affine(n3.shape[-1]), axis=0
-        )
-        return jnp.where(okm, n3, ident)
-
-    batch_ok = MSM.msm_check(
-        cdig, zdig, mask_niels(an3_raw), mask_niels(rn3_raw), udig,
-        interpret=interpret,
-    )
-    # cofactor-gap closure: the batch accept is only sound over the
-    # prime-order subgroup; a mixed-order A or R on any included lane
-    # fails the batch so the caller's strict per-sig fallback decides.
-    # (Excluded lanes — !ok — are already masked to the identity and
-    # cannot poison the equation, so their torsion is irrelevant.)
-    # The gate's extended coords are RECONSTRUCTED from the niels forms
-    # the fused Pallas pass already computed — affine niels is
-    # (y+x, y-x, 2dxy), so x = (n0-n1)/2 and y = (n0+n1)/2, two constant
-    # muls per point — rather than re-running the decompress sqrt chain
-    # (~250 sequential field ops, the dominant prologue cost) over the
-    # 2B points.  Garbage on !dc_ok lanes is fine: masked via ~ok below.
-    n3 = jnp.concatenate([an3_raw, rn3_raw], axis=-1)  # (3*NL, 2B)
-    ypx, ymx = n3[: F.NLIMB], n3[F.NLIMB : 2 * F.NLIMB]
-    inv2 = jnp.asarray(_INV2_LIMBS)
-    x = F.carry1(F.mul_rr(inv2, F.carry1(ypx - ymx)))
-    y = F.carry1(F.mul_rr(inv2, F.carry1(ypx + ymx)))
-    z = jnp.broadcast_to(jnp.asarray(F.c("ONE")), x.shape).astype(x.dtype)
-    tf2 = _torsion_free((x, y, z, F.mul_rr(x, y)))
-    b = ok.shape[0]
-    batch_ok = batch_ok & jnp.all((tf2[:b] & tf2[b:]) | ~ok)
-    return ok, batch_ok
-
-
-def _use_rlc() -> bool:
-    """Opt-in (FDT_VERIFY_RLC=1).  The bucket-MSM batch path saves curve
-    operations but pays a bucket read-modify-write per update, and in
-    its one A/B against the per-sig Strauss kernel it lost — so per-sig
-    stays the default.  Not measured on this installation; ROADMAP
-    S3(b)/D2 decide whether the path stays."""
-    import os
-
-    env = os.environ.get("FDT_VERIFY_RLC")
-    if env is not None:
-        return env.strip().lower() not in ("", "0", "false", "no", "off")
-    return False
-
-
-def verify_batch_digest_rlc(digests, sigs, pubs, zbytes=None):
-    """Batch-verify from precomputed k-digests: RLC accept fast path with
-    strict per-sig fallback whenever the batch equation fails.
-
-    zbytes: (B, 16) uint8 per-batch secret randomness (odd z enforced
-    here); defaults to os.urandom.  Returns (B,) bool.
-    """
-    import os
-
-    digests = jnp.asarray(digests, jnp.uint8)
-    sigs = jnp.asarray(sigs, jnp.uint8)
-    pubs = jnp.asarray(pubs, jnp.uint8)
-    B = sigs.shape[0]
-    if zbytes is None:
-        zbytes = np.frombuffer(os.urandom(16 * B), np.uint8).reshape(B, 16)
-    zbytes = np.asarray(zbytes).copy()
-    zbytes[:, 0] |= 1  # odd z: no 8-torsion residual survives one lane
-    lane_ok, batch_ok = _verify_digest_rlc_impl(
-        digests, sigs, pubs, jnp.asarray(zbytes),
-        # Pallas interpret mode off-TPU (tests); Mosaic on TPU
-        interpret=jax.default_backend() != "tpu",
-    )
-    if bool(np.asarray(batch_ok)):
-        return lane_ok
-    return verify_batch_digest(digests, sigs, pubs)
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas",))

@@ -1,22 +1,15 @@
-"""Load-generation for the full-validator bench (benchg/benchs analog).
+"""Load-generation corpus (benchg analog).
 
 Reference model: src/app/fddev/bench.c:62-90 — benchg tiles sign a
-stream of distinct transfer transactions, benchs blasts them over UDP at
-the QUIC tile's regular (legacy, non-QUIC) transaction port, and bencho
-observes landed transactions via RPC getTransactionCount.  This build's
-analog: `make_transfer_pool` mass-signs a distinct-txn corpus with the
-TPU batch signer (ops/ed25519/sign.py) and `UdpBlaster` is the benchs
-sender thread; the observer is the existing RPC tile.
+stream of distinct transfer transactions for benchs to send.  This
+build's analog: `make_transfer_pool` mass-signs a distinct-txn corpus
+with the TPU batch signer (ops/ed25519/sign.py).
 
 Distinctness matters: every txn has a unique (dest, amount) so dedup
 cannot collapse the load and every landed count is a real execution.
 """
 
 from __future__ import annotations
-
-import socket
-import threading
-import time
 
 import numpy as np
 
@@ -102,76 +95,3 @@ def make_transfer_pool(
     for i, sig in enumerate(sigs):
         rows[i, 1:65] = np.frombuffer(sig, np.uint8)
     return rows, pubs
-
-
-class UdpBlaster:
-    """benchs analog: a sender thread blasting pool rows at a UDP addr.
-
-    UDP severs the ring-credit backpressure the reference's benchs
-    tiles inherit, and pack DROPS inserts when its buffer is full — an
-    unpaced blast of a finite pool burns most of it as rejects within
-    seconds.  Feedback pacing restores the backpressure: the owner
-    updates `landed` (RPC-observed count) and the sender keeps
-    sent - landed <= window."""
-
-    def __init__(self, rows: np.ndarray, addr: tuple[str, int],
-                 burst: int = 64, pace_s: float = 0.0,
-                 window: int | None = None):
-        self.rows = rows
-        self.addr = addr
-        self.burst = burst
-        self.pace_s = pace_s
-        self.window = window
-        self.sent = 0
-        #: RPC-observed landed count, updated by the measuring loop
-        self.landed = 0
-        self._stop = threading.Event()
-        self._thread = threading.Thread(target=self._run, daemon=True)
-
-    def start(self):
-        self._thread.start()
-        return self
-
-    def _run(self):
-        sock = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
-        try:
-            n = len(self.rows)
-            last_landed, last_progress = -1, time.monotonic()
-            while not self._stop.is_set() and self.sent < n:
-                if (
-                    self.window is not None
-                    and self.sent - self.landed > self.window
-                ):
-                    # permanently lost txns (UDP drops, rejects) never
-                    # leave the window; a long landing stall (a cold
-                    # compile or a wedged device call blocks the verify
-                    # tile for tens of seconds) must NOT trigger unpaced
-                    # sending — that burns the finite pool as
-                    # full-buffer rejects in seconds.  Hold position
-                    # unless the stall outlives the verify tile's own
-                    # stall patience.
-                    now = time.monotonic()
-                    if self.landed != last_landed:
-                        last_landed, last_progress = self.landed, now
-                    if now - last_progress < 120.0:
-                        time.sleep(0.005)
-                        continue
-                end = min(self.sent + self.burst, n)
-                for i in range(self.sent, end):
-                    try:
-                        sock.sendto(self.rows[i].tobytes(), self.addr)
-                    except OSError:
-                        pass
-                self.sent = end
-                if self.pace_s:
-                    time.sleep(self.pace_s)
-        finally:
-            sock.close()
-
-    def stop(self):
-        self._stop.set()
-        self._thread.join(timeout=5.0)
-
-    @property
-    def done(self) -> bool:
-        return self.sent >= len(self.rows)

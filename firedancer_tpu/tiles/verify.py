@@ -35,9 +35,11 @@ more chips: this layer is what turns N devices into an aggregate — the
 same conclusion that drove the reference to scale sig-verify across
 tiles and wiredancer FPGA lanes.
 
-Batch discipline: lane counts are padded up to power-of-two buckets so
-XLA compiles a handful of static shapes, then reuses them forever.  All
-per-frag work is vectorized numpy; the Python loop body is O(1) per batch.
+Batch discipline: every batch is padded to `max_lanes`, the one shape the
+boot compiles and warms, and goes to the device with its real lane count:
+the kernel runs only the 256-lane tiles that hold a real lane, so a small
+batch costs a small batch's kernel time.  All per-frag work is vectorized
+numpy; the Python loop body is O(1) per batch.
 """
 
 from __future__ import annotations
@@ -844,7 +846,6 @@ class VerifyTile(Tile):
         msg_width: int = 1232,
         max_lanes: int = 4096,
         pre_dedup: bool = True,
-        pad_full: bool = False,
         shard: tuple[int, int] | None = None,
         async_depth: int = 3,
         device: str = "auto",
@@ -858,14 +859,8 @@ class VerifyTile(Tile):
         stall_patience_s: float = 120.0,
         name: str = "verify",
     ):
-        """pad_full: always pad sub-batches to max_lanes (one compiled
-        shape, so one boot-time compile; what both config-built
-        topologies use).  The batch's real lane count goes to the device
-        with it and the kernel runs only the tiles (256 lanes) that hold
-        a real lane, so a padded batch costs its own tiles' time, not
-        the full shape's.  False pads to power-of-two buckets
-        (log2(max_lanes) compiled shapes, each a cold compile on first
-        use; the same kernel time, less to pad and to copy).
+        """max_lanes: the one batch shape; every batch pads to it (see the
+        module docstring).
 
         shard=(idx, cnt): horizontal scaling — this replica only processes
         frags with seq % cnt == idx (reference: round-robin seq sharding
@@ -902,15 +897,10 @@ class VerifyTile(Tile):
         in-flight device batches), so repartition never strands work.
         Metrics rows are sized for the full universe (the region is
         fixed at build).  Overrides `devices` when set."""
-        assert max_lanes & (max_lanes - 1) == 0, (
-            "max_lanes must be a power of two (pad buckets + warm compiles "
-            "assume it)"
-        )
         self.name = name
         self.msg_width = msg_width
         self.max_lanes = max_lanes
         self.pre_dedup = pre_dedup
-        self.pad_full = pad_full
         self.shard = shard
         self.async_depth = max(async_depth, 1)
         self.device = device
@@ -1055,17 +1045,13 @@ class VerifyTile(Tile):
                     fver.verify_batch_digest_on(local[d])
                     for d in self.device_indices
                 ]
-            # warm the full-batch shape (per device) so the steady state
-            # never compiles.  With pad_full (what both config-built
-            # topologies use) that is the ONLY shape; without it the
-            # smaller pow2 buckets compile on first use, each a cold
-            # compile in the middle of serving — `device_programs`
-            # shows it.  Each device pays its own lowering and, cold,
-            # its own compile: the persistent cache's key covers the
-            # device assignment.  Four v5e devices warmed cold in 346.7 s
-            # (one: 56.2 s); with the programs cached the whole boot of
-            # `leader4` took 112.4 s (PERF.md section 6, my chip runs,
-            # PR 28): ROADMAP S6.
+            # warm the one batch shape (per device) so serving never
+            # compiles (`device_programs` would show it).  Each device
+            # pays its own lowering and, cold, its own compile: the
+            # persistent cache's key covers the device assignment.  Four
+            # v5e devices warmed cold in 346.7 s (one: 56.2 s); with the
+            # programs cached the whole boot of `leader4` took 112.4 s
+            # (PERF.md section 6, my chip runs, PR 28): ROADMAP S6.
             # The lane count goes in exactly as _submit_front sends it
             # (an int32 array; a Python int is weakly typed and would
             # trace a program of its own): its VALUE picks no program.
@@ -1400,8 +1386,9 @@ class VerifyTile(Tile):
                 if k == 0:
                     if lanes == 0:
                         # a single txn with more lanes than the cap: take
-                        # it alone (the kernel pads to any pow2 bucket) —
-                        # never stall with zero progress
+                        # it alone, in a batch of its own size (a second
+                        # shape, only where max_lanes is under a txn's
+                        # signature count) — never stall with zero progress
                         k = 1
                     else:
                         break
@@ -1429,11 +1416,7 @@ class VerifyTile(Tile):
                     for k in take[0]
                     if k != "t_first"
                 }
-            pad = (
-                self.max_lanes
-                if self.pad_full
-                else 1 << max(lanes - 1, 0).bit_length()
-            )
+            pad = max(self.max_lanes, lanes)
             meta = dict(
                 rows=b["rows"], szs=b["szs"], tsorigs=b["tsorigs"],
                 sig_cnt=b["sig_cnt"], tags=b["tags"], seqs=b["seqs"],

@@ -117,8 +117,8 @@ def enable_compilation_cache() -> str:
     """Turn on JAX's persistent compilation cache; returns its directory.
 
     THE one place the cache location is decided — fdtctl run, JAX-using
-    tile children, bench.py, chip_smoke.py and tests/conftest.py all
-    come through here.  With JAX_COMPILATION_CACHE_DIR set, JAX reads
+    tile children (what benchmark/run.py boots), chip_smoke.py and
+    tests/conftest.py all come through here.  With JAX_COMPILATION_CACHE_DIR set, JAX reads
     the directory itself and this code sets none; otherwise it is the
     checkout's `.jax_cache`.  Never the home directory, a temp name or a
     per-process path: a cold verify-kernel compile costs about a minute

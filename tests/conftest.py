@@ -1,9 +1,9 @@
 """Test harness config: force an 8-device virtual CPU mesh before any test
 imports jax.
 
-Multi-chip hardware is not available in CI; all sharding tests run on
-xla_force_host_platform_device_count=8 CPU devices.  Benchmarks (bench.py)
-run outside pytest on the real TPU chip.
+Multi-chip hardware is not available in CI; all multi-device tests run on
+xla_force_host_platform_device_count=8 CPU devices.  The benchmark
+(benchmark/run.py) runs outside pytest on the real TPU chip.
 """
 
 import os

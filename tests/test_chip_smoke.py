@@ -136,11 +136,13 @@ def test_ingress_phase_counts_no_compile_while_serving(
 
 def test_ingress_topology_pads_to_one_shape():
     """What phase (d) relies on: the config-built ingress topology boots
-    its verify tiles with one compiled shape, like the validator's."""
+    its verify tiles with one compiled shape, like the validator's: the
+    configured `max_lanes`, which every batch pads to."""
     from firedancer_tpu.app import config as C
 
-    topo, _ = C.build_ingress_topology(C.parse(""), b"\x07" * 32)
-    assert topo.tiles["verify0"].tile.pad_full
+    cfg = C.parse("[tiles.verify]\nmax_lanes = 512\n")
+    topo, _ = C.build_ingress_topology(cfg, b"\x07" * 32)
+    assert topo.tiles["verify0"].tile.max_lanes == 512
 
 
 def _run(args, cwd=REPO, **env):

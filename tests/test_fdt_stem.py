@@ -1239,7 +1239,7 @@ def _run_quic_pipeline(stem_mode, n_txns=24):
     )
     topo.tile(
         VerifyTile(
-            msg_width=256, max_lanes=64, pad_full=True, pre_dedup=False,
+            msg_width=256, max_lanes=64, pre_dedup=False,
             device="off",
         ),
         ins=[("quic_verify", True)], outs=["verify_dedup"],

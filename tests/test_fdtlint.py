@@ -31,7 +31,6 @@ CORPUS = REPO / "tests" / "fixtures" / "lint_corpus"
 #: the six named by ISSUE 2 plus the fdt_bank executor driver (ISSUE 9)
 SIX_BINDING_MODULES = {
     "firedancer_tpu/tango/rings.py",
-    "firedancer_tpu/models/pipeline.py",
     "firedancer_tpu/ops/ed25519/verify.py",
     "firedancer_tpu/ops/ed25519/sign.py",
     "firedancer_tpu/tiles/wire.py",
@@ -130,7 +129,9 @@ def test_ring_and_purity_coverage(repo_report):
     assert "firedancer_tpu/tiles/verify.py" in ring
     assert "firedancer_tpu/tiles/shred.py" in ring
     assert len(ring) >= 20
-    assert cov["hot_functions"] >= 10  # the marked kernel-layer surface
+    # the marked kernel-layer surface: verify (3), the Pallas kernel,
+    # sha512, the corpus signer, pack_select
+    assert cov["hot_functions"] >= 7
 
 
 def test_mc_hook_coverage(repo_report):

@@ -221,16 +221,6 @@ def test_configure_cache_stage_reports_the_one_location(monkeypatch):
     assert r.ok and hostdev.compilation_cache_dir() in r.detail
 
 
-def test_bench_fails_without_a_tpu():
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")], cwd=REPO,
-        capture_output=True, text=True, timeout=300,
-        env={**os.environ, "JAX_PLATFORMS": "cpu", "FDT_BENCH_DEVICES": "0"},
-    )
-    assert r.returncode != 0
-    assert "no TPU backend" in r.stderr and '"metric"' not in r.stdout
-
-
 def test_graft_entry_is_the_verify_kernel():
     import __graft_entry__ as g
 

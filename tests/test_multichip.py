@@ -1,6 +1,4 @@
-"""Multi-device scale-out surface.
-
-Two layers:
+"""Multi-device scale-out surface: the verify tile's device pool.
 
 * Verify device pool (tiles/verify.py `_DevicePool`): tier-1 tests on
   stubbed per-domain device fns (the strict host verifier standing in
@@ -12,9 +10,9 @@ Two layers:
   redistribution → zero lost/duplicated batches), per-device stall
   patience, and the abort()-cannot-orphan-work accounting contract.
 
-* Mesh sharding (models/pipeline.py, what `parallel/dryrun.py` runs):
-  the dp/mp-sharded pipeline step on the virtual 8-device CPU mesh —
-  slow tier (real jax compiles).
+* The graft entry's dry run (`parallel/dryrun.py`): the same pool on
+  the virtual CPU devices, tier-1 with a host stand-in for the pinned
+  device fn (nothing compiles); with real pinned fns, slow tier.
 """
 
 import threading
@@ -416,35 +414,44 @@ def test_config_parses_verify_devices():
 
 
 # ---------------------------------------------------------------------------
-# mesh sharding (models/pipeline.py): slow tier
+# the graft entry's dry run (parallel/dryrun.py): the pool, nothing else
 
 
-@pytest.mark.slow
-@pytest.mark.parametrize("dp,mp", [(4, 2), (8, 1), (2, 2)])
-def test_pipeline_step_meshes(dp, mp):
-    import jax
-    from jax.sharding import Mesh
+@pytest.mark.parametrize("n", [1, 2, 8])
+def test_dryrun_drives_the_verify_pool_in_order(monkeypatch, capsys, n):
+    """dryrun_multichip(n) runs the verify pool on min(n, 2) devices,
+    lands its 2 x width batches in order, and runs nothing else (there
+    is no `firedancer_tpu.models`).  The pinned device fn is the strict
+    host verifier here, so nothing compiles."""
+    import importlib.util
 
-    from firedancer_tpu.models import pipeline
+    import __graft_entry__ as G
+    from firedancer_tpu.ops.ed25519 import verify as fver
 
-    devs = jax.devices()
-    if len(devs) < dp * mp:
-        pytest.skip("not enough virtual devices")
-    mesh = Mesh(
-        np.array(devs[: dp * mp]).reshape(dp, mp), axis_names=("dp", "mp")
-    )
-    B, W = 4 * dp, 64
-    rng = np.random.default_rng(0)
-    msgs = rng.integers(0, 256, (B, W), np.uint8)
-    lens = np.full(B, W, np.int32)
-    pipeline.dryrun_step(mesh, msgs, lens)  # asserts internally
+    def host_on(device):
+        def fn(digests, sigs, pubs, n_lanes=None):
+            return hostpath.verify_batch_digest_host(digests, sigs, pubs)
+
+        fn.device = device
+        return fn
+
+    monkeypatch.setattr(fver, "verify_batch_digest_on", host_on)
+    G.dryrun_multichip(n)
+    w = min(n, 2)
+    out = capsys.readouterr().out.splitlines()
+    assert out[-2:] == [
+        f"dryrun_verify_pool ok: {2 * w} batches in order over {w}/{w} "
+        "devices",
+        "dryrun_multichip ok",
+    ]
+    assert importlib.util.find_spec("firedancer_tpu.models") is None
 
 
 # ---------------------------------------------------------------------------
 # pool on REAL local devices (virtual 8-dev CPU mesh): the device="auto"
 # path with per-device pinned executables — slow tier (one kernel
 # compile per device PLACEMENT: ~95 s cold / ~12 s compilation-cache
-# hit on this host; pad_full keeps it to ONE shape per device)
+# hit on this host; ONE shape per device)
 
 
 @pytest.mark.slow
@@ -456,7 +463,7 @@ def test_verify_pool_real_devices_spread():
         pytest.skip("needs >= 2 local devices")
     expected, got, c, _ = _run_pool_topology(
         48, seed=59, device_fn=None, device="auto", devices="auto",
-        max_lanes=16, pad_full=True,
+        max_lanes=16,
     )
     assert got == expected
     landed = [v for k, v in c.items()

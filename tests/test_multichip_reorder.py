@@ -73,7 +73,7 @@ def _run(monkeypatch, corp, width: int):
     n = len(rows)
     synth = SynthTile(rows, szs, total=n)
     verify = VT.VerifyTile(msg_width=256, max_lanes=LANES, devices=width,
-                           async_depth=2, pad_full=True)
+                           async_depth=2)
     assert verify.n_devices == width
     sink = SinkTile(record=True)
     topo = Topology()

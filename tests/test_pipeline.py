@@ -3,7 +3,8 @@
 The minimum end-to-end checkpoint from SURVEY.md §7: a replayed ingress
 stream verified on the device, deduped, with metrics proving the counts.
 Runs on the virtual CPU mesh in CI; the same topology runs unchanged on a
-real chip (bench.py measures it there)."""
+real chip (chip_smoke.py's ingress phase and the `ingress.flood` cell of
+benchmark/run.py drive it there)."""
 
 import time
 
@@ -30,9 +31,7 @@ def test_ingress_pipeline_end_to_end():
     synth = SynthTile(rows, szs, total=total, repeat=repeat)
     # pre_dedup off: the 16-deep pre-tcache would swallow the back-to-back
     # repeats that the dedup-tile assertion below wants to see
-    verify = VerifyTile(
-        msg_width=256, max_lanes=32, pad_full=True, pre_dedup=False
-    )
+    verify = VerifyTile(msg_width=256, max_lanes=32, pre_dedup=False)
     dedup = DedupTile(depth=1 << 12)
     sink = SinkTile(record=True)
 
@@ -96,8 +95,7 @@ def test_verify_pre_dedup_with_duplicates():
     pool_n, repeat = 6, 2
     rows, szs, good = make_txn_pool(pool_n, seed=37)
     synth = SynthTile(rows, szs, total=pool_n * repeat, repeat=repeat)
-    verify = VerifyTile(msg_width=256, max_lanes=32, pad_full=True,
-                        pre_dedup=True)
+    verify = VerifyTile(msg_width=256, max_lanes=32, pre_dedup=True)
     sink = SinkTile()
     topo = Topology()
     topo.link("synth_verify", depth=64, mtu=wire.LINK_MTU)

@@ -51,8 +51,7 @@ def test_wire_ingress_quic_and_udp():
     bad_txn = _signed_txn(rng, sk, pk, blockhash, corrupt=True)
 
     qt = QuicIngressTile(identity)
-    verify = VerifyTile(msg_width=256, max_lanes=32, pad_full=True,
-                        pre_dedup=False)
+    verify = VerifyTile(msg_width=256, max_lanes=32, pre_dedup=False)
     dedup = DedupTile(depth=1 << 10)
     sink = SinkTile(record=True)
 

@@ -26,10 +26,10 @@ def test_two_verify_replicas_seq_sharded():
     rows, szs, good = make_txn_pool(pool_n, corrupt_frac=0.25, seed=23)
     n_good = int(good.sum())
     synth = SynthTile(rows, szs, total=pool_n)
-    v0 = VerifyTile(msg_width=256, max_lanes=32, pad_full=True,
-                    pre_dedup=False, shard=(0, 2), name="verify0")
-    v1 = VerifyTile(msg_width=256, max_lanes=32, pad_full=True,
-                    pre_dedup=False, shard=(1, 2), name="verify1")
+    v0 = VerifyTile(msg_width=256, max_lanes=32, pre_dedup=False,
+                    shard=(0, 2), name="verify0")
+    v1 = VerifyTile(msg_width=256, max_lanes=32, pre_dedup=False,
+                    shard=(1, 2), name="verify1")
     dedup = DedupTile(depth=1 << 10)
     sink = SinkTile(record=True)
 

@@ -400,7 +400,7 @@ def test_process_quic_verify_dedup_pack():
     )
     topo.tile(
         VerifyTile(
-            msg_width=256, max_lanes=64, pad_full=True,
+            msg_width=256, max_lanes=64,
             pre_dedup=False, device="off",
         ),
         ins=[("quic_verify", True)], outs=["verify_dedup"],

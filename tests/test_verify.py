@@ -377,7 +377,7 @@ def test_batch_evicted_and_resubmitted_keeps_its_stamps_in_order(
             raise RuntimeError("device lost")
         return np.ones(len(digests), bool)
 
-    rig = rig_factory(device_fn=flaky, devices=2, pad_full=True).boot()
+    rig = rig_factory(device_fn=flaky, devices=2).boot()
     for i in range(4):
         rig.burst(3)
         rig.settle(i + 1)

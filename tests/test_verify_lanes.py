@@ -16,18 +16,20 @@ from firedancer_tpu.tiles import verify as VT
 
 from test_verify import _Rig, _admit_all, rig_factory  # noqa: F401
 
-@pytest.mark.parametrize("lanes", [1, 257, 4096])
+@pytest.mark.parametrize("lanes", [1, 255, 256, 257, 4095, 4096])
 def test_the_device_fn_is_told_the_batchs_real_lanes(rig_factory, lanes):
-    """Beside the three arrays padded to the compiled shape the device fn
-    gets the real lane count, as the int32 array the boot-time warm-up
-    sends, and `kernel_lanes` grows by the whole kernel tiles it fills."""
+    """Beside the three arrays padded to the compiled shape (`max_lanes`,
+    whatever the batch holds: the tile has no other) the device fn gets
+    the real lane count, as the int32 array the boot-time warm-up sends,
+    and `kernel_lanes` grows by the whole kernel tiles it fills — on both
+    sides of each 256-lane tile edge."""
     seen = []
 
     def dev(digests, sigs, pubs, n_lanes):
         seen.append((len(digests), n_lanes))
         return np.ones(len(digests), bool)
 
-    rig = rig_factory(device_fn=dev, max_lanes=4096, pad_full=True).boot()
+    rig = rig_factory(device_fn=dev, max_lanes=4096).boot()
     before = rig.counters()
     for at in range(0, lanes, 256):
         rig.burst(min(256, lanes - at))
@@ -38,7 +40,8 @@ def test_the_device_fn_is_told_the_batchs_real_lanes(rig_factory, lanes):
     assert int(n) == lanes == rig.published[0]["lanes"]
     after = rig.counters()
     tiles = -(-lanes // VT.KERNEL_TILE)
-    assert VT.KERNEL_TILE == 256 and tiles == {1: 1, 257: 2, 4096: 16}[lanes]
+    assert VT.KERNEL_TILE == 256 and tiles == {
+        1: 1, 255: 1, 256: 1, 257: 2, 4095: 16, 4096: 16}[lanes]
     assert after["kernel_lanes"] - before["kernel_lanes"] == 256 * tiles
     assert after["verified_sigs"] - before["verified_sigs"] == lanes
     assert after["fallback_batches"] == after["device_errors"] == 0
@@ -47,7 +50,7 @@ def test_the_device_fn_is_told_the_batchs_real_lanes(rig_factory, lanes):
 def test_a_device_fn_written_for_three_arrays_is_given_three(rig_factory):
     """A stub that takes the padded arrays alone (every test rig written
     before the count) is not a failing device: no error, no fallback."""
-    rig = rig_factory(device_fn=_admit_all, pad_full=True).boot()
+    rig = rig_factory(device_fn=_admit_all).boot()
     rig.burst(3)
     rig.settle(1)
     c = rig.counters()
@@ -62,7 +65,7 @@ def test_the_host_fallback_lands_a_batch_that_carries_the_count(rig_factory):
     def lost(digests, sigs, pubs, n_lanes):
         raise RuntimeError("device lost")
 
-    rig = rig_factory(device_fn=lost, pad_full=True).boot()
+    rig = rig_factory(device_fn=lost).boot()
     rig.burst(3)
     rig.settle(1)
     c = rig.counters()
@@ -76,7 +79,7 @@ def test_the_host_fallback_lands_a_batch_that_carries_the_count(rig_factory):
 def xla_rig():
     """A tile with its own device fn on the plain XLA path (what a CPU
     run picks), booted once: the boot compiles and warms the program."""
-    rig = _Rig(device_fn=None, pad_full=True)
+    rig = _Rig(device_fn=None)
     rig.boot()
     yield rig
     rig.close()
