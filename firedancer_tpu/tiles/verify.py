@@ -93,15 +93,20 @@ PHASE_COUNTERS = (
 #: `device_batches` is, when it lands: `full_batches` went out full (the
 #: throughput regime: queueing behind other batches is real work);
 #: `held_batches` are partial batches whose submit waited for a batch in
-#: flight to land.  The rest of `device_batches` are partial batches that
-#: found their device idle and went at once (trickle), and halt's flush.
-SUBMIT_COUNTERS = ("held_batches", "full_batches")
+#: flight to land; `spread_batches` are partial batches that went to an
+#: idle device while another batch was in flight in the pool, because
+#: they filled a kernel tile.  The rest of `device_batches` are partial
+#: batches that found the whole pool idle and went at once (trickle), and
+#: halt's flush.
+SUBMIT_COUNTERS = ("held_batches", "full_batches", "spread_batches")
 REORDER_COUNTER = "reordered_batches"
 #: batches a device may have in flight for a PARTIAL batch still to be
 #: submitted to it.  1: a partial batch never queues behind another; it
 #: waits staged (and grows) for the land instead.  2 would hide the ~2 ms
 #: of dispatch + H2D under the running batch and cost every txn one more
 #: batch time; PERF.md (PR 26) has both readings on the chip.
+#: A partial batch under one kernel tile also waits while any device of
+#: the pool has a batch in flight (_submit_staged).
 PARTIAL_AHEAD = 1
 #: what of a batch's meta outlives its landing, until its last publish
 _LIFE_KEYS = (
@@ -576,7 +581,8 @@ class _DevicePool:
     round-robin.  A device is open while its `inflight()` (submitted and
     not yet landed: queued, dispatched or running) is below a cap: the
     pool's `depth` by default, or the caller's lower `ahead` (the tile's
-    submit rule gives a partial batch PARTIAL_AHEAD).  When no device is
+    submit rule gives a partial batch PARTIAL_AHEAD, and holds one under
+    a kernel tile while `inflight()` is above 0).  When no device is
     healthy, batches go out in `mode="host"` — the strict host path as
     last resort — on any responsive worker, under the same caps.
 
@@ -670,6 +676,16 @@ class _DevicePool:
         if self.retryq:
             return False
         return self._pick(peek=True, ahead=ahead)[0] is not None
+
+    def inflight(self) -> int:
+        """Batches in flight anywhere in the pool: the schedulable
+        domains' `inflight()` and the evicted batches in `retryq`.  A
+        dead or stalled domain is left out: its batches count where they
+        were moved, so a domain that never lands again holds nothing."""
+        return len(self.retryq) + sum(
+            w.inflight() for i, w in enumerate(self.workers)
+            if self._domain_ok(i)
+        )
 
     def submit(self, meta, args) -> bool:
         """Schedule one new batch on the least-loaded open device (the
@@ -871,7 +887,8 @@ class VerifyTile(Tile):
         not yet landed, counted once (`_DeviceWorker.inflight()`), the
         wiredancer request pipe depth; 1 degenerates to synchronous
         dispatch.  A partial batch is allowed PARTIAL_AHEAD (a module
-        constant) instead.
+        constant) instead, and under one kernel tile only while the pool
+        has nothing in flight (_submit_staged).
 
         device: "auto" jits the batched kernel; "off" never touches JAX
         and verifies every batch on the strict host path (CPU-only tests,
@@ -1332,40 +1349,53 @@ class VerifyTile(Tile):
 
     def _submit_staged(self) -> None:
         """THE submit rule — when a staged batch may go to a device.  It
-        reads only what the tile observes: the lanes staged and each
-        device's batches in flight.
+        reads only what the tile observes: the lanes staged, each
+        device's batches in flight and the pool's (`_DevicePool.inflight`).
 
         1. A full batch goes to the least-loaded healthy device with
            fewer than `async_depth` in flight (_pool_open): queueing
            there is real work.
         2. A partial batch goes only to one with fewer than
-           PARTIAL_AHEAD in flight; otherwise it stays staged, and grows
-           with every burst, until a batch lands or it is full (rule 1).
-           With nothing in flight it goes at once: trickle traffic pays
-           no linger.  The hold waits on nothing but the land of a batch
-           that IS in flight, so it cannot deadlock; halt, crash
-           teardown and repartition flush or drop staging as before.
+           PARTIAL_AHEAD in flight, and only if no batch is in flight
+           anywhere in the pool or its lanes fill at least one kernel
+           tile (KERNEL_TILE); otherwise it stays staged, and grows with
+           every burst, until a batch lands, it fills a tile, or it is
+           full (rule 1).  With nothing in flight it goes at once:
+           trickle traffic pays no linger.  The hold waits on nothing
+           but the land of a batch that IS in flight, so it cannot
+           deadlock; halt, crash teardown and repartition flush or drop
+           staging as before.
 
-        The rule was set when a padded batch cost one full batch time
-        whatever it carried (9.3 ms): a partial batch queued behind k
-        others delayed its txns k batch times and took a slot from the
-        next, fuller, one.  A batch now costs the kernel its own tiles
-        (0.6 ms for up to 256 lanes; PERF.md section 6, PR 31), so the
-        hold is mostly the host's turnaround; the rule stands as it was
-        until a PR retunes it with those numbers."""
+        A batch costs the kernel its own tiles, one (0.6 ms) for up to
+        256 lanes whatever it carries (PERF.md section 6), and
+        every dispatch costs the host its turn under the GIL, more when
+        another worker dispatches beside it.  So a sub-tile batch sent to
+        a second idle device buys almost no compute and slows every
+        dispatch; it waits for the land instead and goes as one batch,
+        as it does where the pool has one device (there "no device has
+        one in flight" and "the pool has none" are the same test).  A
+        batch that fills a tile is real work for an idle device, so load
+        spreads over the devices long before batches are full."""
         pool = self._pool
         while self._staged_lanes:
-            full = self._staged_lanes >= self.max_lanes
-            if not (
-                self._pool_open() if full else pool.can_accept(PARTIAL_AHEAD)
-            ):
+            if self._staged_lanes >= self.max_lanes:
+                go, rule = self._pool_open(), "full_batches"
+            else:
+                # the pool's count is read only when a device is open: at
+                # width one a turn that holds for the land reads no more
+                # than the device's own count
+                go = pool.can_accept(PARTIAL_AHEAD)
+                busy = go and pool.inflight() > 0
+                if busy and self._staged_lanes < KERNEL_TILE:
+                    go = False
+                rule = (
+                    "spread_batches" if busy
+                    else "held_batches" if self._held
+                    else None
+                )
+            if not go:
                 self._held = True
                 return
-            rule = (
-                "full_batches" if full
-                else "held_batches" if self._held
-                else None
-            )
             self._held = False
             self._submit_front(self.max_lanes, rule)
 

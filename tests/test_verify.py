@@ -13,7 +13,8 @@ lifecycle stamps and the four batch_*_us hists, the mux thread's phase
 counters, that host spans are built per BATCH, never per burst, and the
 submit rule (a gated `device_fn`: batches land only as the test lets
 them): a partial batch is held while its device has PARTIAL_AHEAD in
-flight, a full one goes up to `async_depth`.
+flight, and one under a kernel tile while any device of the pool has a
+batch in flight; a full one goes up to `async_depth`.
 """
 
 import threading
@@ -307,13 +308,13 @@ def _inflight(rig) -> list[int]:
 
 
 def _fill_ahead(rig, lanes: int = 3) -> None:
-    """Put PARTIAL_AHEAD partial batches in flight on every device of
-    the pool: each finds a device below the mark, so each goes at once."""
-    for _ in range(VT.PARTIAL_AHEAD * rig.tile.n_devices):
+    """Put PARTIAL_AHEAD partial batches in flight on a one-device
+    pool: each finds the device below the mark, so each goes at once."""
+    for _ in range(VT.PARTIAL_AHEAD):
         rig.burst(lanes)
         rig.credit()
         assert rig.tile._staged_lanes == 0
-    assert _inflight(rig) == [VT.PARTIAL_AHEAD] * rig.tile.n_devices
+    assert _inflight(rig) == [VT.PARTIAL_AHEAD]
 
 
 @pytest.mark.parametrize("traced", [False, True])
@@ -600,34 +601,120 @@ def test_full_batches_go_up_to_async_depth_in_flight_and_no_further(
 
 
 def test_two_devices_carry_two_partial_batches_one_each(rig_factory):
-    """The rule is per device: with `devices=2` each device takes
-    partial batches up to the mark, the next one is held, and a land on
-    EITHER device lets it go — to that device."""
+    """The rule reads the pool: with `devices=2` a partial batch under
+    one kernel tile is held while a batch is in flight, though the other
+    device is idle; it grows, and goes as one batch on the land — to the
+    next device in rotation, so the two devices carry one batch each."""
     gate = _Gate()
     rig = rig_factory(device_fn=gate, devices=2, async_depth=3).boot()
     tile = rig.tile
     try:
-        _fill_ahead(rig)
-        assert _inflight(rig) == [VT.PARTIAL_AHEAD] * 2
-        rig.burst(2)
-        rig.credit()
-        assert tile._staged_lanes == 2 and tile._held
-        assert tile.in_budget(rig.ctx) is None
-        gate.land()                  # whichever device it was
-        _until(lambda: sum(_inflight(rig)) == 2 * VT.PARTIAL_AHEAD - 1,
-               "no land")
-        rig.credit()
-        assert tile._staged_lanes == 0
-        assert _inflight(rig) == [VT.PARTIAL_AHEAD] * 2
+        rig.burst(3)
+        rig.credit()                 # the pool is idle: it goes at once
+        assert tile._staged_lanes == 0 and _inflight(rig) == [1, 0]
+        for k in (1, 2):
+            rig.burst(2)
+            rig.credit()
+            assert tile._staged_lanes == 2 * k and tile._held
+            assert tile.in_budget(rig.ctx) is None
+            assert _inflight(rig) == [1, 0]     # device 1 stays idle
+        gate.land()
+        _until(lambda: _inflight(rig) == [0, 0], "no land")
+        rig.credit()                 # the turn that sees the land
+        assert tile._staged_lanes == 0 and not tile._held
+        assert _inflight(rig) == [0, 1]
     finally:
         gate.open()
-    rig.settle(2 * VT.PARTIAL_AHEAD + 1)
+    rig.settle(2)
+    assert [b["lanes"] for b in rig.published] == [3, 4]
+    assert [b["pool_seq"] for b in rig.published] == [0, 1]
     c = rig.counters()
-    assert c["held_batches"] == 1 and c["full_batches"] == 0
-    assert [b["pool_seq"] for b in rig.published] == list(
-        range(2 * VT.PARTIAL_AHEAD + 1))
-    assert c["dev0_landed"] + c["dev1_landed"] == c["device_batches"]
-    assert min(c["dev0_landed"], c["dev1_landed"]) >= VT.PARTIAL_AHEAD
+    assert (c["held_batches"], c["full_batches"], c["spread_batches"]) == (
+        1, 0, 0)
+    assert (c["dev0_landed"], c["dev1_landed"]) == (1, 1)
+
+
+@pytest.mark.parametrize("devices", [2, 4])
+def test_a_partial_batch_that_fills_a_tile_goes_to_an_idle_device_at_once(
+        rig_factory, devices):
+    """A partial batch held for the land grows until it fills one kernel
+    tile; then it is real work for an idle device and goes at once,
+    beside the batch in flight (`spread_batches`, not `held_batches`).
+    The next sub-tile batch is held again, whatever devices are idle."""
+    tile_lanes = VT.KERNEL_TILE
+    gate = _Gate()
+    rig = rig_factory(device_fn=gate, devices=devices, async_depth=3,
+                      max_lanes=2 * tile_lanes).boot()
+    tile = rig.tile
+    idle = [0] * (devices - 2)
+    try:
+        rig.burst(3)
+        rig.credit()
+        assert _inflight(rig) == [1, 0] + idle
+        rig.burst(100)
+        rig.credit()                 # under a tile: held
+        assert tile._staged_lanes == 100 and tile._held
+        rig.burst(tile_lanes - 100)
+        rig.credit()                 # one tile staged: it goes
+        assert tile._staged_lanes == 0 and not tile._held
+        assert _inflight(rig) == [1, 1] + idle
+        rig.burst(5)
+        rig.credit()
+        assert tile._staged_lanes == 5 and tile._held
+        assert _inflight(rig) == [1, 1] + idle
+    finally:
+        gate.open()
+    rig.settle(3)
+    assert [b["lanes"] for b in rig.published] == [3, tile_lanes, 5]
+    c = rig.counters()
+    assert (c["spread_batches"], c["held_batches"], c["full_batches"]) == (
+        1, 1, 0)
+    assert c["out_frags"] == 3 + tile_lanes + 5
+
+
+#: a fixed script of (bursts, credits, lands) for a width-one tile, and
+#: what the rule before the pool-wide hold did with it: lanes of each
+#: batch in submit order, and (device, held, full) batch counts
+_WIDTH_ONE_SCRIPT = "b5 c b2 c b3 c l c b9 c b4 c b6 c l c l c b1 c l c " \
+    "b12 c b8 c b8 c b2 c l c l c l c b7 c b3 c l c l c"
+_WIDTH_ONE_LANES = [5, 5, 8, 8, 4, 8, 8, 8, 8, 8]
+_WIDTH_ONE_COUNTS = (10, 2, 7)
+
+
+def test_width_one_makes_the_decisions_it_made_before(rig_factory):
+    """At width one "no device has a batch in flight" and "the pool has
+    none" are one test: on a fixed script of bursts, credits and lands
+    the tile sends the batches it sent before the rule read the pool,
+    counts them alike, and never counts a spread."""
+    gate = _Gate()
+    rig = rig_factory(device_fn=gate, async_depth=3).boot()
+    lanes: list[int] = []
+    w = rig.tile._pool.workers[0]
+
+    def submit(meta, args, mode="auto", inner=w.submit):
+        lanes.append(meta["lanes"])
+        inner(meta, args, mode)
+
+    w.submit = submit
+    try:
+        for step in _WIDTH_ONE_SCRIPT.split():
+            if step[0] == "b":
+                rig.burst(int(step[1:]))
+            elif step == "c":
+                rig.credit()
+            else:
+                n = w.inflight()
+                gate.land()
+                _until(lambda: w.inflight() == n - 1, "no land")
+        assert rig.tile._staged_lanes == 0
+    finally:
+        gate.open()
+    rig.settle(len(lanes))
+    c = rig.counters()
+    assert lanes == _WIDTH_ONE_LANES
+    assert (c["device_batches"], c["held_batches"], c["full_batches"]) == (
+        _WIDTH_ONE_COUNTS)
+    assert c["spread_batches"] == 0
 
 
 def test_halt_flushes_a_held_stage(rig_factory):
@@ -697,19 +784,24 @@ def test_crash_teardown_drops_a_held_stage_and_the_next_life_starts_clean(
     assert rig.counters()["held_batches"] == 0
 
 
-@pytest.mark.parametrize("devices,seed", [
-    (1, 1), (1, 2), (1, 3), (2, 4), (2, 5), (3, 6)])
+@pytest.mark.parametrize("devices,seed,max_lanes", [
+    pytest.param(d, s, n, id=f"{d}-{s}" if n == 8 else f"{d}-{s}-{n}")
+    for d, s, n in ((1, 1, 8), (1, 2, 8), (1, 3, 8), (2, 4, 8), (2, 5, 8),
+                    (3, 6, 8), (1, 7, 2 * VT.KERNEL_TILE),
+                    (4, 8, 2 * VT.KERNEL_TILE))])
 def test_random_bursts_publish_every_txn_once_in_ring_order(
-        rig_factory, devices, seed):
+        rig_factory, devices, seed, max_lanes):
     """Random bursts, credits and lands against the rule: every submit
     obeys it, ack_floor is exactly the oldest unpublished frag at every
-    step, the three counters add up, and the published stream is the
-    ring's, txn for txn."""
+    step, the counters add up, and the published stream is the ring's,
+    txn for txn.  Batches of two kernel tiles let a partial batch fill
+    one (bursts scale with `max_lanes`)."""
     rng = np.random.default_rng(seed)
     gate = _Gate()
     depth = 3
-    rig = rig_factory(device_fn=gate, devices=devices,
-                      async_depth=depth).boot()
+    unit = max(max_lanes // 32, 1)      # lanes a burst step: 1 at 8 lanes
+    rig = rig_factory(device_fn=gate, devices=devices, async_depth=depth,
+                      max_lanes=max_lanes).boot()
     tile, ctx = rig.tile, rig.ctx
     tags = rig.rows[:, 1:9].copy().view("<u8").ravel()
     out: list[int] = []
@@ -720,13 +812,15 @@ def test_random_bursts_publish_every_txn_once_in_ring_order(
         return publish(t, *a, **kw)
 
     ctx.publish = recording
-    subs: list[tuple[int, int]] = []   # (lanes, in flight ahead of it)
+    #: (lanes, in flight ahead of it on its device, in the whole pool)
+    subs: list[tuple[int, int, int]] = []
     for w in tile._pool.workers:
         def submit(meta, args, mode="auto", w=w, inner=w.submit):
-            subs.append((meta["lanes"], w.inflight()))
+            subs.append((meta["lanes"], w.inflight(), tile._pool.inflight()))
             inner(meta, args, mode)
         w.submit = submit
     seq0 = int(ctx.ins[0].seq)
+    held_turns: list[bool] = []   # after each step: a partial batch held
 
     def check():
         floor = tile.ack_floor(ctx, 0)
@@ -737,12 +831,13 @@ def test_random_bursts_publish_every_txn_once_in_ring_order(
         assert tile._staged_lanes == sum(
             len(b["sigs"]) for b in tile._staged)
         assert all(0 <= n <= depth for n in _inflight(rig))
+        held_turns.append(tile._held)
 
     try:
         for _ in range(300):
             act = rng.integers(0, 6)
             if act <= 1 and tile.in_budget(ctx) is None:
-                rig.burst(int(rng.integers(1, 13)))
+                rig.burst(int(rng.integers(1, 13)) * unit)
             elif act == 2:
                 rig.credit(int(rng.integers(0, 17)))
             elif act == 3 and sum(_inflight(rig)):
@@ -755,10 +850,22 @@ def test_random_bursts_publish_every_txn_once_in_ring_order(
     _until(lambda: (rig.credit(), check(), len(out) == rig.sent)[-1],
            "the tail did not drain")
     assert out == [int(t) for t in tags[np.arange(rig.sent) % len(tags)]]
-    for lanes, ahead in subs:
-        assert ahead < (depth if lanes == 8 else VT.PARTIAL_AHEAD), subs
+    for lanes, ahead, pool_ahead in subs:
+        if lanes == max_lanes:
+            assert ahead < depth, subs
+        elif lanes < VT.KERNEL_TILE:
+            assert pool_ahead == 0, subs
+        else:
+            assert ahead < VT.PARTIAL_AHEAD, subs
     c = rig.counters()
     assert c["device_batches"] == len(subs) == len(rig.published)
-    assert c["full_batches"] == sum(1 for n, _ in subs if n == 8)
-    assert 0 < c["held_batches"] <= len(subs) - c["full_batches"]
+    assert c["full_batches"] == sum(1 for n, _, _ in subs if n == max_lanes)
+    # the rule held a partial batch; in a wider pool, where a batch is
+    # nearly always in flight, the hold may end in a full batch each time
+    assert any(held_turns)
+    assert c["held_batches"] <= len(subs) - c["full_batches"]
+    assert c["held_batches"] > 0 or devices > 1
+    # a spread was sent beside a batch in flight: it filled a tile
+    assert c["spread_batches"] <= sum(
+        1 for n, _, _ in subs if VT.KERNEL_TILE <= n < max_lanes)
     assert c["verified_sigs"] == c["out_frags"] == rig.sent
