@@ -5,11 +5,14 @@ place with ONE stated guarantee broken, at a cell's own size.
     python3 benchmark/control.py --workload leader.paced --seconds 20 --seeds 1 2 3
 
 The system states no precision, so the control breaks a guarantee the
-configuration states: `verify` off admits the corrupted copies, `dedup`
-off executes the byte-for-byte re-sends a second time.  For each seed it
-prints every number compared beside its limit; the comparison has to come
-out NOT correct for both.  Needs no chip (every comparison is an exact
-count) and boots nothing; exits 0 only if every control failed.
+configuration states: `verify` off admits the corrupted txns, `dedup`
+off executes the byte-for-byte re-sends a second time, and where the
+traffic has txns of more than one signer, `verify=lane0` checks each
+txn's signature 0 alone (with one signer it IS the strict verifier, and
+is no control).  For each seed it prints every number compared beside
+its limit; the comparison has to come out NOT correct for each.  Needs
+no chip (every comparison is an exact count) and boots nothing; exits 0
+only if every control failed.
 """
 
 from __future__ import annotations
@@ -38,22 +41,26 @@ def main(argv=None) -> int:
     conf = load_config(HERE, cell["config"])
     span = cell["warmup_s"] + args.seconds + cell.get("margin_s", 0)
     n_unique = math.ceil(cell.get("rate_tps", cell.get("corpus_tps")) * span)
+    weights = C.signer_weights(conf)
     all_failed = True
     for seed in args.seeds:
         corp = C.make_corpus(n_unique, conf["accounts"], cell["dup_every"],
-                             cell["bad_every"], seed)
-        n = len(corp["send"])
+                             cell["bad_every"], seed, weights=weights)
+        n = len(corp["kind"])
         bal = bool(conf.get("balances"))
         exp = reference.outcome(corp, n, balances=bal)
-        for broken in ("verify", "dedup"):
-            out = reference.outcome(corp, n, balances=bal, **{broken: False})
+        controls = [("verify", False), ("dedup", False)]
+        if (corp["nsig"] > 1).any():
+            controls.append(("verify", "lane0"))
+        for broken, how in controls:
+            out = reference.outcome(corp, n, balances=bal, **{broken: how})
             if not conf.get("siglog_tile"):
                 out.pop("tags"), exp.pop("tags", None)
             checks = ledger.compare(ledger.sound_observation(out, n), exp)
             ok = ledger.correct(checks)
             all_failed &= not ok
             print(f"control {args.workload} seed={seed} rows={n} "
-                  f"{broken}=off correct={ok} "
+                  f"{broken}={how or 'off'} correct={ok} "
                   + " ".join(f"{k}={v}/{lim}" for k, v, lim in checks if v),
                   flush=True)
     return 0 if all_failed else 1

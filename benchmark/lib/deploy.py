@@ -12,6 +12,7 @@ from __future__ import annotations
 import importlib.util
 import json
 import os
+import select
 import socket
 import tomllib
 
@@ -73,27 +74,65 @@ def free_udp_port() -> int:
     return port
 
 
+def _udp_rows(port: int) -> list:
+    """The rows of /proc/net/udp of the sockets bound to `port`, split."""
+    with open("/proc/net/udp") as f:
+        next(f)
+        rows = [line.split() for line in f]
+    return [c for c in rows if int(c[1].rsplit(":", 1)[1], 16) == port]
+
+
 def udp_kernel_drops(port: int) -> int:
     """Datagrams the kernel dropped at the socket bound to `port` (the
     last column of /proc/net/udp): the one loss no tile can count."""
-    drops = 0
-    with open("/proc/net/udp") as f:
-        next(f)
-        for line in f:
-            cols = line.split()
-            if int(cols[1].rsplit(":", 1)[1], 16) == port:
-                drops += int(cols[-1])
-    return drops
+    return sum(int(c[-1]) for c in _udp_rows(port))
+
+
+def _rx_queue(port: int) -> int:
+    """Bytes queued unread at the UDP socket bound to `port` (the
+    rx_queue column of /proc/net/udp: its skbs' truesize)."""
+    return sum(int(c[4].split(":")[1], 16) for c in _udp_rows(port))
+
+
+#: the least a datagram is charged of a receiving socket's buffer: what
+#: loopback UDP charges one of a few hundred bytes (its skb's truesize)
+MIN_TRUESIZE = 1280
 
 
 def socket_window() -> int:
-    """Datagrams the receiving tile's socket can hold unread, with half
-    its buffer to spare.  waltz/udpsock.py asks for 2 MiB; the kernel
-    grants min(that, rmem_max), doubled; a datagram of a few hundred
-    bytes is charged ~1280 bytes of it (skb truesize)."""
+    """Bytes of the receiving tile's socket buffer that unread datagrams
+    may take, with half the buffer to spare.  waltz/udpsock.py asks for
+    2 MiB; the kernel grants min(that, rmem_max), doubled."""
     with open("/proc/sys/net/core/rmem_max") as f:
         granted = 2 * min(1 << 21, int(f.read()))
-    return max(granted // 1280 // 2, 64)
+    return max(granted // 2, 64 * MIN_TRUESIZE)
+
+
+def udp_truesize(lengths) -> dict:
+    """{length: bytes} that one loopback datagram of each length takes of
+    the receiving socket's buffer, at least MIN_TRUESIZE: the kernel's
+    own charge, read off a socket of this process with one such datagram
+    queued.  A host whose /proc/net/udp reports no queue (it reads 0 with
+    the datagram there) gets MIN_TRUESIZE for every length."""
+    rx = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+    tx = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+    try:
+        rx.bind(("127.0.0.1", 0))
+        rx.settimeout(1.0)
+        port = rx.getsockname()[1]
+        got = {}
+        for n in sorted({int(n) for n in lengths}):
+            tx.sendto(bytes(n), ("127.0.0.1", port))
+            # readable once queued, and charged before it is queued
+            if not select.select([rx], [], [], 1.0)[0]:
+                raise OSError(f"a {n}-byte loopback datagram did not "
+                              f"arrive within 1 s")
+            got[n] = max(_rx_queue(port), MIN_TRUESIZE)
+            rx.recv(n)
+        return got
+    finally:
+        rx.close()
+        tx.close()
 
 
 class Deployment:

@@ -168,10 +168,12 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
     `cell_overrides` (over the workload file) is sweep.py's.  The one
     command passes none of them: its runs are the files' alone."""
     from benchmark.lib.deploy import (Deployment, load_config,
-                                      socket_window, udp_kernel_drops)
+                                      socket_window, udp_kernel_drops,
+                                      udp_truesize)
 
     cell = {**load_cell(root, name, rehearse), **(cell_overrides or {})}
     conf = load_config(root, cell["config"])
+    weights = C.signer_weights(conf)
     if rehearse:
         overrides = {**conf.get("rehearse", {}), **(overrides or {})}
     open_loop = cell["loop"] == "open"
@@ -209,17 +211,21 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
         # is one process tracing the verify program for most of a minute
         making = signer.submit(C.make_corpus, n_unique, n_accounts,
                                cell["dup_every"], cell["bad_every"], seed,
-                               keys=keys)
+                               keys=keys, weights=weights)
         dep.start()
         say("boot", runtime=dep.runtime(), seconds=round(
             time.perf_counter() - t0, 2))
         corp = making.result()
-        send, kind = corp["send"], corp["kind"]
-        assert len(send) == n_rows
+        buf, off, kind = corp["buf"], corp["off"], corp["kind"]
+        assert len(kind) == n_rows
+        row_bytes = np.diff(off)
         say("corpus", rows=n_rows, unique=n_unique,
             dup=int((kind == C.KIND_DUP).sum()),
             bad=int((kind == C.KIND_BAD).sum()), accounts=n_accounts,
-            txn_bytes=send.shape[1], boot_and_corpus_s=round(
+            txn_bytes_mean=round(float(row_bytes.mean()), 1),
+            txn_bytes_max=int(row_bytes.max()),
+            lanes=int(corp["nsig"][corp["src"]].sum()),
+            sign_s=round(corp["sign_s"], 2), boot_and_corpus_s=round(
                 time.perf_counter() - t0, 2))
         device = dict(platform="none", kind="none", count=0)
         if hook:
@@ -253,9 +259,12 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
         tile_pids = dep.tile_pids()
         host_reads = hostacct.Host()  # set-up: what this host gives
 
+        sent_acct: dict = {}  # the closed loop's own totals
+
         def at_edge(which: str, now: int, sent: int) -> None:
             edge[which] = dict(t=now, count=read_terminal(), sent=sent,
-                               cpu_ns=time.thread_time_ns())
+                               cpu_ns=time.thread_time_ns(),
+                               sender=dict(sent_acct))
             if trace:
                 edge[which]["snap"] = dep.snapshot()
                 edge[which]["kdrops"] = udp_kernel_drops(dep.port)
@@ -282,21 +291,20 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
                 state["next_sec"] = now + 1_000_000_000
 
         slice_ns = int(min(TRACE_SLICE_S, seconds / 2) * 1e9)
-        sent_at = sender_said = held = None
+        sent_at = sender_said = held = send_busy = None
         if open_loop:
             dgram_rate = cell["rate_tps"] * copies
             interval_ns = round(cell["burst"] / dgram_rate * 1e9)
             due_rel = sender.burst_due_ns(n_rows, cell["burst"], interval_ns)
-            shm = shared_memory.SharedMemory(create=True, size=send.nbytes)
-            np.ndarray(send.shape, np.uint8, buffer=shm.buf)[:] = send
+            shm = shared_memory.SharedMemory(create=True, size=buf.nbytes)
+            np.ndarray(buf.shape, np.uint8, buffer=shm.buf)[:] = buf
             ctx = multiprocessing.get_context("spawn")
             rx, tx = ctx.Pipe(duplex=False)
             t_start = time.monotonic_ns() + 2_000_000_000
             proc = ctx.Process(
                 target=sender.open_loop_main, name="fdt-benchmark-sender",
-                args=(shm.name, n_rows, send.shape[1], addr,
-                      dep.sender_port, t_start, cell["burst"], interval_ns,
-                      tx))
+                args=(shm.name, off, addr, dep.sender_port, t_start,
+                      cell["burst"], interval_ns, tx))
             proc.start()
             tx.close()
             T0 = t_start + int(warmup_s * 1e9)
@@ -331,23 +339,39 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
                 longest_gap_ms=round(gap / 1e6, 3))
         else:
             rx_terms = [conf["rx"]]
-            unread_max = socket_window()
+            # what each row's datagram takes of the receiving socket
+            size_of = udp_truesize(np.unique(row_bytes))
+            charge = np.zeros(max(size_of) + 1, np.int64)
+            charge[list(size_of)] = list(size_of.values())
+            say("socket", unread_bytes=socket_window(),
+                truesize=json.dumps(size_of, separators=(",", ":")))
             t_start = time.monotonic_ns()
             T0 = t_start + int(warmup_s * 1e9)
             T1 = T0 + int(seconds * 1e9)
             t_trace = T0 + (T1 - T0 - slice_ns) // 2
             watch = hostacct.Watch(host_reads, (T0, T1), tile_pids)
             n_sent = sender.closed_loop(
-                sock, addr, send,
+                sock, addr, buf, off,
                 in_flight=lambda sent: sent - (dep.settled() - base),
-                unread=lambda sent: sent - dep.total(rx_terms),
-                window=cell["window_txns"], unread_max=unread_max,
-                t_stop_ns=T1,
-                tick=lambda now, sent: housekeeping(now, lambda _: sent))
+                received=lambda: dep.total(rx_terms),
+                window=cell["window_txns"], unread_bytes=socket_window(),
+                charge=charge[row_bytes], t_stop_ns=T1,
+                tick=lambda now, sent: housekeeping(now, lambda _: sent),
+                account=sent_acct)
             if "T1" not in edge:
                 raise Malformed(
                     f"the corpus ({n_rows} rows) ran out before the "
                     f"window's end: raise corpus_tps in the workload file")
+            # whether the sender kept up: a window spent sending, with
+            # the socket seldom found full, measures the sender
+            a, b = edge["T0"]["sender"], edge["T1"]["sender"]
+            turns = max(b["turns"] - a["turns"], 1)
+            send_busy = (b["send_ns"] - a["send_ns"]) / (
+                edge["T1"]["t"] - edge["T0"]["t"])
+            say("sender", turns=b["turns"] - a["turns"],
+                full_share=round((b["full"] - a["full"]) / turns, 4),
+                empty_share=round((b["empty"] - a["empty"]) / turns, 4),
+                send_busy_share=round(send_busy, 4))
             deadline = time.monotonic() + DRAIN_LIMIT_S
             while (dep.settled() - base < n_sent
                    and time.monotonic() < deadline):
@@ -474,8 +498,8 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
     ctx = dict(rate_tps=rate, lag_ns=lag_in_win, setup_s=setup_s,
                by_second=by_second, t0_ns=T0, t1_ns=T1, seconds=seconds,
                sender_late_ns=late, sent_at_ns=sent_in_win,
-               due_ns=due_in_win, lag_after_send_ns=lag_sent, config=conf,
-               cell=cell)
+               due_ns=due_in_win, lag_after_send_ns=lag_sent,
+               send_busy_share=send_busy, config=conf, cell=cell)
     device_out = dict(device, memory_peak_bytes=mem_peak)
     result = dict(correct=ledger.correct(checks), attempted=attempted,
                   failed=failed)

@@ -319,12 +319,20 @@ def test_a_whole_run_with_the_timed_path_stubbed_or_broken(
     """Skips the harness's look for a chip and drives the rest of a run
     (thread runtime, tiny sizes, the strict host verifier standing in
     for the device): sound -> correct; broken underneath -> not."""
+    from benchmark.lib.deploy import Deployment
     from firedancer_tpu.tiles.verify import VerifyTile
 
     monkeypatch.setattr(VerifyTile, "_make_device_fns",
                         lambda self: [device] * self.n_devices)
+    # under the thread runtime the tiles run in THIS process, and an
+    # earlier test file of the same worker may have initialised JAX here:
+    # whether the topology's parent holds a backend says nothing in the rig
+    monkeypatch.setattr(Deployment, "parent_backend_initialized",
+                        lambda self: False)
+    # two seconds: on a loaded host a one-second window has read a rate
+    # of 0 (the thread runtime's tiles and the sender share one GIL)
     res = RUN.run_cell(
-        ROOT, cell, seed=(1 << 31) + 7, seconds=1.0, trace=False,
+        ROOT, cell, seed=(1 << 31) + 7, seconds=2.0, trace=False,
         rehearse=True, require_chip=False,
         overrides={"topo": {"runtime": "thread", "stem": "python"}})
     bad = {k for k, (v, lim) in res["checks"].items() if v > lim}
