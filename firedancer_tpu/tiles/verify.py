@@ -122,6 +122,12 @@ _STOP = object()
 _NULL_SPAN = contextlib.nullcontext()
 
 
+def _tiles(lanes: int) -> int:
+    """Kernel tiles a batch of `lanes` real lanes costs the device:
+    pallas_kernel.verify_core runs whole tiles up to the last real lane."""
+    return -(-lanes // KERNEL_TILE)
+
+
 def _no_span(name: str, **kw):
     """The span factory of a process that holds no JAX backend: the
     per-batch `fdt.verify.*` spans (VerifyTile._span) cost one call."""
@@ -541,10 +547,16 @@ class _DeviceWorker:
                         # but the H2D put inside it can block on a sick
                         # device, so the watchdog window covers it too
                         self.land_t0 = time.monotonic()
+                        # `tiles` is what the batch asks of the device
+                        # (0 on a host-only policy); a dispatch that
+                        # fails over to the host says 0 on its land
                         with self.span(
                             "fdt.verify.dispatch",
                             seq=meta.get("pool_seq", 0),
                             lanes=meta["lanes"],
+                            txns=meta.get("txns", 0),
+                            tiles=_tiles(meta["lanes"])
+                            if self.policy.device_fn is not None else 0,
                             dev=meta["t_dev"],
                         ):
                             slot[3] = self.policy.dispatch(args)
@@ -557,14 +569,23 @@ class _DeviceWorker:
                     # when the batch has run, and it is where an async
                     # dispatch surfaces a runtime error
                     self.land_t0 = time.monotonic()
+                    # the tiles the device computed: none where the host
+                    # path serves the batch, at dispatch or at land
+                    tiles = _tiles(meta["lanes"]) if fut[0] == "dev" else 0
+                    fallbacks = self.policy.fallback_batches
                     with self.span(
                         "fdt.verify.land",
                         seq=meta.get("pool_seq", 0),
                         lanes=meta["lanes"],
+                        txns=meta.get("txns", 0),
+                        tiles=tiles,
                         dev=meta["t_dev"],
                     ):
                         ok = self.policy.land(fut, args, meta["lanes"])
                     self.land_t0 = 0.0
+                    if self.policy.fallback_batches != fallbacks:
+                        tiles = 0
+                    meta["tiles"] = tiles
                     meta["t_land"] = now_ts()
                     self.policy.stalled = False  # the call returned
                     self.completed_n += 1
@@ -951,6 +972,9 @@ class VerifyTile(Tile):
                 # lanes the kernel computed for the landed batches: each
                 # batch's real lanes rounded up to whole kernel tiles
                 "kernel_lanes",
+                # the kernel tiles the DEVICE computed for them: as
+                # kernel_lanes / 256, less the batches the host served
+                "device_tiles",
                 # FallbackPolicy state, mirrored each loop so monitors
                 # see degradation live (sums across the pool's domains)
                 "fallback_batches",
@@ -1450,7 +1474,7 @@ class VerifyTile(Tile):
             meta = dict(
                 rows=b["rows"], szs=b["szs"], tsorigs=b["tsorigs"],
                 sig_cnt=b["sig_cnt"], tags=b["tags"], seqs=b["seqs"],
-                lanes=lanes, rule=rule,
+                lanes=lanes, txns=len(b["sig_cnt"]), rule=rule,
                 # the deque is FIFO: the first chunk holds the oldest frag
                 t_first=take[0]["t_first"],
             )
@@ -1539,8 +1563,11 @@ class VerifyTile(Tile):
         ctx.metrics.inc("device_batches")
         # what the batch cost the kernel, which runs whole tiles up to the
         # last real lane (pallas_kernel.verify_core).  A batch the host
-        # path served is counted alike: `fallback_batches` counts those
-        ctx.metrics.inc("kernel_lanes", -(-lanes // KERNEL_TILE) * KERNEL_TILE)
+        # path served is counted alike: `fallback_batches` counts those.
+        # `device_tiles` counts what the device itself computed (the
+        # worker's land stamps it), 0 for a batch the host served
+        ctx.metrics.inc("kernel_lanes", _tiles(lanes) * KERNEL_TILE)
+        ctx.metrics.inc("device_tiles", meta["tiles"])
         # counted here, beside device_batches, under the domain the pool
         # accepted the result from: a window's deltas of the dev{i}_landed
         # sum to device_batches' delta

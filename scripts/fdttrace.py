@@ -64,9 +64,10 @@ from firedancer_tpu.disco.mux import (  # noqa: E402
 from firedancer_tpu.tango import rings as R  # noqa: E402
 
 #: the arguments the verify tile gives its `fdt.*` host spans: the batch's
-#: pool seq and lanes, on the worker's dispatch / land spans the pool domain
+#: pool seq and lanes, on the worker's dispatch / land spans its txns, its
+#: kernel tiles (0 where the host path serves it) and the pool domain
 #: (`dev=`) that worker serves, and `fdt.clock`'s tie
-_SPAN_ARGS = ("seq", "lanes", "dev", "mono_ns")
+_SPAN_ARGS = ("seq", "lanes", "txns", "tiles", "dev", "mono_ns")
 
 #: per-tile sub-tracks in the Chrome trace (tid = tile_index * 4 + facet)
 _FACET_FRAGS, _FACET_DEVICE, _FACET_LOOP, _FACET_FAULTS = 0, 1, 2, 3
@@ -234,7 +235,7 @@ def profiler_events(xplane_path: str) -> tuple[int | None, list[dict]]:
     events being the program's own host spans (`fdt.*`) and every device
     op, each {"track", "name", "start_ns", "dur_ns", "args"} on the
     PROFILER's clock (`args`: a host span's `seq`, `lanes` and, for a pool
-    worker's dispatch / land, `dev`).  offset_ns = time.monotonic_ns() - profiler ns, taken from
+    worker's dispatch / land, `txns`, `tiles` and `dev`).  offset_ns = time.monotonic_ns() - profiler ns, taken from
     the `fdt.clock` spans the verify tile writes once a second (their
     `mono_ns` argument is the monotonic clock at their start; the median
     over the trace's ties); None when the trace holds no tie.  With it,

@@ -651,7 +651,8 @@ def test_profiler_clock_is_tied_to_the_rings_by_fdt_clock(tmp_path):
                 pass
             t0 = time.monotonic_ns()
             with jax.profiler.TraceAnnotation(
-                    "fdt.verify.dispatch", seq=seq, lanes=8, dev=seq % 2):
+                    "fdt.verify.dispatch", seq=seq, lanes=8, txns=3,
+                    tiles=1, dev=seq % 2):
                 time.sleep(0.002)
             marks.append(t0)
         with jax.profiler.TraceAnnotation("not.ours"):
@@ -666,9 +667,11 @@ def test_profiler_clock_is_tied_to_the_rings_by_fdt_clock(tmp_path):
     spans = sorted((e for e in events if e["name"] == "fdt.verify.dispatch"),
                    key=lambda e: e["start_ns"])
     assert len(spans) == 3
-    # the worker's pool domain rides along (`dev=`), beside seq and lanes
+    # the worker's pool domain rides along (`dev=`), beside seq and lanes,
+    # and so do the batch's txns (fewer than its lanes where a txn carries
+    # several signatures) and the kernel tiles the device computed
     assert [e["args"] for e in spans] == [
-        dict(seq=i, lanes=8, dev=i % 2) for i in range(3)]
+        dict(seq=i, lanes=8, txns=3, tiles=1, dev=i % 2) for i in range(3)]
     for e, t0 in zip(spans, marks):
         assert abs(e["start_ns"] + offset - t0) < 1_000_000  # < 1 ms
         assert e["dur_ns"] >= 2_000_000

@@ -1,8 +1,8 @@
 """The batch's real lane count rides to the device (tier-1).
 
 With the tile's stub device fn (JAX-free, `test_verify.py`'s rig): the count
-the device fn receives, the `kernel_lanes` counter, a three-argument stub, the
-host fallback.  With the tile's own device fn on the plain XLA path (what a
+the device fn receives, the `kernel_lanes` and `device_tiles` counters, the
+worker's spans, a three-argument stub, the host fallback.  With the tile's own device fn on the plain XLA path (what a
 CPU run picks; one compile, shared by the module): the count picks no
 program, and rows at or past it read False.  Kept apart from
 `test_verify.py`, whose tier-1 tests initialise no JAX backend.
@@ -43,6 +43,7 @@ def test_the_device_fn_is_told_the_batchs_real_lanes(rig_factory, lanes):
     assert VT.KERNEL_TILE == 256 and tiles == {
         1: 1, 255: 1, 256: 1, 257: 2, 4095: 16, 4096: 16}[lanes]
     assert after["kernel_lanes"] - before["kernel_lanes"] == 256 * tiles
+    assert after["device_tiles"] - before["device_tiles"] == tiles
     assert after["verified_sigs"] - before["verified_sigs"] == lanes
     assert after["fallback_batches"] == after["device_errors"] == 0
 
@@ -56,6 +57,7 @@ def test_a_device_fn_written_for_three_arrays_is_given_three(rig_factory):
     c = rig.counters()
     assert c["device_errors"] == c["fallback_batches"] == 0
     assert (c["verified_sigs"], c["kernel_lanes"]) == (3, 256)
+    assert c["device_tiles"] == 1
 
 
 def test_the_host_fallback_lands_a_batch_that_carries_the_count(rig_factory):
@@ -70,9 +72,80 @@ def test_the_host_fallback_lands_a_batch_that_carries_the_count(rig_factory):
     rig.settle(1)
     c = rig.counters()
     assert (c["device_errors"], c["fallback_batches"]) == (1, 1)
+    # the host served it: the kernel's lanes are counted, no device tile
+    assert (c["kernel_lanes"], c["device_tiles"]) == (256, 0)
     # the pool's txns are signed: the strict host path admits all three
     assert c["verified_sigs"] == c["out_frags"] == 3
     assert c["verify_fail_txns"] == 0
+
+
+def _n_signer_rows(n_txns: int, n_signers: int):
+    """Unsigned N-signer transfers as the tile's rows, each with a first
+    signature (and so a dedup tag) of its own: N lanes a txn."""
+    from firedancer_tpu.ballet import txn as T
+    from firedancer_tpu.tiles import wire
+
+    rows = np.zeros((n_txns, wire.LINK_MTU), np.uint8)
+    szs = np.zeros(n_txns, np.uint16)
+    for i in range(n_txns):
+        keys = [bytes([i, j]) + bytes(30) for j in range(n_signers + 2)]
+        body = T.build(
+            [bytes([i + 1]) + bytes(63)] + [bytes(64)] * (n_signers - 1),
+            keys, bytes(32), [(n_signers + 1, [0, n_signers], bytes(12))],
+            readonly_signed_cnt=n_signers - 1, readonly_unsigned_cnt=1)
+        full = wire.append_trailer(body, T.parse(body))
+        rows[i, :len(full)] = np.frombuffer(full, np.uint8)
+        szs[i] = len(full)
+    return rows, szs
+
+
+@pytest.mark.parametrize("n_txns,device,tiles", [
+    (100, "served", 2),   # 300 lanes: two kernel tiles, on the device
+    (4, "lost", 0),       # 12 lanes asked of a lost device: the host's
+])
+def test_the_worker_spans_carry_txns_and_the_device_tiles(
+        rig_factory, n_txns, device, tiles):
+    """Three signatures a txn: the worker's `fdt.verify.dispatch` and
+    `fdt.verify.land` spans say the batch's txns beside its lanes, and
+    the kernel tiles the device computed, which `device_tiles` counts;
+    a batch the host path served computed none."""
+    built = []
+
+    class Span:
+        def __init__(self, name, **kw):
+            built.append((name, kw))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    def served(digests, sigs, pubs, n_lanes):
+        return np.ones(len(digests), bool)
+
+    def lost(digests, sigs, pubs, n_lanes):
+        raise RuntimeError("device lost")
+
+    rig = rig_factory(device_fn=served if device == "served" else lost,
+                      max_lanes=4096)
+    rig.rows, rig.szs = _n_signer_rows(n_txns, 3)
+    rig.tile._span = Span            # as _make_device_fns binds jax's
+    rig.boot()
+    rig.burst(n_txns)
+    rig.settle(1)
+    lanes = 3 * n_txns
+    asked = -(-lanes // VT.KERNEL_TILE)
+    spans = dict(built)
+    assert spans["fdt.verify.dispatch"] == dict(
+        seq=0, lanes=lanes, txns=n_txns, tiles=asked, dev=0)
+    assert spans["fdt.verify.land"] == dict(
+        seq=0, lanes=lanes, txns=n_txns, tiles=tiles, dev=0)
+    c = rig.counters()
+    assert (c["device_batches"], c["verified_sigs"]) == (1, lanes)
+    assert c["kernel_lanes"] == asked * VT.KERNEL_TILE
+    assert c["device_tiles"] == tiles
+    assert c["fallback_batches"] == (device == "lost")
 
 
 @pytest.fixture(scope="module")
@@ -102,7 +175,7 @@ def test_the_lane_count_picks_no_program(xla_rig):
     assert c["device_batches"] == 3
     # signed txns, really verified: all twelve leave
     assert c["verified_sigs"] == c["out_frags"] == 12
-    assert c["kernel_lanes"] == 3 * 256
+    assert c["kernel_lanes"] == 3 * 256 == c["device_tiles"] * 256
 
 
 def test_rows_at_or_past_the_count_read_false(xla_rig):
